@@ -192,37 +192,66 @@ def fused_sign_encode_jnp(flat: jax.Array, key, sigma, *, z: int,
     layout, same f32 threshold math — see noise.tile_u01 /
     noise.stochastic_sign_bits).
 
-    ``chunk_tiles == 0`` (default): one elementwise pass. The jaxpr shows a
-    (d_pad,) f32 uniform intermediate, but XLA fuses the whole
-    threefry -> threshold -> bitpack chain into the uint8 output — compiled
-    temp allocation is ~0 bytes where the dense draw allocates 2 x 4d
-    (pinned by tests/test_encode_fused.py), and it is the fastest CPU path.
+    ``chunk_tiles == 0`` (default): one elementwise pass. Each quarter-
+    counter's threefry call yields the bits of four coordinates (one per
+    tile quarter); they are packed into one 4-bit code per counter, and the
+    wire bytes are read off the code's bit planes. So the largest computed
+    intermediate is the (d/4,) uint8 code, never a (d,) f32 uniform: XLA
+    fuses elementwise chains but will not fuse a concatenation of the four
+    quarter streams, which would otherwise hold two (d,) f32 buffers. That
+    holds on the CPU compiler only (pinned by tests/test_encode_fused.py):
+    the TPU compiler does not fuse the pass, and at qwen2-0.5B width
+    (d = 494M) it needs 7.8 GiB of temp.
 
     ``chunk_tiles > 0``: lax.scan over chunks of that many 8192-element
-    tiles, bounding even the jaxpr-level live window to
-    (chunk_tiles * 8192,) f32 per client — the memory-guarantee-by-
-    construction variant (scan carries ~30-80ms of loop overhead per round
-    on small CPUs, so it is opt-in rather than the default).
+    tiles, bounding the noise window to one chunk per client whatever the
+    compiler fuses — use it at LM widths on the TPU (3.7 GiB of temp at
+    d = 494M with 64-tile chunks: two copies of the padded input). The
+    scan carries ~30-80ms of loop overhead per round on small CPUs, so it
+    is opt-in rather than the default.
     """
     d = flat.shape[0]
     tile = ENCODE_TILE
+    q = tile // 4
     n_tiles = -(-d // tile)
     dpad = n_tiles * tile
-    flat = jnp.pad(flat.astype(jnp.float32), (0, dpad - d))
+    flat = flat.astype(jnp.float32)
     if not add_noise:
-        return pack_flat(flat)
+        return pack_flat(jnp.pad(flat, (0, dpad - d)))
     k0, k1 = znoise.key_words(key)
+    sig = jnp.asarray(sigma, jnp.float32)
+    inv = znoise.threshold_scale(sig, z)
 
     def tiles_packed(x_chunk, first_tile, n):
-        u = jax.vmap(lambda t: znoise.tile_u01(k0, k1, t * tile, tile))(
-            first_tile + jnp.arange(n, dtype=jnp.uint32)).reshape(-1)
-        return wire.pack_bool(znoise.stochastic_sign_bits(x_chunk, u, sigma, z))
+        # quarter j of tile t holds elements t*tile + j*q + k, and takes its
+        # uniforms from half j of the words of counters t*q + k
+        x4 = x_chunk.reshape(n, 4, q)
+        cnt = ((first_tile + jnp.arange(n, dtype=jnp.uint32))[:, None]
+               * jnp.uint32(q) + jnp.arange(q, dtype=jnp.uint32)[None])
+        y0, y1 = znoise.counter_words(k0, k1, cnt)
+        us = znoise.halves_to_u01(y0) + znoise.halves_to_u01(y1)
+        code = jnp.zeros((n, q), jnp.uint8)
+        for j, u in enumerate(us):
+            x = x4[:, j]
+            bit = jnp.where(sig > 0, znoise.noisy_sign_bits(x, u, inv, z),
+                            x >= 0)
+            code = code | (bit.astype(jnp.uint8) << j)
+        planes = (code[:, None, :] >> jnp.arange(4, dtype=jnp.uint8)[:, None]
+                  ) & jnp.uint8(1)                        # (n, 4, q)
+        return wire.pack_bool(planes > 0)
 
     if chunk_tiles <= 0 or n_tiles <= chunk_tiles:
-        return tiles_packed(flat, jnp.uint32(0), n_tiles)
+        # whole tiles straight from the input, only the last one padded: a
+        # pad of the whole buffer would be a (d,) copy
+        n_full = d // tile
+        parts = [tiles_packed(flat[:n_full * tile], jnp.uint32(0), n_full)]
+        if dpad > d:
+            tail = jnp.pad(flat[n_full * tile:], (0, dpad - d))
+            parts.append(tiles_packed(tail, jnp.uint32(n_full), 1))
+        return jnp.concatenate(parts)
     n_chunks = -(-n_tiles // chunk_tiles)
-    cpad = n_chunks * chunk_tiles * tile - dpad
-    x2 = jnp.pad(flat, (0, cpad)).reshape(n_chunks, chunk_tiles * tile)
+    x2 = jnp.pad(flat, (0, n_chunks * chunk_tiles * tile - d)).reshape(
+        n_chunks, chunk_tiles * tile)
     starts = jnp.arange(n_chunks, dtype=jnp.uint32) * jnp.uint32(chunk_tiles)
     _, packed = jax.lax.scan(
         lambda _, xs: (None, tiles_packed(xs[0], xs[1], chunk_tiles)),

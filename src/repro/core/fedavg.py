@@ -107,7 +107,6 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import noise as znoise
@@ -330,6 +329,38 @@ def iter_shards(batch, mask, cstate, *, shard: int, total: int):
                (m[rows] * (sl < total)).astype(np.float32))
 
 
+def client_pseudo_gradient(loss_fn: Callable, cfg: FedConfig, spec, params0,
+                           client_batch, *, legacy_client_path: bool = False):
+    """One client's E local SGD steps from ``params0`` -> (flat f32
+    pseudo-gradient (x0 - xE)/gamma, mean loss). ``client_batch`` leaves
+    lead with the E axis; ``spec`` is the params' wire.TreeSpec."""
+    gamma = cfg.client_lr
+    if cfg.local_steps == 1 and not legacy_client_path:
+        # E == 1: the pseudo-gradient (x0 - x1)/gamma IS the batch
+        # gradient, so neither the updated weights nor the subtraction
+        # back need to exist (and a length-1 lax.scan would lower to an
+        # XLA while loop whose params-tree carry is copied at the loop
+        # boundary — an (n_clients x params) copy per round for zero
+        # sequencing). ~2x less client-side memory traffic around the
+        # flatten on the CPU benchmark; identical up to f32 rounding
+        # (this path skips the (gamma*g)/gamma round-trip).
+        loss, g = jax.value_and_grad(loss_fn)(
+            params0, jax.tree.map(lambda x: x[0], client_batch))
+        return spec.flatten(g), loss
+
+    def step(p, b):
+        loss, g = jax.value_and_grad(loss_fn)(p, b)
+        p = jax.tree.map(lambda w, gw: w - gamma * gw.astype(w.dtype), p, g)
+        return p, loss
+
+    x_e, losses = jax.lax.scan(step, params0, client_batch)
+    pseudo = jax.tree.map(
+        lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32)) / gamma,
+        params0, x_e)
+    # the ONE flatten: pytree -> contiguous fp32 wire buffer
+    return spec.flatten(pseudo), jnp.mean(losses)
+
+
 def _build_round_math(loss_fn: Callable, compressor, cfg: FedConfig, *,
                       dynamic_sigma: bool, legacy_client_path: bool,
                       spmd_axes, constrain_wire: Callable,
@@ -343,40 +374,11 @@ def _build_round_math(loss_fn: Callable, compressor, cfg: FedConfig, *,
     corruption semantics) and every cohort plan sees the identical attack
     (selection is by global client index + round).
     """
-    gamma = cfg.client_lr
-
-    def local_sgd(params, client_batch):
-        """scan over E local steps; returns (x_E, mean loss)."""
-        def step(p, b):
-            loss, g = jax.value_and_grad(loss_fn)(p, b)
-            p = jax.tree.map(lambda w, gw: w - gamma * gw.astype(w.dtype), p, g)
-            return p, loss
-
-        x_e, losses = jax.lax.scan(step, params, client_batch)
-        return x_e, jnp.mean(losses)
-
     def client_update(spec, params0, client_batch, key, cstate, sigma,
                       server=None):
-        if cfg.local_steps == 1 and not legacy_client_path:
-            # E == 1: the pseudo-gradient (x0 - x1)/gamma IS the batch
-            # gradient, so neither the updated weights nor the subtraction
-            # back need to exist (and a length-1 lax.scan would lower to an
-            # XLA while loop whose params-tree carry is copied at the loop
-            # boundary — an (n_clients x params) copy per round for zero
-            # sequencing). ~2x less client-side memory traffic around the
-            # flatten on the CPU benchmark; identical up to f32 rounding
-            # (this path skips the (gamma*g)/gamma round-trip).
-            loss, g = jax.value_and_grad(loss_fn)(
-                params0, jax.tree.map(lambda x: x[0], client_batch))
-            flat = spec.flatten(g)
-        else:
-            x_e, loss = local_sgd(params0, client_batch)
-            pseudo = jax.tree.map(
-                lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32))
-                / gamma,
-                params0, x_e)
-            # the ONE flatten: pytree -> contiguous fp32 wire buffer
-            flat = spec.flatten(pseudo)
+        flat, loss = client_pseudo_gradient(
+            loss_fn, cfg, spec, params0, client_batch,
+            legacy_client_path=legacy_client_path)
         if cfg.dp_clip > 0.0:
             flat = clip_flat(flat, cfg.dp_clip)
         # the server/spec kwargs are capability-gated: only pipelines with
@@ -673,11 +675,11 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
                 loss = jax.lax.psum(loss, "clients")
                 return acc, loss, cstate_out
 
-            enc_sum, loss_sum, cstate_sh = shard_map(
+            enc_sum, loss_sum, cstate_sh = jax.shard_map(
                 per_device, mesh=mesh,
                 in_specs=(rep, rep, rep, rep, rep, shd, shd, shd, shd),
                 out_specs=(rep, rep, shd),
-                check_rep=False,
+                check_vma=False,
             )(params, sub, sigma, jnp.asarray(round_idx, jnp.int32),
               server, s_idx, s_batch, s_cstate, s_mask)
             enc_sum = constrain_wire(enc_sum)

@@ -45,7 +45,7 @@ Bit-transforms (uint32 -> noise):
 
 The encoder never materializes xi at all: Sign(x + sigma*F_z^{-1}(u)) ==
 [u > 1 - P_z(x/sigma)] for the symmetric z-noise CDF F_z (P_z(r) =
-P(r + xi >= 0) = F_z(r), ``sign_prob``), so the fused kernels sample the
+P(r + xi >= 0) = F_z(r), ``noisy_sign_bits``), so the fused kernels sample the
 wire bit directly from its exact Bernoulli law — the inverse-CDF coupling
 makes this THE SAME random variable as adding counter noise and taking the
 sign, not an approximation (``stochastic_sign_bits``; equivalence verified
@@ -144,9 +144,12 @@ def halves_to_u01(bits):
     exactly symmetric around 1/2, so erfinv(2u-1) is always finite and 2u-1
     has mean exactly 0.
     """
+    # via int32: Mosaic has no uint32 -> float32 conversion, and both
+    # halves are < 2^16, so the int32 detour is exact
     scale = jnp.float32(2.0 ** -16)
-    lo = ((bits & jnp.uint32(0xFFFF)).astype(jnp.float32) + 0.5) * scale
-    hi = ((bits >> 16).astype(jnp.float32) + 0.5) * scale
+    lo = ((bits & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+          + 0.5) * scale
+    hi = ((bits >> 16).astype(jnp.int32).astype(jnp.float32) + 0.5) * scale
     return lo, hi
 
 
@@ -203,18 +206,52 @@ def counter_noise(key, n: int, z: int, *, tile: int = 8192) -> jax.Array:
     return u01_to_noise(u, z)[:n]
 
 
-def sign_prob(r, z: int):
-    """P_z(r) = P(r + xi_z >= 0) = F_z(r), the noise CDF at r.
+# The f32 erf approximation erf(y) ~ y * A(y^2) / B(y^2) on y in [-4, 4]
+# (the rational form XLA lowers lax.erf to), as Horner coefficients from the
+# highest degree down. Both polynomials are negative on the whole range.
+_ERF_A = (-2.72614225801306e-10, 2.77068142495902e-08, -2.10102402082508e-06,
+          -5.69250639462346e-05, -7.34990630326855e-04, -2.95459980854025e-03,
+          -1.60960333262415e-02)
+_ERF_B = (-1.45660718464996e-05, -2.13374055278905e-04, -1.68282697438203e-03,
+          -7.37332916720468e-03, -1.42647390514189e-02)
 
-    z=inf: clip((r+1)/2, 0, 1);  z=1: Phi(r) = (1 + erf(r/sqrt(2)))/2.
-    Pallas-safe (clip/erf lower on the VPU).
-    """
-    r = jnp.asarray(r, jnp.float32)
-    if z <= Z_INF:
-        return jnp.clip(0.5 * (r + 1.0), 0.0, 1.0)
+
+def _horner(y2, coeffs):
+    acc = jnp.full_like(y2, coeffs[0])
+    for c in coeffs[1:]:
+        acc = acc * y2 + jnp.float32(c)
+    return acc
+
+
+def threshold_scale(sigma, z: int) -> jax.Array:
+    """The per-client multiplier that maps a coordinate x to the argument of
+    the sign CDF: 1/sigma for z=inf, 1/(sigma*sqrt(2)) for z=1 (the erf
+    argument). Computed once per client OUTSIDE the encode kernels, so the
+    one f32 division of the encode is the same XLA op on every backend."""
+    sig = jnp.maximum(jnp.asarray(sigma, jnp.float32), _TINY)
     if z == 1:
-        return 0.5 * (1.0 + jax.lax.erf(r * jnp.float32(1.0 / math.sqrt(2.0))))
-    raise ValueError(f"sign_prob covers z=inf and z=1 only, got {z}")
+        sig = sig * jnp.float32(math.sqrt(2.0))
+    return 1.0 / sig
+
+
+def noisy_sign_bits(x, u, inv, z: int):
+    """[u > 1 - P_z(x * inv * c_z)] with ``inv = threshold_scale(sigma, z)``:
+    the z-sign wire bit for sigma > 0.
+
+    Only f32 multiply, add, clamp and compare: no division, no
+    transcendental. That keeps the bit identical between the Pallas kernel
+    (Mosaic) and ordinary XLA on the same chip. For z=1 the comparison
+    erf(y) > 1 - 2u is cross-multiplied by the (negative) denominator of the
+    rational erf, so it needs no division either.
+    """
+    if z <= Z_INF:
+        return u > 1.0 - jnp.clip(0.5 * (x * inv + 1.0), 0.0, 1.0)
+    if z == 1:
+        y = jnp.clip(x * inv, -4.0, 4.0)
+        y2 = y * y
+        # erf(y) = y*A/B > t  <=>  y*A < t*B, since B < 0
+        return y * _horner(y2, _ERF_A) < (1.0 - 2.0 * u) * _horner(y2, _ERF_B)
+    raise ValueError(f"noisy_sign_bits covers z=inf and z=1 only, got {z}")
 
 
 def stochastic_sign_bits(x, u, sigma, z: int):
@@ -229,8 +266,7 @@ def stochastic_sign_bits(x, u, sigma, z: int):
     ``wire.pack_flat``.
     """
     sig = jnp.asarray(sigma, jnp.float32)
-    r = x * (1.0 / jnp.maximum(sig, _TINY))
-    noisy = u > (1.0 - sign_prob(r, z))
+    noisy = noisy_sign_bits(x, u, threshold_scale(sig, z), z)
     return jnp.where(sig > 0, noisy, x >= 0)
 
 

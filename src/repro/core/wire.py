@@ -127,9 +127,9 @@ def pack_bool(bits: jax.Array) -> jax.Array:
     """bool (flat, len % 8 == 0) -> uint8 bitfield of len/8.
 
     THE little-endian pack every sign path shares: element 8i+j lands in bit
-    j of byte i. The Pallas kernels keep a shape-local copy of these three
-    lines (kernels/zsign ``_pack_bits_u8``) — bit-exactness between the two
-    is pinned by the encode-equivalence tests.
+    j of byte i. The Pallas kernels compute the same bytes on the MXU
+    (kernels/common ``pack_bits``) — bit-exactness between the two is pinned
+    by the encode-equivalence tests.
     """
     b = bits.astype(jnp.uint8).reshape(-1, 8)
     weights = (jnp.uint8(1) << jnp.arange(8, dtype=jnp.uint8))

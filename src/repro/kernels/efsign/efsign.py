@@ -4,9 +4,9 @@ One pass over HBM computes ALL THREE outputs of the error-feedback step —
 q = scale*Sign(g+e), the new residual e' = g+e-q, and the bitpacked uint8
 wire payload (bit j of byte i == Sign((g+e)[8i+j]) >= 0, the same
 little-endian layout as kernels/zsign) — instead of the separate elementwise
-+ pack passes the naive jnp formulation costs. Same VMEM tiling discipline
-as kernels/zsign: (ROWS_BLK, 1024) fp32 tiles in, (ROWS_BLK, 128) uint8
-payload tiles out.
++ pack passes the naive jnp formulation costs. Same tiling as kernels/zsign: one 8192-element fp32 tile of
+the flat view in, (8, 128) uint8 payload tiles out, with the shared layout
+and bit-pack of kernels/common.py.
 
 Sign convention is ``p >= 0 -> +1`` (matching wire.pack_flat), NOT jnp.sign:
 the residual must account exactly for what the server decodes from the
@@ -17,46 +17,38 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-LANE = 128
-PACK = 8
-COLS = LANE * PACK
-ROWS_BLK = 8
+from repro.kernels.common import (COLS, FLAT_ROWS, LANE, ROWS_BLK, flat_spec,
+                                  matrix_spec, pack_bits, pack_matrix, to_tile)
 
 
-def _ef_kernel(g_ref, e_ref, s_ref, q_ref, eout_ref, p_ref):
+def _ef_kernel(s_ref, g_ref, e_ref, m_ref, q_ref, eout_ref, p_ref):
     p = g_ref[...] + e_ref[...]
-    r = p.shape[0]
-    pm = jnp.where(p >= 0.0, jnp.float32(1), jnp.float32(-1))
-    q = s_ref[0, 0] * pm
+    s = s_ref[0]
+    q = jnp.where(p >= 0.0, s, -s)
     q_ref[...] = q
     eout_ref[...] = p - q
-    bits = (p >= 0.0).reshape(r, LANE, PACK).astype(jnp.uint8)
-    weights = (jnp.uint8(1) << jnp.arange(PACK, dtype=jnp.uint8))
-    p_ref[...] = jnp.sum(bits * weights, axis=-1, dtype=jnp.uint8)
+    p_ref[...] = pack_bits(to_tile(p) >= 0.0, m_ref[...])
 
 
 def ef_update_pallas(g2d, e2d, scale, *, interpret: bool):
-    """(rows, 1024) f32 x2 + scale -> (q, e_new, packed_u8[rows, 128])."""
-    rows = g2d.shape[0]
-    grid = (rows // ROWS_BLK,)
+    """flat views (n_tiles * 64, 128) f32 x2 + scale ->
+    (q, e_new, packed_u8[n_tiles * 8, 128]), q and e_new as flat views."""
+    n_tiles = g2d.shape[0] // FLAT_ROWS
+    rows = n_tiles * FLAT_ROWS
+    tile = flat_spec(lambda i: (i, 0))
     return pl.pallas_call(
         _ef_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, LANE), lambda i: (i, 0)),
-        ],
+        grid=(n_tiles,),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile, tile,
+                  matrix_spec((COLS, LANE))],
+        out_specs=[tile, tile,
+                   pl.BlockSpec((ROWS_BLK, LANE), lambda i: (i, 0))],
         out_shape=[
-            jax.ShapeDtypeStruct((rows, COLS), jnp.float32),
-            jax.ShapeDtypeStruct((rows, COLS), jnp.float32),
-            jax.ShapeDtypeStruct((rows, LANE), jnp.uint8),
+            jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
+            jax.ShapeDtypeStruct((n_tiles * ROWS_BLK, LANE), jnp.uint8),
         ],
         interpret=interpret,
-    )(g2d, e2d, scale.reshape(1, 1).astype(jnp.float32))
+    )(scale.reshape(1).astype(jnp.float32), g2d, e2d, pack_matrix())

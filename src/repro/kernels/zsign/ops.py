@@ -1,8 +1,9 @@
 """jit'd public wrappers around the z-sign Pallas kernels.
 
-Handle arbitrary-shaped inputs (flatten + pad to the 8192-element tile), and
-select interpret mode automatically off-TPU so the same code validates on CPU
-and runs the real kernel on TPU.
+Handle arbitrary-shaped inputs (flatten + pad to the 8192-element tile).
+The kernels run compiled on the TPU and in interpret mode on the CPU
+(kernels/common.interpret_mode); interpret mode checks the kernels' logic,
+not what the TPU compiler accepts (tests/test_tpu_compile.py does that).
 """
 from __future__ import annotations
 
@@ -12,13 +13,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import noise as znoise
+from repro.core import wire
+from repro.kernels.common import (LANE, ROWS_BLK, TILE, flat_view,
+                                  interpret_mode)
 from repro.kernels.zsign import zsign as K
-
-TILE = K.ROWS_BLK * K.COLS   # 8192
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 def _pad_flat(x: jax.Array):
@@ -26,7 +24,7 @@ def _pad_flat(x: jax.Array):
     pad = (-flat.size) % TILE
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(-1, K.COLS), pad
+    return flat_view(flat), pad
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -35,7 +33,7 @@ def zsign_compress(x: jax.Array, noise: jax.Array, sigma,
     """Fused noisy-sign + bitpack.  x, noise: same shape float32.
     Returns uint8 of ceil(x.size/8) bytes (padded tail packs sign(+pad zeros)).
     """
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     x2d, _ = _pad_flat(x.astype(jnp.float32))
     n2d, _ = _pad_flat(noise.astype(jnp.float32))
     packed = K.compress_pallas(x2d, n2d, jnp.asarray(sigma), interpret=interpret)
@@ -45,17 +43,18 @@ def zsign_compress(x: jax.Array, noise: jax.Array, sigma,
 def _batched_encode_tiles_jnp(x2d, key2, sigma, *, z):
     """Client-batched counter-stream encode, pure jnp, tile-scanned.
 
-    x2d: (n, rows, 1024) f32; key2: (n, 2) u32; sigma: (n,) f32 ->
-    (n, rows, 128) u8, byte-for-byte the stack of per-client
+    x2d: (n, n_tiles * 64, 128) f32 flat views; key2: (n, 2) u32; sigma:
+    (n,) f32 -> (n, n_tiles * 8, 128) u8, byte-for-byte the stack of per-client
     ``compress_rng_pallas`` outputs (same global quarter-counters, same
     tile word layout — noise.tile_u01). The lax.scan walks the TILE axis
     so the largest computed f32 intermediate is one (n, 8192) uniform
     window, never an (n, d) noise surface (the jaxpr pin of
     tests/test_encode_fused.py)."""
-    n, rows, _ = x2d.shape
+    n = x2d.shape[0]
+    n_tiles = x2d[0].size // TILE
+    rows = n_tiles * ROWS_BLK
     if z is None:
-        return jax.vmap(K._pack_bits_u8)(x2d >= 0.0)
-    n_tiles = rows // K.ROWS_BLK
+        return wire.pack_bool(x2d >= 0.0).reshape(n, rows, LANE)
     k0, k1 = key2[:, 0], key2[:, 1]
     sig = sigma.reshape(n, 1)
     xt = jnp.moveaxis(x2d.reshape(n, n_tiles, TILE), 1, 0)
@@ -65,13 +64,13 @@ def _batched_encode_tiles_jnp(x2d, key2, sigma, *, z):
         u = jax.vmap(lambda a, b: znoise.tile_u01(a, b, t * TILE, TILE))(
             k0, k1)
         bits = znoise.stochastic_sign_bits(x_t, u, sig, z)
-        return None, K._pack_bits_u8(bits.reshape(n * K.ROWS_BLK, K.COLS))
+        return None, wire.pack_bool(bits).reshape(n * ROWS_BLK, LANE)
 
     _, packed = jax.lax.scan(
         step, None, (xt, jnp.arange(n_tiles, dtype=jnp.uint32)))
     # (n_tiles, n*ROWS_BLK, LANE) -> per-client (rows, LANE), tile-major
-    return jnp.moveaxis(packed.reshape(n_tiles, n, K.ROWS_BLK, K.LANE),
-                        1, 0).reshape(n, rows, K.LANE)
+    return jnp.moveaxis(packed.reshape(n_tiles, n, ROWS_BLK, LANE),
+                        1, 0).reshape(n, rows, LANE)
 
 
 @lru_cache(maxsize=None)
@@ -87,7 +86,7 @@ def _rng_encode_vmappable(z, interpret: bool):
     50 -> 1560 us/client from n=16 to n=256 at d=1024). The rule here
     replaces that lowering wholesale:
 
-      * compiled TPU path: :func:`zsign.compress_rng_pallas_batched` folds
+      * compiled TPU path: :func:`zsign.compress_rng_pallas` folds
         the client axis into the kernel GRID — block-pipelined in-place
         writes, one kernel launch, linear in n;
       * interpret/CPU path: the tile-scanned jnp twin
@@ -100,7 +99,7 @@ def _rng_encode_vmappable(z, interpret: bool):
 
     @jax.custom_batching.custom_vmap
     def enc(x2d, key2, sigma):
-        return K.compress_rng_pallas(x2d, key2, sigma, z=z,
+        return K.compress_rng_pallas(x2d, key2, sigma.reshape(1), z=z,
                                      interpret=interpret)
 
     @enc.def_vmap
@@ -112,15 +111,13 @@ def _rng_encode_vmappable(z, interpret: bool):
             key2 = jnp.broadcast_to(key2[None], (n,) + key2.shape)
         if not in_batched[2]:
             sigma = jnp.broadcast_to(jnp.reshape(sigma, (1,)), (n,))
-        rows = x2d.shape[1]
         key2 = key2.reshape(n, 2)
         sigma = sigma.reshape(n).astype(jnp.float32)
         if interpret:
             return _batched_encode_tiles_jnp(x2d, key2, sigma, z=z), True
-        packed = K.compress_rng_pallas_batched(
-            x2d.reshape(n * rows, K.COLS), key2, sigma, z=z,
-            interpret=interpret)
-        return packed.reshape(n, rows, K.LANE), True
+        packed = K.compress_rng_pallas(
+            x2d.reshape(-1, LANE), key2, sigma, z=z, interpret=interpret)
+        return packed.reshape(n, -1, LANE), True
 
     return enc
 
@@ -142,7 +139,7 @@ def zsign_encode_fused(x: jax.Array, key: jax.Array, sigma,
     may be traced (Plateau dynamic sigma; stosign's per-client norm) — a
     runtime 0 also degrades exactly to noise-free signs.
     """
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     x2d, _ = _pad_flat(x.astype(jnp.float32))
     k0, k1 = znoise.key_words(key)
     key2 = jnp.stack([k0, k1]).reshape(1, 2)
@@ -163,29 +160,24 @@ def sign_reduce(packed: jax.Array, weights: jax.Array,
     with zero weight, bytes to the (ROWS_BLK * LANE) tile; both pads
     contribute exactly 0.
     """
-    interpret = _interpret() if interpret is None else interpret
+    interpret = interpret_mode() if interpret is None else interpret
     n, nbytes = packed.shape
-    bpad = (-nbytes) % (K.ROWS_BLK * K.LANE)
+    bpad = (-nbytes) % (ROWS_BLK * LANE)
     cpad = (-n) % K.CLIENT_BLK
     if bpad or cpad:
         packed = jnp.pad(packed, ((0, cpad), (0, bpad)))
     w = weights.astype(jnp.float32)
     if cpad:
         w = jnp.pad(w, (0, cpad))
-    p3 = packed.reshape(n + cpad, -1, K.LANE)
-    s = K.sign_reduce_pallas(p3, w.reshape(-1, 1), interpret=interpret)
+    p3 = packed.reshape(n + cpad, -1, LANE)
+    s = K.sign_reduce_pallas(p3, w, interpret=interpret)
     return s.reshape(-1)[: nbytes * 8]
 
 
 @partial(jax.jit, static_argnames=("n_coords", "interpret"))
 def zsign_decompress_sum(packed: jax.Array, n_coords: int,
                          *, interpret: bool | None = None) -> jax.Array:
-    """packed: (n_clients, n_bytes) uint8 -> (n_coords,) f32 sum of signs."""
-    interpret = _interpret() if interpret is None else interpret
-    n, nbytes = packed.shape
-    pad = (-nbytes) % (K.ROWS_BLK * K.LANE)
-    if pad:
-        packed = jnp.pad(packed, ((0, 0), (0, pad)))
-    p3 = packed.reshape(n, -1, K.LANE)
-    s = K.unpack_sum_pallas(p3, interpret=interpret).reshape(-1)
-    return s[:n_coords]
+    """packed: (n_clients, n_bytes) uint8 -> (n_coords,) f32 sum of signs:
+    the sign-reduce with unit weights."""
+    w = jnp.ones((packed.shape[0],), jnp.float32)
+    return sign_reduce(packed, w, interpret=interpret)[:n_coords]

@@ -1,25 +1,24 @@
 """Pallas TPU kernels for z-SignFedAvg's compression hot path.
 
-Four kernels:
+Three kernels:
 
   _compress_kernel:  y = x + sigma*noise; pack Sign(y) bits -> uint8
                      (fused elementwise + 8:1 bitpack; 1 byte out per 8 in;
-                     noise is a kernel INPUT — the legacy/dense-noise path,
-                     kept for finite z > 1 and as the reference encoder)
+                     noise is a kernel INPUT — the dense-noise path, kept for
+                     finite z > 1 and as the reference encoder)
   _compress_rng_kernel: in-kernel counter-based noise — each grid tile
                      derives its randomness from threefry2x32(client_key,
                      tile_counters) (core/noise.py, plain VPU uint32 ops; 4
                      u16 uniforms per call) and samples the wire bit
                      directly from its exact Bernoulli law
                      [u > 1 - P_z(x/sigma)] (the inverse-CDF coupling of
-                     noise.stochastic_sign_bits). The fp32 noise buffer that
-                     the old path streamed through HBM never exists: the
-                     client encode reads x and writes wire bytes, nothing
-                     else. Counters are GLOBAL quarter-tile indices, so the
-                     chunked jnp fallback (core/compression.py) reproduces
-                     the byte stream bit-exactly on CPU.
-  _unpack_sum_kernel: (n_clients, ...) packed uint8 -> sum of {-1,+1} fp32
-                     (legacy whole-stack unpack; kept as kernel oracle)
+                     noise.stochastic_sign_bits). The fp32 noise buffer
+                     never exists: the client encode reads x and writes wire
+                     bytes, nothing else. Counters are GLOBAL quarter-tile
+                     indices, so the jnp encode (core/compression.py)
+                     reproduces the byte stream bit-exactly. The client axis
+                     is the outer grid axis: one launch encodes a whole
+                     client stack.
   _sign_reduce_kernel: (n_clients, ...) packed uint8 + (n_clients,) fp32
                      weights -> weighted sum of {-1,+1} fp32, with the client
                      axis folded into the grid and a VMEM accumulator per
@@ -29,15 +28,16 @@ Four kernels:
                      wire bytes in VMEM, multiplies by the per-client
                      weights, and accumulates into the revisited output tile.
 
-TPU adaptation notes (DESIGN.md §2): the compressor is bandwidth-bound
-elementwise work, so the kernels stream HBM->VMEM in (ROWS_BLK, 1024) tiles
-(1024 = 8 lanes-groups x 128 lanes, MXU-free, VPU-only) and write uint8 tiles
-(ROWS_BLK, 128). Bit order matches the flat little-endian order of the
-pure-jnp oracle in ref.py (element 8i+j -> bit j of byte i). The counter
-scheme was chosen over pltpu.prng_random_bits because the hardware PRNG's
-stream cannot be reproduced off-TPU — threefry2x32 is ~13 VPU integer ops
-per word and gives the interpret-mode kernel, the compiled TPU kernel, and
-the jnp fallback the identical byte stream for the same client key.
+The kernels stream HBM->VMEM one 8192-element f32 tile per grid step (a
+(64, 128) block of the flat view, worked on as (8, 1024)) and write
+(8, 128) uint8 tiles; the layout, the bit-pack and the unpack are the
+shared forms of kernels/common.py. Per-client scalars (key words, sigma, the
+threshold scale, reduce weights) live in SMEM; the tile index is the grid
+position. The counter scheme was chosen over pltpu.prng_random_bits because
+the hardware PRNG's stream cannot be reproduced off-TPU — threefry2x32 is
+~13 VPU integer ops per word and gives the interpret-mode kernel, the
+compiled TPU kernel, and the jnp encode the identical byte stream for the
+same client key.
 """
 from __future__ import annotations
 
@@ -46,173 +46,124 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import noise as znoise
+from repro.kernels.common import (COLS, FLAT_ROWS, LANE, ROWS_BLK, TILE,
+                                  flat_spec, from_tile, matrix_spec, pack_bits,
+                                  pack_matrix, spread_matrix, to_tile,
+                                  unpack_bits)
 
-LANE = 128
-PACK = 8
-COLS = LANE * PACK          # 1024 elements per row
-ROWS_BLK = 8                # 8192 elements per block
 CLIENT_BLK = 8              # clients per sign-reduce grid step
 
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
-def _compress_kernel(x_ref, n_ref, sig_ref, o_ref):
-    x = x_ref[...]                                   # (R, 1024) f32
-    y = x + sig_ref[0, 0] * n_ref[...]
-    r = x.shape[0]
-    bits = (y >= 0.0).reshape(r, LANE, PACK).astype(jnp.uint8)
-    weights = (jnp.uint8(1) << jnp.arange(PACK, dtype=jnp.uint8))
-    o_ref[...] = jnp.sum(bits * weights, axis=-1, dtype=jnp.uint8)
+
+def _compress_kernel(sig_ref, x_ref, n_ref, m_ref, o_ref):
+    y = to_tile(x_ref[...]) + sig_ref[0] * to_tile(n_ref[...])
+    o_ref[...] = pack_bits(y >= 0.0, m_ref[...])
 
 
 def compress_pallas(x2d: jax.Array, noise2d: jax.Array, sigma: jax.Array,
                     *, interpret: bool) -> jax.Array:
-    """x2d/noise2d: (rows, 1024) f32, rows % ROWS_BLK == 0 -> (rows, 128) u8."""
-    rows = x2d.shape[0]
-    grid = (rows // ROWS_BLK,)
+    """x2d/noise2d: flat views (n_tiles * 64, 128) f32 ->
+    (n_tiles * 8, 128) u8."""
+    n_tiles = x2d.shape[0] // FLAT_ROWS
+    tile = flat_spec(lambda i: (i, 0))
     return pl.pallas_call(
         _compress_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-            pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        ],
+        grid=(n_tiles,),
+        in_specs=[_SMEM, tile, tile, matrix_spec((COLS, LANE))],
         out_specs=pl.BlockSpec((ROWS_BLK, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.uint8),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * ROWS_BLK, LANE), jnp.uint8),
         interpret=interpret,
-    )(x2d, noise2d, sigma.reshape(1, 1).astype(jnp.float32))
+    )(sigma.reshape(1).astype(jnp.float32), x2d, noise2d, pack_matrix())
 
 
-def _pack_bits_u8(bits):
-    """(R, COLS) bool -> (R, LANE) uint8, little-endian within each byte."""
-    r = bits.shape[0]
-    b = bits.reshape(r, LANE, PACK).astype(jnp.uint8)
-    weights = (jnp.uint8(1) << jnp.arange(PACK, dtype=jnp.uint8))
-    return jnp.sum(b * weights, axis=-1, dtype=jnp.uint8)
+def _compress_rng_kernel(k_ref, sig_ref, inv_ref, x_ref, m_ref, o_ref, *, z):
+    """Counter-based in-kernel noise: one tile of one client's encode.
 
-
-def _compress_rng_kernel(x_ref, k_ref, sig_ref, t_ref, o_ref, *, z):
-    """Counter-based in-kernel noise: one tile of the fused client encode.
-
-    Tile t covers elements [t*8192, (t+1)*8192). Quarter-counters are global
-    (c = t*2048 + local); one threefry2x32 call yields 4 u16 uniforms that
-    feed the tile's four row-quarters — the layout of noise.tile_u01, which
-    the jnp fallback replays verbatim. ``z`` is static: None disables the
-    noise entirely (vanilla SignSGD, satellite of the sigma==0 gating), else
-    z in {Z_INF, 1} selects the sign CDF.
+    Grid (client c, tile t). Tile t covers the client's elements
+    [t*8192, (t+1)*8192). Quarter-counters are global (c = t*2048 + local);
+    one threefry2x32 call yields 4 u16 uniforms that feed the tile's four
+    row-quarters — the layout of noise.tile_u01, which the jnp encode
+    replays verbatim. ``z`` is static: None disables the noise entirely
+    (vanilla SignSGD, sigma == 0), else z in {Z_INF, 1} selects the sign
+    CDF.
     """
-    x = x_ref[...]                                   # (R, 1024) f32
+    def plain():
+        o_ref[...] = pack_bits(to_tile(x_ref[...]) >= 0.0, m_ref[...])
+
     if z is None:
-        o_ref[...] = _pack_bits_u8(x >= 0.0)
+        plain()
         return
-    r = x.shape[0]
-    qrows = r // 4
-    t = t_ref[0, 0].astype(jnp.uint32)
-    row = jax.lax.broadcasted_iota(jnp.uint32, (qrows, COLS), 0)
-    col = jax.lax.broadcasted_iota(jnp.uint32, (qrows, COLS), 1)
-    c = t * jnp.uint32(r * COLS // 4) + row * jnp.uint32(COLS) + col
-    y0, y1 = znoise.counter_words(k_ref[0, 0], k_ref[0, 1], c)
-    u0, u1 = znoise.halves_to_u01(y0)
-    u2, u3 = znoise.halves_to_u01(y1)
-    u = jnp.concatenate([u0, u1, u2, u3], axis=0)    # (R, 1024) in (0,1)
-    o_ref[...] = _pack_bits_u8(
-        znoise.stochastic_sign_bits(x, u, sig_ref[0, 0], z))
+    c = pl.program_id(0)
+    t = pl.program_id(1).astype(jnp.uint32)
+
+    @pl.when(sig_ref[c] > 0.0)
+    def _noisy():
+        qrows = ROWS_BLK // 4
+        row = jax.lax.broadcasted_iota(jnp.uint32, (qrows, COLS), 0)
+        col = jax.lax.broadcasted_iota(jnp.uint32, (qrows, COLS), 1)
+        cnt = t * jnp.uint32(TILE // 4) + row * jnp.uint32(COLS) + col
+        y0, y1 = znoise.counter_words(k_ref[2 * c], k_ref[2 * c + 1], cnt)
+        u0, u1 = znoise.halves_to_u01(y0)
+        u2, u3 = znoise.halves_to_u01(y1)
+        u = jnp.concatenate([u0, u1, u2, u3], axis=0)  # (R, 1024) in (0,1)
+        bits = znoise.noisy_sign_bits(to_tile(x_ref[...]), u, inv_ref[c], z)
+        o_ref[...] = pack_bits(bits, m_ref[...])
+
+    # a runtime sigma of 0 is the noise-free sign (stochastic_sign_bits)
+    pl.when(sig_ref[c] <= 0.0)(plain)
 
 
 def compress_rng_pallas(x2d: jax.Array, key2: jax.Array, sigma: jax.Array,
                         *, z, interpret: bool) -> jax.Array:
-    """x2d: (rows, 1024) f32 (rows % ROWS_BLK == 0), key2: (1, 2) uint32 ->
-    (rows, 128) u8 with noise generated inside each grid step."""
-    rows = x2d.shape[0]
-    n_tiles = rows // ROWS_BLK
-    tiles = jnp.arange(n_tiles, dtype=jnp.int32).reshape(-1, 1)
-    return pl.pallas_call(
-        functools.partial(_compress_rng_kernel, z=z),
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-            pl.BlockSpec((1, 2), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((ROWS_BLK, LANE), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.uint8),
-        interpret=interpret,
-    )(x2d, key2, sigma.reshape(1, 1).astype(jnp.float32), tiles)
+    """Fused encode of a client stack, the client axis folded into the GRID.
 
+    x2d: flat view (n * n_tiles * 64, 128) f32 of n clients' tile-padded
+    buffers stacked contiguously; key2: (n, 2) uint32; sigma: (n,) f32 ->
+    (n * n_tiles * 8, 128) u8.
 
-def compress_rng_pallas_batched(x2d: jax.Array, key2: jax.Array,
-                                sigma: jax.Array, *, z,
-                                interpret: bool) -> jax.Array:
-    """Client-batched fused encode: the vmap lowering of
-    :func:`compress_rng_pallas`, with the client axis folded into the GRID.
-
-    x2d: (n * rows, 1024) f32 — n clients' padded rows stacked contiguously
-    (rows % ROWS_BLK == 0); key2: (n, 2) uint32; sigma: (n,) f32 ->
-    (n * rows, 128) u8.
-
-    Same kernel body as the unbatched call: the tile-id operand carries the
-    client-LOCAL tile index and the key/sigma BlockSpecs select client c's
-    row, so every client sees exactly the counter stream of its own
-    unbatched call — bit-identical bytes. Folding the batch into the grid
-    (instead of letting vmap batch the pallas_call) keeps each grid step's
-    output write loop-indexed: JAX's pallas batching rule would instead
-    add the client axis to every dynamic-update-slice, which XLA lowers to
-    a per-tile copy of the WHOLE (n, rows, 128) buffer — the measured
-    superlinear per-client encode cost at vmap widths >= 64.
+    Every client sees exactly the counter stream of a one-client call (its
+    tile index is the grid position along its own rows), so the bytes are
+    bit-identical for any n. Folding the batch into the grid (instead of
+    letting vmap batch the pallas_call) keeps each grid step's output write
+    loop-indexed: JAX's pallas batching rule would instead add the client
+    axis to every dynamic-update-slice, which XLA lowers to a per-tile copy
+    of the WHOLE (n, rows, 128) buffer.
     """
     n = key2.shape[0]
-    rows_all = x2d.shape[0]
-    n_tiles = rows_all // n // ROWS_BLK
-    tiles = jnp.arange(n_tiles, dtype=jnp.int32).reshape(-1, 1)
+    n_tiles = x2d.shape[0] // n // FLAT_ROWS
+    sigma = sigma.reshape(n).astype(jnp.float32)
+    inv = (jnp.zeros_like(sigma) if z is None
+           else znoise.threshold_scale(sigma, z))
     return pl.pallas_call(
         functools.partial(_compress_rng_kernel, z=z),
         grid=(n, n_tiles),
         in_specs=[
-            pl.BlockSpec((ROWS_BLK, COLS), lambda c, i: (c * n_tiles + i, 0)),
-            pl.BlockSpec((1, 2), lambda c, i: (c, 0)),
-            pl.BlockSpec((1, 1), lambda c, i: (c, 0)),
-            pl.BlockSpec((1, 1), lambda c, i: (i, 0)),
+            _SMEM, _SMEM, _SMEM,
+            flat_spec(lambda c, i: (c * n_tiles + i, 0)),
+            matrix_spec((COLS, LANE)),
         ],
-        out_specs=pl.BlockSpec((ROWS_BLK, LANE), lambda c, i: (c * n_tiles + i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows_all, LANE), jnp.uint8),
+        out_specs=pl.BlockSpec((ROWS_BLK, LANE),
+                               lambda c, i: (c * n_tiles + i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n * n_tiles * ROWS_BLK, LANE),
+                                       jnp.uint8),
         interpret=interpret,
-    )(x2d, key2, sigma.reshape(-1, 1).astype(jnp.float32), tiles)
+    )(key2.reshape(-1).astype(jnp.uint32), sigma, inv, x2d, pack_matrix())
 
 
-def _unpack_sum_kernel(p_ref, o_ref):
-    p = p_ref[...]                                   # (n, R, 128) u8
-    weights = (jnp.uint8(1) << jnp.arange(PACK, dtype=jnp.uint8))
-    bits = (p[..., None] & weights) > 0              # (n, R, 128, 8)
-    pm = jnp.where(bits, jnp.float32(1), jnp.float32(-1))
-    s = jnp.sum(pm, axis=0)                          # (R, 128, 8)
-    o_ref[...] = s.reshape(s.shape[0], COLS)
-
-
-def unpack_sum_pallas(packed: jax.Array, *, interpret: bool) -> jax.Array:
-    """packed: (n_clients, rows, 128) u8 -> (rows, 1024) f32 sum of signs."""
-    n, rows, _ = packed.shape
-    grid = (rows // ROWS_BLK,)
-    return pl.pallas_call(
-        _unpack_sum_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((n, ROWS_BLK, LANE), lambda i: (0, i, 0))],
-        out_specs=pl.BlockSpec((ROWS_BLK, COLS), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, COLS), jnp.float32),
-        interpret=interpret,
-    )(packed)
-
-
-def _sign_reduce_kernel(p_ref, w_ref, o_ref):
+def _sign_reduce_kernel(w_ref, p_ref, e_ref, o_ref):
     c = pl.program_id(1)
-    p = p_ref[...]                                   # (CB, R, 128) u8
-    w = w_ref[...].reshape(-1, 1, 1, 1)              # (CB, 1, 1, 1) f32
-    bitw = (jnp.uint8(1) << jnp.arange(PACK, dtype=jnp.uint8))
-    bits = (p[..., None] & bitw) > 0                 # (CB, R, 128, 8)
-    pm = jnp.where(bits, jnp.float32(1), jnp.float32(-1))
-    part = jnp.sum(pm * w, axis=0)                   # (R, 128, 8)
-    part = part.reshape(part.shape[0], COLS)
+    spread = e_ref[...]
+    part = None
+    for j in range(CLIENT_BLK):                      # left fold, client order
+        w = w_ref[c * CLIENT_BLK + j]
+        term = jnp.where(unpack_bits(p_ref[j], spread), w, -w)
+        part = term if part is None else part + term
+
+    part = from_tile(part)
 
     @pl.when(c == 0)
     def _init():
@@ -225,8 +176,8 @@ def _sign_reduce_kernel(p_ref, w_ref, o_ref):
 
 def sign_reduce_pallas(packed: jax.Array, weights: jax.Array,
                        *, interpret: bool) -> jax.Array:
-    """packed: (n_clients, rows, 128) u8, weights: (n_clients, 1) f32 ->
-    (rows, 1024) f32 weighted sum of signs.
+    """packed: (n_clients, n_tiles * 8, 128) u8, weights: (n_clients,) f32
+    -> flat view (n_tiles * 64, 128) f32 of the weighted sum of signs.
 
     n_clients % CLIENT_BLK == 0 and rows % ROWS_BLK == 0 (caller pads; dead
     or padded clients carry weight 0 and contribute exactly 0). The client
@@ -235,15 +186,17 @@ def sign_reduce_pallas(packed: jax.Array, weights: jax.Array,
     is one wire slab + one fp32 tile, never the (n_clients, d) sign matrix.
     """
     n, rows, _ = packed.shape
-    grid = (rows // ROWS_BLK, n // CLIENT_BLK)
+    n_tiles = rows // ROWS_BLK
     return pl.pallas_call(
         _sign_reduce_kernel,
-        grid=grid,
+        grid=(n_tiles, n // CLIENT_BLK),
         in_specs=[
+            _SMEM,
             pl.BlockSpec((CLIENT_BLK, ROWS_BLK, LANE), lambda i, c: (c, i, 0)),
-            pl.BlockSpec((CLIENT_BLK, 1), lambda i, c: (c, 0)),
+            matrix_spec((LANE, COLS)),
         ],
-        out_specs=pl.BlockSpec((ROWS_BLK, COLS), lambda i, c: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, COLS), jnp.float32),
+        out_specs=flat_spec(lambda i, c: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_tiles * FLAT_ROWS, LANE),
+                                       jnp.float32),
         interpret=interpret,
-    )(packed, weights.astype(jnp.float32))
+    )(weights.reshape(n).astype(jnp.float32), packed, spread_matrix())

@@ -25,51 +25,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 _CTX = {"mesh": None, "seq_axes": None, "batch_axes": None}
 
 
-def _register_barrier_rules():
-    """Backfill JVP/transpose/vmap rules for ``optimization_barrier``.
-
-    The jax pinned in this image (0.4.x) exposes the primitive but ships no
-    differentiation or batching rules, so any grad/vmap through a barrier
-    raises NotImplementedError. The barrier is semantically the identity;
-    these rules (barrier the tangents/cotangents, pass batch dims through)
-    match what later jax versions ship natively. No-ops when the rules
-    already exist or the private layout shifts.
-    """
-    try:
-        from jax._src.lax import lax as _lax_internal
-        from jax.interpreters import ad, batching
-        prim = _lax_internal.optimization_barrier_p
-
-        if prim not in ad.primitive_jvps:
-            def _jvp(primals, tangents):
-                tangents = [ad.instantiate_zeros(t) if type(t) is ad.Zero
-                            else t for t in tangents]
-                return prim.bind(*primals), prim.bind(*tangents)
-            ad.primitive_jvps[prim] = _jvp
-
-        if prim not in ad.primitive_transposes:
-            def _transpose(cts, *primals):
-                return tuple(prim.bind(*[ad.instantiate_zeros(ct)
-                                         for ct in cts]))
-            ad.primitive_transposes[prim] = _transpose
-
-        if prim not in batching.primitive_batchers:
-            def _batch(args, dims, **params):
-                return prim.bind(*args, **params), dims
-            batching.primitive_batchers[prim] = _batch
-    except Exception:  # pragma: no cover - newer jax ships these natively
-        pass
-
-
-_register_barrier_rules()
-
-
-def opt_barrier(x):
-    """``jax.lax.optimization_barrier`` usable under grad and vmap (the
-    pinned jax lacks the rules; see _register_barrier_rules)."""
-    return jax.lax.optimization_barrier(x)
-
-
 @contextmanager
 def sharding_hints(mesh, seq_axes, batch_axes=None):
     old = dict(_CTX)
@@ -140,7 +95,7 @@ def fsdp_params(lp, *, skip=("w1", "w2", "w3")):
             # checkpoint_name lets the layer remat policy SAVE the gathered
             # copy (one gather instead of two per layer per round).
             return jax.ad_checkpoint.checkpoint_name(
-                opt_barrier(
+                jax.lax.optimization_barrier(
                     jax.lax.with_sharding_constraint(x, rep)),
                 "fsdp_gathered")
         return x

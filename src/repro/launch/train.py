@@ -9,11 +9,17 @@ runs z-SignFedAvg rounds on a deterministic token stream, samples partial
 participation with straggler over-provisioning, adapts sigma with the Plateau
 criterion, checkpoints atomically every ``--save-every`` rounds and
 self-resumes from the newest valid checkpoint on restart.
+
+``main(argv)`` is also the in-process entry point (chip_smoke.py calls it):
+it returns the final server state and a per-round record.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +33,40 @@ from repro.fed.sampling import ParticipationSampler
 from repro.models.api import build_model
 
 
-def main():
+#: the checkout this module lives in (src/repro/launch/train.py)
+REPO_ROOT = Path(__file__).resolve().parents[3]
+#: the weights are bundle.init(PRNGKey(PARAMS_SEED)); the server state's
+#: rng, from which every round's client keys derive, is PRNGKey(SERVER_SEED)
+PARAMS_SEED = 0
+SERVER_SEED = 1
+
+
+def enable_compile_cache(root: Path = REPO_ROOT) -> str:
+    """Turn on JAX's persistent compile cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is used as it is (JAX reads the
+    variable itself). Otherwise the cache is ``<root>/.jax_cache``: a fixed
+    path, because the path is part of the cache key.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(root / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+class TrainResult(NamedTuple):
+    state: fedavg.ServerState
+    #: one dict per round: round, loss, ghat_norm, live, sec
+    rounds: list
+    #: n_params, encode/agg backend and cohort plan as resolved, and for a
+    #: jitted round its compile seconds and its count of compiled Pallas
+    #: kernel calls (tpu_custom_call)
+    info: dict
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true",
@@ -108,33 +147,73 @@ def main():
     ap.add_argument("--plateau", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--save-every", type=int, default=20)
-    args = ap.parse_args()
+    return ap.parse_args(argv)
 
+
+def build_compressor(args: argparse.Namespace):
+    """The compression pipeline the arguments select."""
+    if args.pipeline:
+        return compression.Pipeline(args.pipeline)
+    # legacy per-name kwargs -> the equivalent pipeline (shim-free)
+    return {
+        "zsign": lambda: compression.ZSignCompressor(
+            z=args.z, sigma=args.sigma),
+        "zsign_packed": lambda: compression.PackedZSignCompressor(
+            z=args.z, sigma=args.sigma),
+        "dpgauss": lambda: compression.DPGaussianCompressor(
+            sigma=args.sigma),
+        "qsgd": lambda: compression.QSGDCompressor(s=args.qsgd_s),
+        "topk": lambda: compression.TopKCompressor(frac=args.topk_frac),
+        "efsign": compression.EFSignCompressor,
+        "stosign": compression.StoSignCompressor,
+        "identity": compression.Compressor,
+    }[args.compressor]()
+
+
+def fed_config(args: argparse.Namespace) -> fedavg.FedConfig:
+    return fedavg.FedConfig(n_clients=args.clients, client_groups=args.groups,
+                            local_steps=args.local_steps,
+                            client_lr=args.client_lr, server_lr=args.server_lr)
+
+
+def make_sampler(args: argparse.Namespace) -> ParticipationSampler:
+    """The participation sampler; its t-th ``mask`` is round t's mask."""
+    total = args.groups * args.clients
+    return ParticipationSampler(
+        total_clients=total,
+        per_round=max(1, int(total * args.participation)),
+        over_provision=args.over_provision, failure_rate=args.failure_rate)
+
+
+def round_batch(args: argparse.Namespace, bundle, stream: TokenStream,
+                t: int) -> dict:
+    """Round t's batch: leaves lead with (groups, clients, E, micro)."""
+    layout = (args.groups, args.clients, args.local_steps, args.micro_batch)
+    per_step = bundle.train_batch_spec(args.micro_batch, args.seq_len)
+    tokens = stream.round_batch(t, layout, args.seq_len)
+    batch = {"tokens": tokens}
+    for name, spec in per_step.items():
+        if name == "tokens":
+            continue
+        key = jax.random.fold_in(jax.random.PRNGKey(7), t)
+        batch[name] = jax.random.normal(key, layout + spec.shape[1:],
+                                        jnp.float32)
+    if "embeds" in per_step or "img_embeds" in per_step:
+        s_txt = per_step["tokens"].shape[-1]
+        batch["tokens"] = tokens[..., :s_txt]
+    return batch
+
+
+def main(argv=None) -> TrainResult:
+    args = parse_args(argv)
+    enable_compile_cache()
     arch = get_arch(args.arch)
     if args.reduced:
         arch = arch.reduced()
     bundle = build_model(arch.model)
 
-    if args.pipeline:
-        comp = compression.Pipeline(args.pipeline)
-    else:
-        # legacy per-name kwargs -> the equivalent pipeline (shim-free)
-        comp = {
-            "zsign": lambda: compression.ZSignCompressor(
-                z=args.z, sigma=args.sigma),
-            "zsign_packed": lambda: compression.PackedZSignCompressor(
-                z=args.z, sigma=args.sigma),
-            "dpgauss": lambda: compression.DPGaussianCompressor(
-                sigma=args.sigma),
-            "qsgd": lambda: compression.QSGDCompressor(s=args.qsgd_s),
-            "topk": lambda: compression.TopKCompressor(frac=args.topk_frac),
-            "efsign": compression.EFSignCompressor,
-            "stosign": compression.StoSignCompressor,
-            "identity": compression.Compressor,
-        }[args.compressor]()
-    cfg = fedavg.FedConfig(n_clients=args.clients, client_groups=args.groups,
-                           local_steps=args.local_steps,
-                           client_lr=args.client_lr, server_lr=args.server_lr)
+    comp = build_compressor(args)
+    cfg = fed_config(args)
     # ONE typed deployment policy for the round step (core/context.py):
     # CLI backend selectors, the Plateau dynamic-sigma flag, and
     # weights_are_mask=True — the ParticipationSampler below produces exact
@@ -176,9 +255,10 @@ def main():
     # one shard at a time — it must NOT be jitted (and state donation is
     # meaningless for it; the jitted PER-SHARD kernel is cached inside)
 
-    params = bundle.init(jax.random.PRNGKey(0))
+    params = bundle.init(jax.random.PRNGKey(PARAMS_SEED))
     n_params = sum(p.size for p in jax.tree_util.tree_leaves(params))
-    state = fedavg.init_server_state(params, cfg, comp, jax.random.PRNGKey(1),
+    state = fedavg.init_server_state(params, cfg, comp,
+                                     jax.random.PRNGKey(SERVER_SEED),
                                      sigma0=args.sigma)
     start_round = 0
     mgr = CheckpointManager(args.ckpt_dir) if args.ckpt_dir else None
@@ -191,56 +271,68 @@ def main():
 
     stream = TokenStream(vocab=arch.model.vocab)
     total = args.groups * args.clients
-    sampler = ParticipationSampler(
-        total_clients=total,
-        per_round=max(1, int(total * args.participation)),
-        over_provision=args.over_provision, failure_rate=args.failure_rate)
+    sampler = make_sampler(args)
     plateau = (PlateauController(sigma_init=args.sigma,
                                  sigma_bound=args.sigma * 100, kappa=10)
                if args.plateau else None)
 
-    layout = (args.groups, args.clients, args.local_steps, args.micro_batch)
-    per_step = bundle.train_batch_spec(args.micro_batch, args.seq_len)
     wf = comp.wire_format()
+    plan = fedavg.resolve_cohort(args.cohort, total, n_params)
+    info = dict(n_params=n_params,
+                encode_backend=compression.resolve_backend(
+                    "encode", args.encode_backend),
+                agg_backend=compression.resolve_backend(
+                    "agg", args.agg_backend),
+                cohort=plan._asdict())
     print(f"# arch={arch.model.name} params={n_params:,} "
           f"compressor={comp.name} wire={wf.layout}/{wf.dtype} "
           f"({wf.bits_per_coord:g} bits/coord)")
+    print(f"# device={jax.devices()[0].device_kind} "
+          f"encode={info['encode_backend']} agg={info['agg_backend']} "
+          f"cohort={plan.mode}(shard={plan.shard},devices={plan.devices})")
     print("round,loss,ghat_norm,live,Mbits_cum,sigma,sec")
 
     bits = 0.0
+    rounds = []
     for t in range(start_round, args.rounds):
-        tokens = stream.round_batch(t, layout, args.seq_len)
-        batch = {"tokens": tokens}
-        for name, spec in per_step.items():
-            if name == "tokens":
-                continue
-            key = jax.random.fold_in(jax.random.PRNGKey(7), t)
-            batch[name] = jax.random.normal(key, layout + spec.shape[1:],
-                                            jnp.float32)
-        if "embeds" in per_step or "img_embeds" in per_step:
-            s_txt = per_step["tokens"].shape[-1]
-            batch["tokens"] = tokens[..., :s_txt]
+        batch = round_batch(args, bundle, stream, t)
         mask = jnp.asarray(sampler.mask((args.groups, args.clients)))
-        t0 = time.time()
+        if checked is None and not host_loop and "compile_s" not in info:
+            # compile ahead of the first round, so that its time is set-up
+            # and not part of the round's seconds
+            t0 = time.perf_counter()
+            step = step.lower(state, batch, mask).compile()
+            info["compile_s"] = time.perf_counter() - t0
+            info["custom_calls"] = step.as_text().count(
+                'custom_call_target="tpu_custom_call"')
+            print(f"# compiled the round in {info['compile_s']:.1f}s "
+                  f"({info['custom_calls']} tpu_custom_call)")
+        t0 = time.perf_counter()
         if checked is not None:
             err, (state, m) = checked(state, batch, mask)
             err.throw()
         else:
             state, m = step(state, batch, mask)
+        jax.block_until_ready((state, m))
+        sec = time.perf_counter() - t0
         loss = float(m.loss)
         bits += float(m.uplink_bits)
         if plateau is not None:
             state = state._replace(
                 sigma=jnp.asarray(plateau.update(loss), jnp.float32))
+        rounds.append(dict(round=t, loss=loss,
+                           ghat_norm=float(m.grad_est_norm),
+                           live=int(m.participation), sec=sec))
         print(f"{t},{loss:.4f},{float(m.grad_est_norm):.3f},"
               f"{int(m.participation)},{bits/1e6:.2f},"
-              f"{float(state.sigma):.4f},{time.time()-t0:.2f}")
+              f"{float(state.sigma):.4f},{sec:.2f}")
         if mgr and (t + 1) % args.save_every == 0:
             mgr.save(t + 1, state._asdict())
     if mgr:
         mgr.save(args.rounds, state._asdict())
     print(f"# done: {args.rounds} rounds, {bits/1e6:.1f} Mbit uplink "
           f"({32.0/comp.wire_bits_per_coord:.0f}x less than fp32)")
+    return TrainResult(state, rounds, info)
 
 
 if __name__ == "__main__":

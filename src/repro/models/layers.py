@@ -100,7 +100,7 @@ def _qkv(x, lp, cfg: AttnCfg, positions):
         # otherwise fuses the fp32 upcast for the scores matmul *before* the
         # all-gather: 2x wire bytes, measured).
         q = hints.seq_shard(q, 1)
-        k, v = hints.opt_barrier(
+        k, v = jax.lax.optimization_barrier(
             (hints.gather_seq(k), hints.gather_seq(v)))
         # name the gathered K/V so the layer remat policy can SAVE them:
         # re-gathering on the remat pass costs a third of the attention
@@ -408,7 +408,7 @@ def moe_apply(x, lp, n_experts: int, top_k: int, capacity_factor: float = 1.25,
             if mesh is None:
                 return w
             from jax.sharding import NamedSharding, PartitionSpec as P
-            return hints.opt_barrier(
+            return jax.lax.optimization_barrier(
                 jax.lax.with_sharding_constraint(
                     w, NamedSharding(mesh, P("model", None, None))))
 

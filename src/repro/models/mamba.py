@@ -103,7 +103,7 @@ def mamba_block(x, lp, *, d_model: int):
     dt_rank = lp["dt_proj"].shape[0]
     seq_par = x.shape[1] > 1
     if seq_par:
-        x = H.opt_barrier(H.gather_seq(x))
+        x = jax.lax.optimization_barrier(H.gather_seq(x))
     xz = x @ lp["in_proj"]
     if seq_par:
         xz = H.shard_dim(xz, 2, ("model",))     # channel-parallel from here

@@ -68,7 +68,7 @@ def mlstm_block(x, lp, *, n_heads: int):
         kc = T
     nc = T // kc
     qf = q.astype(jnp.float32)                        # (B, H, T, hd)
-    k_g, v_g, F_g, i_g = HN.opt_barrier(
+    k_g, v_g, F_g, i_g = jax.lax.optimization_barrier(
         (HN.gather_seq(k.swapaxes(1, 2)),             # (B, T, H, hd)
          HN.gather_seq(v.swapaxes(1, 2)),
          HN.gather_seq(F.swapaxes(1, 2)),             # (B, T, H)

@@ -354,7 +354,7 @@ _STRUCTURAL = {"pad", "reshape", "squeeze", "transpose", "broadcast_in_dim",
                "convert_element_type", "slice", "dynamic_slice",
                "dynamic_update_slice", "concatenate", "copy",
                # transparent containers: their bodies are walked instead
-               "pjit", "closed_call", "custom_jvp_call", "custom_vjp_call"}
+               "jit", "closed_call", "custom_jvp_call", "custom_vjp_call"}
 
 
 def _max_f32_outvar_bytes(jaxpr):

@@ -1,5 +1,9 @@
-"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracle
-(interpret mode on CPU; identical code path runs compiled on TPU)."""
+"""Pallas kernel validation: shape/dtype sweeps vs the pure-jnp oracle.
+
+On the CPU the kernels run in interpret mode, which checks their logic but
+not what the TPU compiler accepts: tests/test_tpu_compile.py compiles them
+for a v5e chip, and chip_smoke.py compares them with the jnp backend on
+one."""
 import jax
 import jax.numpy as jnp
 import numpy as np

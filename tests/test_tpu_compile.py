@@ -1,0 +1,122 @@
+"""Every Pallas kernel compiles for a TPU v5e chip at the widths the round
+gives it.
+
+The TPU compiler is installed with jax, and it compiles for a chip that is
+described rather than attached, so these tests need no accelerator. They see
+what interpret mode cannot: block shapes the chip's tiling refuses, lowering
+rules Mosaic lacks, VMEM and HBM limits. Widths: the flat qwen2-0.5B
+pseudo-gradient (one client's full encode), and 16-client stacks for the
+batched encode and the sign-reduce.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.common import get_arch
+from repro.kernels.common import FLAT_ROWS, LANE, ROWS_BLK, TILE
+from repro.kernels.efsign import efsign as EK
+from repro.kernels.efsign import ops as eops
+from repro.kernels.zsign import ops
+from repro.kernels.zsign import zsign as K
+from repro.models.api import build_model
+
+N_CLIENTS = 16
+#: per-client width of the 16-client encode stack: 16 full-width clients
+#: (16 x 2 GB of f32 input) would not fit one chip's 16 GB
+STACK_TILES = (1 << 24) // TILE
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # can never be read back without the chip: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def n_tiles():
+    """8192-element tiles of the tile-padded qwen2-0.5B flat buffer."""
+    bundle = build_model(get_arch("qwen2_0_5b").model)
+    shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
+    d = sum(s.size for s in jax.tree_util.tree_leaves(shapes))
+    assert d > 490_000_000
+    return -(-d // TILE)
+
+
+@pytest.fixture(scope="module")
+def shape(one_chip):
+    return lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+
+
+def _compile(fn, *shapes):
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("z", [1, 0, None])
+def test_compress_rng_one_client(shape, n_tiles, z):
+    _compile(lambda x, k, s: K.compress_rng_pallas(x, k, s, z=z,
+                                                   interpret=False),
+             shape((n_tiles * FLAT_ROWS, LANE), jnp.float32),
+             shape((1, 2), jnp.uint32), shape((1,), jnp.float32))
+
+
+def test_compress_rng_client_stack(shape):
+    _compile(lambda x, k, s: K.compress_rng_pallas(x, k, s, z=1,
+                                                   interpret=False),
+             shape((N_CLIENTS * STACK_TILES * FLAT_ROWS, LANE), jnp.float32),
+             shape((N_CLIENTS, 2), jnp.uint32),
+             shape((N_CLIENTS,), jnp.float32))
+
+
+def test_encode_needs_only_its_input_and_output(shape, n_tiles):
+    """The fused encode at full width allocates the padded input and the
+    packed output, and no f32 noise surface."""
+    d = n_tiles * TILE - 100
+    compiled = _compile(
+        lambda x, k: ops.zsign_encode_fused(x, k, 0.01, z=1, interpret=False),
+        shape((d,), jnp.float32), shape((2,), jnp.uint32))
+    mem = compiled.memory_analysis()
+    padded_in, packed_out = 4 * n_tiles * TILE, n_tiles * TILE // 8
+    assert mem.temp_size_in_bytes <= padded_in + packed_out + (1 << 20), mem
+
+
+def test_sign_reduce(shape, n_tiles):
+    _compile(lambda p, w: K.sign_reduce_pallas(p, w, interpret=False),
+             shape((N_CLIENTS, n_tiles * ROWS_BLK, LANE), jnp.uint8),
+             shape((N_CLIENTS,), jnp.float32))
+
+
+def test_ef_update(shape, n_tiles):
+    view = shape((n_tiles * FLAT_ROWS, LANE), jnp.float32)
+    _compile(lambda g, e, s: EK.ef_update_pallas(g, e, s, interpret=False),
+             view, view, shape((), jnp.float32))
+
+
+def test_ef_encode_op(shape, n_tiles):
+    flat = shape((n_tiles * TILE,), jnp.float32)
+    _compile(lambda g, e: eops.ef_sign_encode(g, e, 0.5, interpret=False),
+             flat, flat)
+
+
+def test_compress_dense_noise(shape, n_tiles):
+    view = shape((n_tiles * FLAT_ROWS, LANE), jnp.float32)
+    _compile(lambda x, n, s: K.compress_pallas(x, n, s, interpret=False),
+             view, view, shape((), jnp.float32))
