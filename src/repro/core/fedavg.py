@@ -117,6 +117,7 @@ from repro.core.context import (COHORT_DEVICES_AUTO, STREAM_AUTO_MIN_ELEMS,
                                 STREAM_SHARD_MIN, CohortPolicy, RoundContext,
                                 RoundModePolicy)
 from repro.core.dp import clip_flat
+from repro.core.spans import phase
 from repro.optim.optimizers import Optimizer, make_optimizer
 
 
@@ -344,21 +345,26 @@ def client_pseudo_gradient(loss_fn: Callable, cfg: FedConfig, spec, params0,
         # sequencing). ~2x less client-side memory traffic around the
         # flatten on the CPU benchmark; identical up to f32 rounding
         # (this path skips the (gamma*g)/gamma round-trip).
-        loss, g = jax.value_and_grad(loss_fn)(
-            params0, jax.tree.map(lambda x: x[0], client_batch))
-        return spec.flatten(g), loss
+        with phase("fed.client.sgd"):
+            loss, g = jax.value_and_grad(loss_fn)(
+                params0, jax.tree.map(lambda x: x[0], client_batch))
+        with phase("fed.client.flatten"):
+            return spec.flatten(g), loss
 
     def step(p, b):
         loss, g = jax.value_and_grad(loss_fn)(p, b)
         p = jax.tree.map(lambda w, gw: w - gamma * gw.astype(w.dtype), p, g)
         return p, loss
 
-    x_e, losses = jax.lax.scan(step, params0, client_batch)
-    pseudo = jax.tree.map(
-        lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32)) / gamma,
-        params0, x_e)
-    # the ONE flatten: pytree -> contiguous fp32 wire buffer
-    return spec.flatten(pseudo), jnp.mean(losses)
+    with phase("fed.client.sgd"):
+        x_e, losses = jax.lax.scan(step, params0, client_batch)
+        loss = jnp.mean(losses)
+    with phase("fed.client.flatten"):
+        pseudo = jax.tree.map(
+            lambda a, b: (a.astype(jnp.float32) - b.astype(jnp.float32))
+            / gamma, params0, x_e)
+        # the ONE flatten: pytree -> contiguous fp32 wire buffer
+        return spec.flatten(pseudo), loss
 
 
 def _build_round_math(loss_fn: Callable, compressor, cfg: FedConfig, *,
@@ -380,16 +386,18 @@ def _build_round_math(loss_fn: Callable, compressor, cfg: FedConfig, *,
             loss_fn, cfg, spec, params0, client_batch,
             legacy_client_path=legacy_client_path)
         if cfg.dp_clip > 0.0:
-            flat = clip_flat(flat, cfg.dp_clip)
+            with phase("fed.client.flatten"):
+                flat = clip_flat(flat, cfg.dp_clip)
         # the server/spec kwargs are capability-gated: only pipelines with
         # server-scope slots receive ``server`` and only tree-structured
         # pipelines (sigma_sched) receive ``spec`` (legacy duck-typed
         # compressors keep their three-argument encode signature)
-        enc, new_cstate = compressor.encode(
-            key, flat, cstate, sigma=sigma if dynamic_sigma else None,
-            **({"server": server} if server is not None else {}),
-            **({"spec": spec}
-               if getattr(compressor, "needs_tree_spec", False) else {}))
+        with phase("fed.client.encode"):
+            enc, new_cstate = compressor.encode(
+                key, flat, cstate, sigma=sigma if dynamic_sigma else None,
+                **({"server": server} if server is not None else {}),
+                **({"spec": spec}
+                   if getattr(compressor, "needs_tree_spec", False) else {}))
         return enc, new_cstate, loss
 
     def group_encode(spec, params, group_batch, keys, group_cstate, mask_g,
@@ -450,8 +458,9 @@ def _build_round_math(loss_fn: Callable, compressor, cfg: FedConfig, *,
         enc, new_cstate, loss_sum = group_encode(
             spec, params, group_batch, keys, group_cstate, mask_g, sigma,
             idx_g, round_idx, server)
-        enc_sum = constrain_wire(
-            compressor.aggregate(enc, mask_g, spec.n_coords))
+        with phase("fed.server.fold"):
+            enc_sum = constrain_wire(
+                compressor.aggregate(enc, mask_g, spec.n_coords))
         return enc_sum, new_cstate, loss_sum
 
     return RoundMath(client_update=client_update, group_encode=group_encode,
@@ -630,8 +639,9 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
                 enc, new_cstate_s, loss_s = math.group_encode(
                     spec, params_d, batch_s, keys_s, cstate_s, mask_s,
                     sigma_d, idx_s, round_d, server_d)
-                acc = compressor.aggregate(enc, mask_s, spec.n_coords,
-                                           acc=acc)
+                with phase("fed.server.fold"):
+                    acc = compressor.aggregate(enc, mask_s, spec.n_coords,
+                                               acc=acc)
                 if fold0 is None:
                     # launcher wire constraints expect the flat buffer;
                     # the structured carry is constrained post-finalize
@@ -647,7 +657,8 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
                 params, sub, sigma, round_idx, server, s_idx, s_batch,
                 s_cstate, s_mask, constrain_wire)
             if fold0 is not None:
-                enc_sum = constrain_wire(finalize(enc_sum))
+                with phase("fed.server.fold"):
+                    enc_sum = constrain_wire(finalize(enc_sum))
         else:
             mesh = Mesh(np.asarray(jax.devices()[:devices]), ("clients",))
             rep, shd = P(), P("clients")
@@ -663,16 +674,19 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
                 # structured fold carries finalize BEFORE the psum: pending
                 # rows are positional, not additive, and the flat fp32
                 # buffer keeps the collective at one O(d) psum
-                acc = finalize(acc)
+                with phase("fed.server.fold"):
+                    acc = finalize(acc)
                 # THE cross-device reduce: one O(<= 2d) psum of the local
                 # wire accumulators (f32 sum, or the int32 vote pair for
                 # robust agg=) — compressed-domain all the way; the
                 # per-client payload stack never crosses the interconnect
-                if hasattr(compressor, "reduce_across_devices"):
-                    acc = compressor.reduce_across_devices(acc, "clients")
-                else:
-                    acc = wire.psum_accumulator(acc, "clients")
-                loss = jax.lax.psum(loss, "clients")
+                with phase("fed.server.psum"):
+                    if hasattr(compressor, "reduce_across_devices"):
+                        acc = compressor.reduce_across_devices(acc,
+                                                               "clients")
+                    else:
+                        acc = wire.psum_accumulator(acc, "clients")
+                    loss = jax.lax.psum(loss, "clients")
                 return acc, loss, cstate_out
 
             enc_sum, loss_sum, cstate_sh = jax.shard_map(
@@ -756,9 +770,10 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
                 gn = cfg.client_groups * cfg.n_clients
                 enc_all = jax.tree.map(
                     lambda e: e.reshape((gn,) + e.shape[2:]), enc_stack)
-                enc_sum = constrain_wire(
-                    compressor.aggregate(enc_all, mask.reshape(-1),
-                                         spec.n_coords))
+                with phase("fed.server.fold"):
+                    enc_sum = constrain_wire(
+                        compressor.aggregate(enc_all, mask.reshape(-1),
+                                             spec.n_coords))
             else:
                 # dense fp32 wire: accumulate the decoded group sums in the
                 # scan carry (stacking G*N dense payloads would cost G*N*d
@@ -770,8 +785,9 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
                         spec, state.params, g_batch, keys_g, cstate_g,
                         mask_g, sigma, idx_g, state.round,
                         state.comp_server)
-                    return ((enc_acc + enc_sum, loss_acc + loss_sum),
-                            new_cstate_g)
+                    with phase("fed.server.fold"):
+                        enc_acc = enc_acc + enc_sum
+                    return (enc_acc, loss_acc + loss_sum), new_cstate_g
 
                 agg_shape = jax.eval_shape(
                     lambda b, k, c, m: math.group_round(
@@ -789,6 +805,7 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
         return _finish(state, spec, rng, sigma, enc_sum, new_cstate,
                        loss_sum, mask, plan.shard)
 
+    @phase("fed.server.apply")
     def _finish(state, spec, rng, sigma, enc_sum, new_cstate, loss_sum,
                 mask, shard_used):
         n_live = jnp.maximum(jnp.sum(mask), 1.0)
@@ -850,8 +867,9 @@ def build_round_step(loss_fn: Callable, compressor, cfg: FedConfig,
                 enc, new_cstate_s, loss_s = math.group_encode(
                     spec, params, batch_s, keys_s, cstate_s, mask_s, sigma,
                     idx_s, round_idx, server)
-                acc = compressor.aggregate(enc, mask_s, spec.n_coords,
-                                           acc=acc)
+                with phase("fed.server.fold"):
+                    acc = compressor.aggregate(enc, mask_s, spec.n_coords,
+                                               acc=acc)
                 if not isinstance(acc, wire.SignFoldAcc):
                     # structured carries are constrained post-finalize;
                     # launcher wire constraints expect the flat buffer

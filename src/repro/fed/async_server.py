@@ -65,6 +65,7 @@ import numpy as np
 from repro.core import wire
 from repro.core import noise as znoise
 from repro.core.context import RoundModePolicy
+from repro.core.spans import phase
 
 #: latency model kinds (RoundContext.latency spec heads)
 LATENCY_KINDS = ("zero", "const", "linear", "lognormal", "pareto")
@@ -268,8 +269,9 @@ def build_async_round_step(*, policy: RoundModePolicy, latency_spec,
                 enc, new_cstate_s, loss_s = round_math.group_encode(
                     spec, params, batch_s, keys_s, cstate_s, mask_s, sigma,
                     idx_s, round_idx, server)
-                acc = compressor.aggregate(enc, fold_w_s, spec.n_coords,
-                                           acc=acc)
+                with phase("fed.server.fold"):
+                    acc = compressor.aggregate(enc, fold_w_s, spec.n_coords,
+                                               acc=acc)
                 if not isinstance(acc, wire.SignFoldAcc):
                     acc = constrain_wire(acc)
                 return acc, loss_acc + loss_s, new_cstate_s, enc

@@ -50,5 +50,6 @@ def ef_update_pallas(g2d, e2d, scale, *, interpret: bool):
             jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
             jax.ShapeDtypeStruct((n_tiles * ROWS_BLK, LANE), jnp.uint8),
         ],
+        name="ef_update",
         interpret=interpret,
     )(scale.reshape(1).astype(jnp.float32), g2d, e2d, pack_matrix())

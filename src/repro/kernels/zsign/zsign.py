@@ -76,6 +76,7 @@ def compress_pallas(x2d: jax.Array, noise2d: jax.Array, sigma: jax.Array,
         in_specs=[_SMEM, tile, tile, matrix_spec((COLS, LANE))],
         out_specs=pl.BlockSpec((ROWS_BLK, LANE), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * ROWS_BLK, LANE), jnp.uint8),
+        name="zsign_compress",
         interpret=interpret,
     )(sigma.reshape(1).astype(jnp.float32), x2d, noise2d, pack_matrix())
 
@@ -150,6 +151,7 @@ def compress_rng_pallas(x2d: jax.Array, key2: jax.Array, sigma: jax.Array,
                                lambda c, i: (c * n_tiles + i, 0)),
         out_shape=jax.ShapeDtypeStruct((n * n_tiles * ROWS_BLK, LANE),
                                        jnp.uint8),
+        name="compress_rng",
         interpret=interpret,
     )(key2.reshape(-1).astype(jnp.uint32), sigma, inv, x2d, pack_matrix())
 
@@ -198,5 +200,6 @@ def sign_reduce_pallas(packed: jax.Array, weights: jax.Array,
         out_specs=flat_spec(lambda i, c: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_tiles * FLAT_ROWS, LANE),
                                        jnp.float32),
+        name="sign_reduce",
         interpret=interpret,
     )(weights.reshape(n).astype(jnp.float32), packed, spread_matrix())

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs.common import get_arch
-from repro.core import compression, fedavg
+from repro.core import compression, fedavg, spans
 from repro.core.plateau import PlateauController
 from repro.data.synthetic import TokenStream
 from repro.fed.sampling import ParticipationSampler
@@ -295,41 +295,54 @@ def main(argv=None) -> TrainResult:
     bits = 0.0
     rounds = []
     for t in range(start_round, args.rounds):
-        batch = round_batch(args, bundle, stream, t)
-        mask = jnp.asarray(sampler.mask((args.groups, args.clients)))
-        if checked is None and not host_loop and "compile_s" not in info:
-            # compile ahead of the first round, so that its time is set-up
-            # and not part of the round's seconds
+        with spans.host("fed.round", step=t):
+            with spans.host("fed.feed"):
+                batch = round_batch(args, bundle, stream, t)
+                mask = jnp.asarray(sampler.mask((args.groups, args.clients)))
+            if checked is None and not host_loop and "compile_s" not in info:
+                # compile ahead of the first round, so that its time is
+                # set-up and not part of the round's seconds
+                log0 = spans.COMPILES.snapshot()
+                t0 = time.perf_counter()
+                with spans.host("fed.compile"):
+                    step = step.lower(state, batch, mask).compile()
+                info["compile_s"] = time.perf_counter() - t0
+                log1 = spans.COMPILES.snapshot()
+                info["compile_log"] = {k: log1[k] - log0[k] for k in log1}
+                info["custom_calls"] = step.as_text().count(
+                    'custom_call_target="tpu_custom_call"')
+                c = info["compile_log"]
+                print(f"# compiled the round in {info['compile_s']:.1f}s "
+                      f"({info['custom_calls']} tpu_custom_call; "
+                      f"{c['compiles']} compiles, {c['cache_hits']} cache "
+                      f"hits: trace {c['trace_s']:.1f}s, lower "
+                      f"{c['lower_s']:.1f}s, compile or cache load "
+                      f"{c['compile_s']:.1f}s)")
             t0 = time.perf_counter()
-            step = step.lower(state, batch, mask).compile()
-            info["compile_s"] = time.perf_counter() - t0
-            info["custom_calls"] = step.as_text().count(
-                'custom_call_target="tpu_custom_call"')
-            print(f"# compiled the round in {info['compile_s']:.1f}s "
-                  f"({info['custom_calls']} tpu_custom_call)")
-        t0 = time.perf_counter()
-        if checked is not None:
-            err, (state, m) = checked(state, batch, mask)
-            err.throw()
-        else:
-            state, m = step(state, batch, mask)
-        jax.block_until_ready((state, m))
-        sec = time.perf_counter() - t0
-        loss = float(m.loss)
-        bits += float(m.uplink_bits)
-        if plateau is not None:
-            state = state._replace(
-                sigma=jnp.asarray(plateau.update(loss), jnp.float32))
-        rounds.append(dict(round=t, loss=loss,
-                           ghat_norm=float(m.grad_est_norm),
-                           live=int(m.participation), sec=sec))
-        print(f"{t},{loss:.4f},{float(m.grad_est_norm):.3f},"
-              f"{int(m.participation)},{bits/1e6:.2f},"
-              f"{float(state.sigma):.4f},{sec:.2f}")
-        if mgr and (t + 1) % args.save_every == 0:
-            mgr.save(t + 1, state._asdict())
+            if checked is not None:
+                err, (state, m) = checked(state, batch, mask)
+                err.throw()
+            else:
+                state, m = step(state, batch, mask)
+            jax.block_until_ready((state, m))
+            sec = time.perf_counter() - t0
+            loss = float(m.loss)
+            bits += float(m.uplink_bits)
+            if plateau is not None:
+                state = state._replace(
+                    sigma=jnp.asarray(plateau.update(loss), jnp.float32))
+            rounds.append(dict(round=t, loss=loss,
+                               ghat_norm=float(m.grad_est_norm),
+                               live=int(m.participation), sec=sec))
+            print(f"{t},{loss:.4f},{float(m.grad_est_norm):.3f},"
+                  f"{int(m.participation)},{bits/1e6:.2f},"
+                  f"{float(state.sigma):.4f},{sec:.2f}")
+            if mgr and (t + 1) % args.save_every == 0:
+                with spans.host("fed.checkpoint"):
+                    mgr.save(t + 1, state._asdict())
     if mgr:
-        mgr.save(args.rounds, state._asdict())
+        with spans.host("fed.checkpoint"):
+            mgr.save(args.rounds, state._asdict())
     print(f"# done: {args.rounds} rounds, {bits/1e6:.1f} Mbit uplink "
           f"({32.0/comp.wire_bits_per_coord:.0f}x less than fp32)")
     return TrainResult(state, rounds, info)

@@ -15,6 +15,7 @@ import jax
 import jax.ad_checkpoint
 import jax.numpy as jnp
 
+from repro.core.spans import phase
 from repro.launch import hints
 
 
@@ -183,6 +184,7 @@ def _flash_kv_attention(q, k, v, positions, cfg: AttnCfg, kv_chunk: int):
     return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H * hd).astype(q.dtype)
 
 
+@phase("model.attn")
 def attention(x, lp, cfg: AttnCfg, positions):
     """Training attention. x: (B, S, D) -> (B, S, D).
 
