@@ -296,10 +296,11 @@ def sign_reduce(packed: jax.Array, weights: jax.Array,
     client remainders so the result is bit-identical to one concatenated
     call at ANY shard size — that route always runs through
     ``wire.unpack_sum`` (the pending rows are positional state the kernel
-    has no inlet for; streaming folds are host/CPU-driven paths). The
-    Pallas kernel has no in-kernel init accumulator, so that backend adds a
-    flat ``acc`` to the kernel's blocked sum — still integer-exact for 0/1
-    masks.
+    has no inlet for). The Pallas kernel takes a flat ``acc`` as its
+    in-place accumulator: it is aliased onto the kernel's output and the
+    client blocks are folded into it in order, the left fold of
+    ``wire.unpack_sum``, so the two backends agree to the bit for any
+    weights.
 
     ``debug`` turns on the dynamic membership assertion of the popcount
     path (``wire.check_mask_membership``; debug-wire mode) — it only fires
@@ -310,8 +311,7 @@ def sign_reduce(packed: jax.Array, weights: jax.Array,
         return unpack_sum(packed, weights, acc)
     if backend == "pallas":
         from repro.kernels.zsign import ops as K
-        out = K.sign_reduce(packed, weights)
-        return out if acc is None else acc + out
+        return K.sign_reduce(packed, weights, acc)
     if backend == "dense":
         return wire.unpack_sum_dense(packed, weights, acc)
     if weights_are_mask:

@@ -167,6 +167,9 @@ def pack_flat(flat: jax.Array) -> jax.Array:
 # the jnp fallback below accumulates in the same blocked client order as the
 # Pallas sign_reduce kernel, so the CPU path and the TPU kernel produce
 # bit-identical f32 sums for ANY per-client weights (not just 0/1 masks).
+# A stack under 8 clients is one block of its own size in the kernel and
+# one zero-padded block of 8 here: the same in-block left fold, as the
+# zero-weight terms change at most the sign of a zero.
 SIGN_REDUCE_CLIENT_BLK = 8
 
 
@@ -247,10 +250,11 @@ def unpack_sum(packed: jax.Array, weights: jax.Array,
     Accumulation order mirrors the Pallas ``sign_reduce`` kernel: clients
     are padded to SIGN_REDUCE_CLIENT_BLK with zero weight, the in-block
     8-element reduce happens at LUT build time in client order, and block
-    partials are added sequentially — bit-exact vs the kernel for ANY fp32
-    weights (verified in tests/test_sign_reduce.py), exact vs any order for
-    0/1 masks (integer sums), and within 1 ulp/client of the legacy dense
-    path (``unpack_sum_dense``).
+    partials are added sequentially onto ``acc`` — bit-exact vs the kernel,
+    carried sum included, for ANY fp32 weights up to the sign of a zero
+    (verified in tests/test_sign_reduce.py), exact vs any order for 0/1
+    masks (integer sums), and within 1 ulp/client of the legacy dense path
+    (``unpack_sum_dense``).
     """
     if isinstance(acc, SignFoldAcc):
         return _sign_fold_step(packed, weights, acc)

@@ -77,13 +77,15 @@ def flat_spec(index_map):
 
 
 def to_tile(block: jax.Array) -> jax.Array:
-    """(FLAT_ROWS, LANE) -> (ROWS_BLK, COLS), the same elements in order."""
-    return block.reshape(ROWS_BLK, COLS)
+    """(t * FLAT_ROWS, LANE) -> (t * ROWS_BLK, COLS), the same elements in
+    order, for a block of t tiles."""
+    return block.reshape(-1, COLS)
 
 
 def from_tile(tile: jax.Array) -> jax.Array:
-    """(ROWS_BLK, COLS) -> (FLAT_ROWS, LANE), the inverse of :func:`to_tile`."""
-    return tile.reshape(FLAT_ROWS, LANE)
+    """(t * ROWS_BLK, COLS) -> (t * FLAT_ROWS, LANE), the inverse of
+    :func:`to_tile`."""
+    return tile.reshape(-1, LANE)
 
 
 def matrix_spec(shape):

@@ -150,27 +150,34 @@ def zsign_encode_fused(x: jax.Array, key: jax.Array, sigma,
 
 @partial(jax.jit, static_argnames=("interpret",))
 def sign_reduce(packed: jax.Array, weights: jax.Array,
+                acc: jax.Array | None = None,
                 *, interpret: bool | None = None) -> jax.Array:
     """Fused weighted sign-reduce: (n_clients, n_bytes) u8 + (n_clients,)
-    f32 -> (8*n_bytes,) f32 weighted sum of the +/-1 signs.
+    f32 -> (8*n_bytes,) f32 weighted sum of the +/-1 signs, plus ``acc``.
 
     ONE kernel launch for the whole client stack (clients folded into the
-    grid, VMEM accumulator per output tile) — replaces the per-client-row
-    vmap over ``zsign_decompress_sum``. Clients are padded to CLIENT_BLK
-    with zero weight, bytes to the (ROWS_BLK * LANE) tile; both pads
-    contribute exactly 0.
+    grid, the sum resident in VMEM per output block). A stack of fewer than
+    CLIENT_BLK clients is one block of its own size; a larger one is padded
+    to whole blocks of CLIENT_BLK with zero weight, and bytes to the
+    (ROWS_BLK * LANE) tile; both pads contribute exactly 0. A flat
+    (8*n_bytes,) f32 ``acc`` is aliased onto the kernel's output and folded
+    in first, ``((acc + b_0) + b_1) + ...``: the sum is updated in place,
+    with no separate add. The round's wires are whole tiles, so ``acc``
+    is only padded (copied) for widths off the tile.
     """
     interpret = interpret_mode() if interpret is None else interpret
     n, nbytes = packed.shape
     bpad = (-nbytes) % (ROWS_BLK * LANE)
-    cpad = (-n) % K.CLIENT_BLK
+    cpad = (-n) % min(n, K.CLIENT_BLK)
     if bpad or cpad:
         packed = jnp.pad(packed, ((0, cpad), (0, bpad)))
     w = weights.astype(jnp.float32)
     if cpad:
         w = jnp.pad(w, (0, cpad))
+    if acc is not None:
+        acc = flat_view(jnp.pad(acc, (0, 8 * bpad)) if bpad else acc)
     p3 = packed.reshape(n + cpad, -1, LANE)
-    s = K.sign_reduce_pallas(p3, w, interpret=interpret)
+    s = K.sign_reduce_pallas(p3, w, acc, interpret=interpret)
     return s.reshape(-1)[: nbytes * 8]
 
 

@@ -20,18 +20,25 @@ Three kernels:
                      is the outer grid axis: one launch encodes a whole
                      client stack.
   _sign_reduce_kernel: (n_clients, ...) packed uint8 + (n_clients,) fp32
-                     weights -> weighted sum of {-1,+1} fp32, with the client
-                     axis folded into the grid and a VMEM accumulator per
-                     output tile. This is the fused server aggregation: the
-                     dense (n_clients, d) fp32 sign matrix never exists —
-                     each grid step expands one CLIENT_BLK x tile slab of
-                     wire bytes in VMEM, multiplies by the per-client
-                     weights, and accumulates into the revisited output tile.
+                     weights [+ the carried fp32 sum] -> that sum plus the
+                     weighted sum of {-1,+1} fp32, with the client axis
+                     folded into the grid and the output block resident in
+                     VMEM while every client block streams past it. This is
+                     the fused server aggregation: the dense (n_clients, d)
+                     fp32 sign matrix never exists. A client block is the
+                     whole stack under CLIENT_BLK clients (no dead rows),
+                     else CLIENT_BLK; a grid step covers as many tiles as
+                     REDUCE_VMEM allows, so the MXU unpack runs on
+                     (8 * tiles, 128) bytes at once. The carried sum is
+                     aliased onto the output and added at the first client
+                     block: a streamed fold reads and writes the sum once
+                     per call, in place.
 
-The kernels stream HBM->VMEM one 8192-element f32 tile per grid step (a
-(64, 128) block of the flat view, worked on as (8, 1024)) and write
-(8, 128) uint8 tiles; the layout, the bit-pack and the unpack are the
-shared forms of kernels/common.py. Per-client scalars (key words, sigma, the
+The encode kernels stream HBM->VMEM one 8192-element f32 tile per grid
+step (a (64, 128) block of the flat view, worked on as (8, 1024)) and write
+(8, 128) uint8 tiles; the reduce takes a run of such tiles per step. The
+layout, the bit-pack and the unpack are the shared forms of
+kernels/common.py. Per-client scalars (key words, sigma, the
 threshold scale, reduce weights) live in SMEM; the tile index is the grid
 position. The counter scheme was chosen over pltpu.prng_random_bits because
 the hardware PRNG's stream cannot be reproduced off-TPU — threefry2x32 is
@@ -54,7 +61,8 @@ from repro.kernels.common import (COLS, FLAT_ROWS, LANE, ROWS_BLK, TILE,
                                   pack_matrix, spread_matrix, to_tile,
                                   unpack_bits)
 
-CLIENT_BLK = 8              # clients per sign-reduce grid step
+CLIENT_BLK = 8              # clients per sign-reduce block, stacks of 8+
+REDUCE_VMEM = 8 << 20       # VMEM budget of one sign-reduce grid step
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
@@ -156,12 +164,25 @@ def compress_rng_pallas(x2d: jax.Array, key2: jax.Array, sigma: jax.Array,
     )(key2.reshape(-1).astype(jnp.uint32), sigma, inv, x2d, pack_matrix())
 
 
-def _sign_reduce_kernel(w_ref, p_ref, e_ref, o_ref):
+def reduce_tiles(n_tiles: int, blk: int) -> int:
+    """Tiles per sign-reduce grid step for a client block of ``blk`` rows.
+
+    The most that fit REDUCE_VMEM, rounded down to a power of two: the
+    double-buffered f32 accumulator and output blocks and ``blk`` packed
+    rows per tile, plus about three f32 tiles of temporaries. All of a
+    shorter buffer is one block."""
+    per_tile = 2 * (2 * 4 * TILE + blk * TILE // 8) + 3 * 4 * TILE
+    t = 1 << ((REDUCE_VMEM // per_tile).bit_length() - 1)
+    return n_tiles if n_tiles <= t else t
+
+
+def _sign_reduce_kernel(w_ref, p_ref, e_ref, *refs, blk):
+    a_ref, o_ref = refs if len(refs) == 2 else (None, refs[0])
     c = pl.program_id(1)
     spread = e_ref[...]
     part = None
-    for j in range(CLIENT_BLK):                      # left fold, client order
-        w = w_ref[c * CLIENT_BLK + j]
+    for j in range(blk):                             # left fold, client order
+        w = w_ref[c * blk + j]
         term = jnp.where(unpack_bits(p_ref[j], spread), w, -w)
         part = term if part is None else part + term
 
@@ -169,7 +190,7 @@ def _sign_reduce_kernel(w_ref, p_ref, e_ref, o_ref):
 
     @pl.when(c == 0)
     def _init():
-        o_ref[...] = part
+        o_ref[...] = part if a_ref is None else a_ref[...] + part
 
     @pl.when(c != 0)
     def _acc():
@@ -177,29 +198,45 @@ def _sign_reduce_kernel(w_ref, p_ref, e_ref, o_ref):
 
 
 def sign_reduce_pallas(packed: jax.Array, weights: jax.Array,
+                       acc: jax.Array | None = None,
                        *, interpret: bool) -> jax.Array:
-    """packed: (n_clients, n_tiles * 8, 128) u8, weights: (n_clients,) f32
-    -> flat view (n_tiles * 64, 128) f32 of the weighted sum of signs.
+    """packed: (n_clients, n_tiles * 8, 128) u8, weights: (n_clients,) f32,
+    acc: None or a flat view (n_tiles * 64, 128) f32 -> the flat view of
+    ``acc`` plus the weighted sum of signs.
 
-    n_clients % CLIENT_BLK == 0 and rows % ROWS_BLK == 0 (caller pads; dead
-    or padded clients carry weight 0 and contribute exactly 0). The client
-    axis is the INNER grid dimension, so each output tile stays resident in
-    VMEM while every client block streams past it — the server's working set
-    is one wire slab + one fp32 tile, never the (n_clients, d) sign matrix.
+    The client block is the whole stack when it has fewer than CLIENT_BLK
+    rows, else CLIENT_BLK rows, and n_clients must be a multiple of it
+    (the caller pads with weight-0 rows, which contribute exactly 0). The
+    client axis is the INNER grid dimension, so each output block stays
+    resident in VMEM while every client block streams past it; each step
+    covers :func:`reduce_tiles` tiles, the last block ragged. ``acc`` is
+    aliased onto the output and folded in first, in place:
+    ``((acc + b_0) + b_1) + ...`` over the client blocks, the order of
+    ``wire.unpack_sum``.
     """
     n, rows, _ = packed.shape
     n_tiles = rows // ROWS_BLK
+    blk = min(n, CLIENT_BLK)
+    t = reduce_tiles(n_tiles, blk)
+    out_spec = pl.BlockSpec((t * FLAT_ROWS, LANE), lambda i, c: (i, 0))
+    operands = [weights.reshape(n).astype(jnp.float32), packed,
+                spread_matrix()]
+    in_specs = [
+        _SMEM,
+        pl.BlockSpec((blk, t * ROWS_BLK, LANE), lambda i, c: (c, i, 0)),
+        matrix_spec((LANE, COLS)),
+    ]
+    if acc is not None:
+        operands.append(acc)
+        in_specs.append(out_spec)
     return pl.pallas_call(
-        _sign_reduce_kernel,
-        grid=(n_tiles, n // CLIENT_BLK),
-        in_specs=[
-            _SMEM,
-            pl.BlockSpec((CLIENT_BLK, ROWS_BLK, LANE), lambda i, c: (c, i, 0)),
-            matrix_spec((LANE, COLS)),
-        ],
-        out_specs=flat_spec(lambda i, c: (i, 0)),
+        functools.partial(_sign_reduce_kernel, blk=blk),
+        grid=(pl.cdiv(n_tiles, t), n // blk),
+        in_specs=in_specs,
+        out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((n_tiles * FLAT_ROWS, LANE),
                                        jnp.float32),
+        input_output_aliases={} if acc is None else {3: 0},
         name="sign_reduce",
         interpret=interpret,
-    )(weights.reshape(n).astype(jnp.float32), packed, spread_matrix())
+    )(*operands)

@@ -94,6 +94,80 @@ def test_kernel_zero_weight_rows_contribute_nothing():
         got, np.asarray(wire.unpack_sum(packed, w * mask)))
 
 
+@pytest.mark.parametrize("weights", ["mask", "fractional"])
+@pytest.mark.parametrize("n", [1, 3, 8, 13, 16])
+def test_pallas_fold_into_acc_matches_jnp_bit_exact(n, weights):
+    """A carried flat sum folds into the kernel in place, in the left-fold
+    order of wire.unpack_sum, so the two agree to the bit for any weights.
+    33 tiles: more than one grid step, the last one ragged."""
+    n_bytes = 33 * 1024
+    rng = np.random.RandomState(n * 101 + len(weights))
+    packed = _payload(rng, n, n_bytes)
+    w = (rng.randint(0, 2, n) if weights == "mask" else rng.randn(n))
+    w = jnp.asarray(w.astype(np.float32))
+    acc = jnp.asarray(rng.randn(8 * n_bytes).astype(np.float32))
+    got = C.sign_reduce(packed, w, "pallas", acc=acc)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(wire.unpack_sum(packed, w, acc)))
+
+
+def test_pallas_fold_off_tile_width():
+    """A width off the tile pads the carried sum with the bytes, and only
+    the leading coordinates come back."""
+    rng = np.random.RandomState(5)
+    packed = _payload(rng, 3, 1000)
+    w = jnp.asarray(rng.randn(3).astype(np.float32))
+    acc = jnp.asarray(rng.randn(8000).astype(np.float32))
+    got = ops.sign_reduce(packed, w, acc)
+    assert got.shape == (8000,)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(wire.unpack_sum(packed, w, acc)))
+
+
+def _outer_eqns(jaxpr):
+    """Every equation, nested jits included, but not a kernel's body."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name == "pallas_call":
+            continue
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                yield from _outer_eqns(inner)
+
+
+@pytest.mark.parametrize("n", [1, 3])
+def test_pallas_fold_is_in_place(n):
+    """The Pallas route hands the carried sum to the kernel as the operand
+    its output aliases: no add of the sum after the kernel, and a stack
+    under CLIENT_BLK is not padded with dead rows."""
+    n_bytes = 2 * 1024
+    jaxpr = jax.make_jaxpr(
+        lambda p, w, a: C.sign_reduce(p, w, "pallas", acc=a))(
+            jnp.zeros((n, n_bytes), jnp.uint8), jnp.ones((n,)),
+            jnp.zeros((8 * n_bytes,)))
+    eqns = list(_outer_eqns(jaxpr.jaxpr))
+    names = [e.primitive.name for e in eqns]
+    calls = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    (alias,) = calls[0].params["input_output_aliases"]
+    assert alias[1] == 0
+    aliased = calls[0].invars[alias[0]]
+    assert aliased.aval.shape == (8 * n_bytes // 128, 128)
+    assert aliased.aval.dtype == jnp.float32
+    assert "add" not in names
+    assert "pad" not in names
+
+
+@pytest.mark.parametrize("n_tiles,blk,want", [
+    (1, 1, 1), (13, 1, 13), (32, 1, 32), (33, 1, 32), (60307, 1, 32),
+    (73220, 8, 32), (60307, 3, 32)])
+def test_reduce_tiles_per_step(n_tiles, blk, want):
+    """Tiles per grid step: a power of two under the VMEM budget, or the
+    whole buffer when it is shorter."""
+    assert ZK.reduce_tiles(n_tiles, blk) == want
+
+
 @pytest.mark.parametrize("d", [8, 64, 8192, 8192 * 2 + 136, 100_008])
 def test_tile_and_pack_padding(d):
     """d off the 8192-element kernel tile: padded bytes/clients never leak

@@ -5,8 +5,9 @@ The TPU compiler is installed with jax, and it compiles for a chip that is
 described rather than attached, so these tests need no accelerator. They see
 what interpret mode cannot: block shapes the chip's tiling refuses, lowering
 rules Mosaic lacks, VMEM and HBM limits. Widths: the flat qwen2-0.5B
-pseudo-gradient (one client's full encode), and 16-client stacks for the
-batched encode and the sign-reduce.
+pseudo-gradient (one client's full encode and its in-place fold into the
+server's sum), and 16-client stacks for the batched encode and the
+sign-reduce.
 """
 import os
 
@@ -102,6 +103,22 @@ def test_sign_reduce(shape, n_tiles):
     _compile(lambda p, w: K.sign_reduce_pallas(p, w, interpret=False),
              shape((N_CLIENTS, n_tiles * ROWS_BLK, LANE), jnp.uint8),
              shape((N_CLIENTS,), jnp.float32))
+
+
+def test_sign_reduce_folds_one_client_in_place(shape, n_tiles):
+    """The streamed fold of one full-width client into the donated f32 sum
+    writes the sum in place: no copy of it, no pad of the payload to a
+    block of CLIENT_BLK clients."""
+    n_bytes = n_tiles * TILE // 8
+    compiled = jax.jit(lambda p, w, a: ops.sign_reduce(p, w, a,
+                                                       interpret=False),
+                       donate_argnums=2).lower(
+        shape((1, n_bytes), jnp.uint8), shape((1,), jnp.float32),
+        shape((8 * n_bytes,), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 4 * n_tiles * TILE, mem
+    assert mem.temp_size_in_bytes <= 4 << 20, mem
 
 
 def test_ef_update(shape, n_tiles):
