@@ -6,8 +6,10 @@ Everything a cell names is found by name: the cell in ``BENCHMARK.json``,
 its configuration at the ``file`` that ``BENCHMARK.json`` gives, its traffic
 mix at ``bench/traffic/<traffic>.json``, every metric's reader at
 ``bench/metrics/<name>.py`` (a ``read(ctx)`` that returns a number, or None
-where it finds nothing to read), and the cell's limits at
-``bench/limits/<cell>.json``.
+where it finds nothing to read), the cell's limits at
+``bench/limits/<cell>.json``, and the configuration's model family (its
+reference, configuration check and FLOP count) at
+``bench/reference/<reference>.py``.
 """
 from __future__ import annotations
 
@@ -132,7 +134,8 @@ def reference_readings(config, traffic, prog, seed: int, precision="f32",
 
     from bench import inputs
     from bench.reference.round import Reference
-    ref = Reference(config, setting(config, traffic, prog), precision, fault)
+    ref = Reference(prog.family, config, setting(config, traffic, prog),
+                    precision, fault)
     dkey = inputs.data_key(seed)
     lead = (prog.clients,) + prog.layout[2:] + (prog.seq,)
     if mask is None:
@@ -279,9 +282,8 @@ def run(root: Path, cell_name: str, seed: int, seconds: float, traced: bool,
                     if int(d[len(trace.DEVICE_PREFIX):]) < len(used)}
         d = work.n_params(prog.shapes)
         ctx.work.update(
-            flops_per_token=work.flops_per_token(
-                prog.shapes, prog.model.n_layers, prog.model.n_heads,
-                prog.model.d_head, prog.seq),
+            flops_per_token=prog.family.flops_per_token(
+                config, prog.shapes, prog.seq),
             encode_bytes=work.encode_bytes(prog.clients, d),
             reduce_bytes=work.reduce_bytes(int(np.sum(np.asarray(mask))), d))
         device.update(busy_s=trace.mean(ctx.busy.values()),

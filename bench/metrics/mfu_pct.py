@@ -2,8 +2,9 @@
 window's length times the chips' bf16 peak, in percent.
 
 Layer: the round step (``core/fedavg.build_round_step``) as a whole. FLOPs
-per token are ``bench/work.flops_per_token``: 6 N_matmul plus causal
-attention, with nothing counted for recompute. Moves ``client_tokens_per_s``.
+per token are the configuration's family module's ``flops_per_token``
+(``bench/reference/<reference>.py``), with nothing counted for recompute.
+Moves ``client_tokens_per_s``.
 """
 
 

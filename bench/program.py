@@ -1,15 +1,18 @@
 """The system under test: the jitted round step of ``core/fedavg``, built from
 the program's own pieces as ``launch/train.main`` builds it, for one
-configuration file and one traffic file.
+configuration file and one traffic file, with the configuration's model
+family module (``bench/reference/<reference>.py``).
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib.util
 import inspect
 import math
 import re
 import sys
 from pathlib import Path
+from types import ModuleType
 from typing import Any, NamedTuple
 
 import jax
@@ -17,17 +20,14 @@ import jax.numpy as jnp
 
 from bench import inputs
 
-#: configuration-file key -> the program's ModelCfg field it must equal
+#: configuration-file key -> the program's ModelCfg field it must equal, for
+#: the keys every language model's configuration has; the family module
+#: checks the rest
 MODEL_FIELDS = {
     "hidden_size": "d_model",
-    "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
     "num_hidden_layers": "n_layers",
     "vocab_size": "vocab",
     "tie_word_embeddings": "tie_embeddings",
-    "attention_bias": "qkv_bias",
-    "rope_theta": "rope_theta",
 }
 
 
@@ -43,6 +43,7 @@ class Program(NamedTuple):
     layout: tuple       # (groups, clients, E, micro)
     seq: int
     plan: Any           # the fedavg.CohortPlan the round runs
+    family: ModuleType  # bench/reference/<reference>.py
 
     @property
     def clients(self) -> int:
@@ -65,29 +66,41 @@ def train_argv(config: dict, traffic: dict) -> list:
     return argv
 
 
-def check_model(model, config: dict) -> None:
-    """The configuration file has to describe what runs."""
+def load_family(config: dict, root: Path) -> ModuleType:
+    """The model family module the configuration names with its
+    ``"reference"`` key: ``bench/reference/<reference>.py``."""
+    name, where = config.get("reference"), root / "bench" / "reference"
+    if not (isinstance(name, str) and name.isidentifier()):
+        raise ValueError(f"configuration {config['name']!r} names no model "
+                         f"family module: its \"reference\" is {name!r}, "
+                         f"where {where}/<reference>.py is looked for")
+    path = where / f"{name}.py"
+    if not path.is_file():
+        raise ValueError(f"configuration {config['name']!r} names the model "
+                         f"family {name!r}: looked for {path}, not found")
+    spec = importlib.util.spec_from_file_location(f"bench_family_{name}",
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def check_model(model, config: dict, family: ModuleType) -> None:
+    """The configuration file has to describe what runs: the keys every
+    language model has here, the rest in the family module's ``check``."""
     from repro.models import layers
     wrong = {k: (config[k], getattr(model, f)) for k, f in MODEL_FIELDS.items()
              if config[k] != getattr(model, f)}
     if jnp.dtype(model.dtype).name != config["torch_dtype"]:
         wrong["torch_dtype"] = (config["torch_dtype"],
                                 jnp.dtype(model.dtype).name)
-    if not math.isclose(config["attention_multiplier"], model.d_head ** -0.5):
-        wrong["attention_multiplier"] = (config["attention_multiplier"],
-                                         model.d_head ** -0.5)
-    for k in ("embedding_multiplier", "residual_multiplier",
-              "logits_scaling"):
-        if config[k] != 1.0:
-            wrong[k] = (config[k], 1.0)
-    eps = inspect.signature(layers.rms_norm).parameters["eps"].default
-    if not math.isclose(config["rms_norm_eps"], eps):
-        wrong["rms_norm_eps"] = (config["rms_norm_eps"], eps)
-    if model.family != "dense" or model.sliding_window:
-        wrong["family"] = (model.family, model.sliding_window)
+    facts = {"rms_norm_eps":
+             inspect.signature(layers.rms_norm).parameters["eps"].default}
+    wrong.update(family.check(config, model, facts))
     if wrong:
         raise ValueError(f"configuration {config['name']!r} does not describe "
-                         f"the model that runs (file, program): {wrong}")
+                         f"the model that runs, checked with "
+                         f"{family.__file__} (file, program): {wrong}")
 
 
 def build(config: dict, traffic: dict, root: Path) -> Program:
@@ -99,10 +112,11 @@ def build(config: dict, traffic: dict, root: Path) -> Program:
     from repro.launch import train
     from repro.models.api import build_model
 
+    family = load_family(config, root)
     args = train.parse_args(train_argv(config, traffic))
     model = dataclasses.replace(get_arch(args.arch).model,
                                 **config["program"].get("model", {}))
-    check_model(model, config)
+    check_model(model, config, family)
     bundle = build_model(model)
     comp = train.build_compressor(args)
     fed = train.fed_config(args)
@@ -120,7 +134,7 @@ def build(config: dict, traffic: dict, root: Path) -> Program:
                    sampler=train.make_sampler(args), step=step, shapes=shapes,
                    layout=(args.groups, args.clients, args.local_steps,
                            args.micro_batch),
-                   seq=args.seq_len, plan=plan)
+                   seq=args.seq_len, plan=plan, family=family)
 
 
 def init_state(prog: Program, params, seed: int):

@@ -1,4 +1,25 @@
-"""A plain float32 decoder-only transformer: loss and gradients.
+"""The dense model family: a plain float32 decoder-only transformer (its
+loss and gradients), the check that a configuration describes the program's
+dense model, and the training FLOPs one token costs in it.
+
+A configuration names its family with its ``"reference"`` key; the harness
+loads ``bench/reference/<reference>.py`` and uses three things of it:
+
+- ``loss(cfg, params, tokens, quantize=identity)``: the mean next-token
+  cross entropy, in float32 at HIGHEST precision, with ``quantize`` applied
+  where the lower-precision control rounds;
+- ``check(config, model, program_facts) -> {key: (file, program)}``: what in
+  the configuration does not match the program's ``ModelCfg`` beyond the
+  fields every configuration has (``bench/program.check_model``);
+  ``program_facts`` holds what only the program's code states (here the
+  default eps of its RMSNorm), so that the family imports nothing of it;
+- ``flops_per_token(config, shapes, seq)``: training FLOPs per token, forward
+  and backward, no recompute, of what one token computes in this family.
+
+A family module imports nothing of the program. Its weights come from
+``bench/inputs.py``, which draws each leaf by its name: ``ln*`` ones,
+``embed`` and ``b*`` N(0, 0.02), any other leaf N(0, 1/shape[-2]). A family
+names its leaves so that each gets the draw it needs.
 
 The dense architecture of Qwen2 and Granite 3.0 as their papers and
 ``config.json`` describe it: token embedding (times ``embedding_multiplier``),
@@ -22,10 +43,26 @@ from the configuration file as it is run (see its ``reduced`` list).
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+#: configuration-file key -> the program's ModelCfg field it must equal
+MODEL_FIELDS = {
+    "intermediate_size": "d_ff",
+    "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads",
+    "attention_bias": "qkv_bias",
+    "rope_theta": "rope_theta",
+}
+
+#: leaves that are matrices of a matmul in the forward pass; the tied
+#: embedding is counted once, as the output head (its lookup is no matmul)
+MATMUL_LEAVES = ("wq", "wk", "wv", "wo", "w1", "w2", "w3", "embed",
+                 "lm_head")
 
 
 def identity(x):
@@ -105,3 +142,40 @@ def loss(cfg, params, tokens, quantize=identity):
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, tokens[:, 1:, None], axis=-1)
     return jnp.mean(nll)
+
+
+def check(config, model, program_facts) -> dict:
+    """What does not match between the configuration and the program's
+    dense ``ModelCfg``, as ``{key: (file, program)}``."""
+    wrong = {k: (config[k], getattr(model, f)) for k, f in MODEL_FIELDS.items()
+             if config[k] != getattr(model, f)}
+    if not math.isclose(config["attention_multiplier"], model.d_head ** -0.5):
+        wrong["attention_multiplier"] = (config["attention_multiplier"],
+                                         model.d_head ** -0.5)
+    for k in ("embedding_multiplier", "residual_multiplier",
+              "logits_scaling"):
+        if config[k] != 1.0:
+            wrong[k] = (config[k], 1.0)
+    eps = program_facts["rms_norm_eps"]
+    if not math.isclose(config["rms_norm_eps"], eps):
+        wrong["rms_norm_eps"] = (config["rms_norm_eps"], eps)
+    if model.family != "dense" or model.sliding_window:
+        wrong["family"] = (model.family, model.sliding_window)
+    return wrong
+
+
+def n_matmul(shapes) -> int:
+    flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
+    return sum(math.prod(s.shape) for path, s in flat
+               if str(getattr(path[-1], "key", "")) in MATMUL_LEAVES)
+
+
+def flops_per_token(config, shapes, seq: int) -> float:
+    """Training FLOPs per token, forward and backward, no recompute:
+    6 N_matmul, plus causal attention's 6 S H hd per layer (QK^T and AV,
+    each 2 S H hd forward for a full square, halved by the causal mask,
+    times 3 for the backward)."""
+    heads = config["num_attention_heads"]
+    d_head = config["hidden_size"] // heads
+    return 6.0 * n_matmul(shapes) \
+        + 6.0 * seq * heads * d_head * config["num_hidden_layers"]

@@ -9,7 +9,9 @@ sigma (``threefry``: one bit per coordinate). The server sums the +-1 signs
 of the live clients, and steps x <- x - eta * gamma * eta_z * sigma *
 sum / n_live, where eta_z = 2^{1/(2z)} Gamma(1 + 1/(2z)) debiases the sign.
 
-All arithmetic is float32 at HIGHEST precision (``model``). The weights are
+The model is the ``loss`` of the configuration's family module
+(``bench/reference/<family>.py``), which the caller hands in. All
+arithmetic is float32 at HIGHEST precision. The weights are
 held between steps in the dtype the configuration states (bfloat16): each
 local step and the server step round their result to it, as a deployment
 that keeps bf16 weights does. Departures from the program's arithmetic,
@@ -39,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.compare import change_signs, leaf_norms
-from bench.reference import model, threefry
+from bench.reference import threefry
 
 
 class Setting(NamedTuple):
@@ -82,11 +84,16 @@ def _fp8(x):
 
 _fp8.defvjp(lambda x: (_fp8(x), None), lambda _, g: (g,))
 
+
+def identity(x):
+    return x
+
+
 def _precision(name: str, store_dtype):
     """-> (rounding of contraction inputs and activations, rounding of the
     weights between steps)."""
     if name == "f32":
-        return model.identity, lambda w: w.astype(store_dtype)
+        return identity, lambda w: w.astype(store_dtype)
     if name == "fp8":
         return fp8, lambda w: w.astype(store_dtype)
     raise ValueError(f"unknown precision {name!r}")
@@ -98,10 +105,11 @@ def client_keys(round_key, n: int):
 
 
 class Reference:
-    """The round above for one configuration and setting."""
+    """The round above for one configuration, its family module and a
+    setting."""
 
-    def __init__(self, cfg: dict, setting: Setting, precision: str = "f32",
-                 fault: str | None = None):
+    def __init__(self, family, cfg: dict, setting: Setting,
+                 precision: str = "f32", fault: str | None = None):
         if fault not in (None, "keys", "flip"):
             raise ValueError(f"unknown fault {fault!r}")
         self.cfg, self.s, self.fault = cfg, setting, fault
@@ -110,7 +118,7 @@ class Reference:
 
         def loss(p, tokens):
             p32 = jax.tree.map(lambda w: w.astype(jnp.float32), p)
-            return model.loss(cfg, p32, tokens, q)
+            return family.loss(cfg, p32, tokens, q)
 
         def client(x0, counts, tokens, key, weight):
             def step(x, tok):

@@ -3,6 +3,7 @@ chip skipped: it is correct, it finds what a cell names by name, and its
 measurement path refuses any device but a TPU it has peaks for."""
 import filecmp
 import json
+import math
 import time
 from types import SimpleNamespace
 
@@ -50,10 +51,79 @@ def test_new_config_mix_and_metric_need_no_edit(tmp_path):
                                   "unit": "rounds"}
     assert "round_gap_ms" in r["metrics"]
     assert {"busy_s", "window_s"} <= set(r["device"])
+    _unchanged(root)
+
+
+#: a model family added as a new file: the dense loss, a check of its own
+#: and a FLOP count no other family gives
+TINYFAM = '''"""A model family for the tests."""
+from bench.reference import dense
+
+loss = dense.loss
+FLOPS = 1234567.0
+
+
+def check(config, model, program_facts):
+    if config.get("tinyfam_refuses"):
+        return {"tinyfam_refuses": (True, False)}
+    return dense.check(config, model, program_facts)
+
+
+def flops_per_token(config, shapes, seq):
+    return FLOPS
+'''
+
+
+def _unchanged(root):
     cmp = filecmp.dircmp(tiny.ROOT / "bench", root / "bench",
                          ignore=["__pycache__"])
     for sub in [cmp] + list(cmp.subdirs.values()):
         assert not sub.diff_files, sub.diff_files
+
+
+def test_new_family_needs_no_edit(tmp_path):
+    """A model family added as a new module under bench/reference/, and a
+    configuration that names it, are found by name: the run takes its
+    reference, its configuration check and its FLOP count from the new
+    module, and every file the benchmark already had is left as it was."""
+    root = tiny.make_root(tmp_path)
+    (root / "bench" / "reference" / "tinyfam.py").write_text(TINYFAM)
+    tiny.add_cell(root, "tinyfam.mix",
+                  dict(tiny.tiny_config("tinyfam-qwen2"), reference="tinyfam"),
+                  "mix")
+    r = harness.run(root, "tinyfam.mix", 2 ** 31 + 15, 0.2, True,
+                    time.perf_counter(), HOOKS)
+    assert r["correct"], r["checks"]
+    tokens_per_round = 4 * 2 * 1 * 16
+    want = 100.0 * 1234567.0 * tokens_per_round * r["attempted"] / (
+        r["device"]["window_s"] * PEAK["bf16_flops_per_s"])
+    assert math.isclose(r["metrics"]["mfu_pct"]["value"], want,
+                        rel_tol=1e-9)
+    tiny.add_cell(root, "tinyfam.refused",
+                  dict(tiny.tiny_config("tinyfam-refused"),
+                       reference="tinyfam", tinyfam_refuses=True), "mix")
+    with pytest.raises(ValueError, match=r"tinyfam\.py.*tinyfam_refuses"):
+        harness.run(root, "tinyfam.refused", 1, 0.2, False,
+                    time.perf_counter(), HOOKS)
+    _unchanged(root)
+
+
+@pytest.mark.parametrize("reference, message", [
+    ("nofam", r"looked for .*/bench/reference/nofam\.py"),
+    (None, r"\"reference\" is None, where .*/bench/reference/<reference>\.py"),
+])
+def test_config_naming_no_family_module_is_refused(tmp_path, reference,
+                                                   message):
+    root = tiny.make_root(tmp_path)
+    config = tiny.tiny_config("tiny-nofam")
+    if reference is None:
+        del config["reference"]
+    else:
+        config["reference"] = reference
+    tiny.add_cell(root, "nofam.mix", config, "mix")
+    with pytest.raises(ValueError, match=message):
+        harness.run(root, "nofam.mix", 1, 0.2, False, time.perf_counter(),
+                    HOOKS)
 
 
 def _dev(platform, kind="TPU v5 lite"):

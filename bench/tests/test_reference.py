@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.reference import model, round as rround, threefry
+from bench.reference import dense, round as rround, threefry
 
 
 def test_threefry_20_rounds_is_jax_threefry():
@@ -85,9 +85,9 @@ def test_loss_is_causal():
     cfg, p = _tiny_cfg(), _tiny_params(jax.random.PRNGKey(0))
     tok = jax.random.randint(jax.random.PRNGKey(1), (2, 12), 0, 50)
     other = tok.at[:, -1].set((tok[:, -1] + 1) % 50)
-    cut = lambda t: model.loss(cfg, p, t[:, :-1])
+    cut = lambda t: dense.loss(cfg, p, t[:, :-1])
     assert float(cut(tok)) == float(cut(other))
-    assert float(model.loss(cfg, p, tok)) != float(model.loss(cfg, p, other))
+    assert float(dense.loss(cfg, p, tok)) != float(dense.loss(cfg, p, other))
 
 
 def test_gqa_with_one_group_is_multi_head_attention():
@@ -104,8 +104,8 @@ def test_gqa_with_one_group_is_multi_head_attention():
     for b in ("bk", "bv"):
         x = p["attn"][b].reshape(2, 2, 8)
         wide["attn"][b] = jnp.repeat(x, 2, axis=1).reshape(2, 32)
-    got = model.loss(cfg, p, tok)
-    want = model.loss(dict(cfg, num_key_value_heads=4), wide, tok)
+    got = dense.loss(cfg, p, tok)
+    want = dense.loss(dict(cfg, num_key_value_heads=4), wide, tok)
     np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
 
 

@@ -1,10 +1,12 @@
 """Work counts from shapes: the configurations' sizes, and that no share the
-per-layer metrics compute can pass 100% for work the program really does."""
+per-layer metrics compute can pass 100% for work the program really does
+(the dense family's FLOP count among them)."""
 import jax
 import jax.numpy as jnp
 import pytest
 
 from bench import harness, program, work
+from bench.reference import dense
 from bench.tests import tiny
 
 
@@ -16,7 +18,7 @@ def test_parameter_counts(cell, d):
     assert work.n_params(prog.shapes) == d
     # the tied embedding is counted once, as the head; norms and biases are
     # no matmul
-    assert d - work.n_matmul(prog.shapes) < 0.001 * d
+    assert d - dense.n_matmul(prog.shapes) < 0.001 * d
 
 
 def _tiny_program(seq):
@@ -27,7 +29,7 @@ def _tiny_program(seq):
     config["program"]["model"]["n_layers"] = 1
     traffic = tiny.tiny_traffic()
     traffic["train_args"]["seq-len"] = seq
-    return program.build(config, traffic, tiny.ROOT)
+    return config, program.build(config, traffic, tiny.ROOT)
 
 
 @pytest.mark.parametrize("seq", [16, 64])
@@ -36,14 +38,12 @@ def test_model_flops_do_not_exceed_the_programs(seq):
     program's own loss and gradient on the same batch (which also holds
     the recompute, the full attention square and the elementwise work),
     and more than half of it."""
-    prog = _tiny_program(seq)
-    m = prog.model
+    config, prog = _tiny_program(seq)
     batch = {"tokens": jax.ShapeDtypeStruct((2, seq), jnp.int32)}
     cost = jax.jit(jax.value_and_grad(prog.bundle.loss_fn)).lower(
         prog.shapes, batch).compile().cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
-    ours = work.flops_per_token(prog.shapes, m.n_layers, m.n_heads,
-                                m.d_head, seq) * 2 * seq
+    ours = prog.family.flops_per_token(config, prog.shapes, seq) * 2 * seq
     assert 0.5 * cost["flops"] < ours <= cost["flops"]
 
 
@@ -65,6 +65,8 @@ def test_flops_per_token_counts_attention_once_per_layer():
     shapes = {"embed": jax.ShapeDtypeStruct((10, 4), jnp.float32),
               "attn": {"wq": jax.ShapeDtypeStruct((2, 4, 4), jnp.float32)},
               "ln1": jax.ShapeDtypeStruct((2, 4), jnp.float32)}
-    assert work.n_matmul(shapes) == 40 + 32
-    assert work.flops_per_token(shapes, 2, 2, 2, 8) == \
+    config = {"num_attention_heads": 2, "hidden_size": 4,
+              "num_hidden_layers": 2}
+    assert dense.n_matmul(shapes) == 40 + 32
+    assert dense.flops_per_token(config, shapes, 8) == \
         6 * 72 + 6 * 8 * 2 * 2 * 2

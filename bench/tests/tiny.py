@@ -41,6 +41,28 @@ def tiny_traffic(clients: int = 4, cohort: str = "stream(shard=1)") -> dict:
                            "client-lr": 0.05, "server-lr": 0.5}}
 
 
+def add_cell(root: Path, cell: str, config: dict, traffic: str, *,
+             limits=None, chips: int = 1) -> None:
+    """Add a configuration and a cell on the mix ``traffic`` to the
+    checkout at ``root`` as new files and entries of its BENCHMARK.json,
+    as a later change to the benchmark would."""
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    (root / "bench" / "configs" / f"{config['name']}.json").write_text(
+        json.dumps(config))
+    (root / "bench" / "limits" / f"{cell}.json").write_text(
+        json.dumps({"limits": TINY_LIMITS if limits is None else limits}))
+    bench["configs"].append({"name": config["name"], "source": "test",
+                             "file": f"bench/configs/{config['name']}.json",
+                             "reduced": [], "why": "test"})
+    bench["workloads"].append({"name": cell, "config": config["name"],
+                               "traffic": traffic, "chips": chips,
+                               "why": "test"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if "workloads" in m:
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
 def make_root(tmp: Path, *, limits=None, chips: int = 1,
               cohort: str = "stream(shard=1)", clients: int = 4) -> Path:
     """A checkout in ``tmp`` holding the cell ``tiny.mix`` and the
@@ -48,23 +70,9 @@ def make_root(tmp: Path, *, limits=None, chips: int = 1,
     shutil.copytree(ROOT / "bench", tmp / "bench",
                     ignore=shutil.ignore_patterns("__pycache__"))
     (tmp / "src").symlink_to(ROOT / "src")
-    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    (tmp / "bench" / "configs" / "tiny-qwen2.json").write_text(
-        json.dumps(tiny_config()))
+    shutil.copy(ROOT / "BENCHMARK.json", tmp / "BENCHMARK.json")
     (tmp / "bench" / "traffic" / "mix.json").write_text(
         json.dumps(tiny_traffic(clients, cohort)))
-    if limits is None:
-        limits = TINY_LIMITS
-    (tmp / "bench" / "limits" / "tiny.mix.json").write_text(
-        json.dumps({"limits": limits}))
-    bench["configs"].append({"name": "tiny-qwen2", "source": "test",
-                             "file": "bench/configs/tiny-qwen2.json",
-                             "reduced": [], "why": "test"})
-    bench["workloads"].append({"name": "tiny.mix", "config": "tiny-qwen2",
-                               "traffic": "mix", "chips": chips,
-                               "why": "test"})
-    for m in bench["end_to_end"] + bench["per_layer"]:
-        if "workloads" in m:
-            m["workloads"].append("tiny.mix")
-    (tmp / "BENCHMARK.json").write_text(json.dumps(bench))
+    add_cell(tmp, "tiny.mix", tiny_config(), "mix", limits=limits,
+             chips=chips)
     return tmp

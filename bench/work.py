@@ -1,6 +1,8 @@
-"""Work counts from shapes: model FLOPs per token and the least bytes of the
-codec kernels. The per-layer shares divide these by measured time, so none
-of them can pass 100% unless the time leaves out part of the work.
+"""Work counts from shapes: the parameter count and the least bytes of the
+codec kernels, the same for every model family (a family's FLOPs per token
+are its module's, ``bench/reference/<family>.py``). The per-layer shares
+divide these by measured time, so none of them can pass 100% unless the
+time leaves out part of the work.
 """
 from __future__ import annotations
 
@@ -8,29 +10,9 @@ import math
 
 import jax
 
-#: leaves that are matrices of a matmul in the forward pass; the tied
-#: embedding is counted once, as the output head (its lookup is no matmul)
-MATMUL_LEAVES = ("wq", "wk", "wv", "wo", "w1", "w2", "w3", "embed",
-                 "lm_head")
-
 
 def n_params(shapes) -> int:
     return sum(math.prod(s.shape) for s in jax.tree_util.tree_leaves(shapes))
-
-
-def n_matmul(shapes) -> int:
-    flat, _ = jax.tree_util.tree_flatten_with_path(shapes)
-    return sum(math.prod(s.shape) for path, s in flat
-               if str(getattr(path[-1], "key", "")) in MATMUL_LEAVES)
-
-
-def flops_per_token(shapes, n_layers: int, n_heads: int, d_head: int,
-                    seq: int) -> float:
-    """Training FLOPs per token, forward and backward, no recompute:
-    6 N_matmul, plus causal attention's 6 S H hd per layer (QK^T and AV,
-    each 2 S H hd forward for a full square, halved by the causal mask,
-    times 3 for the backward)."""
-    return 6.0 * n_matmul(shapes) + 6.0 * seq * n_heads * d_head * n_layers
 
 
 def encode_bytes(clients: int, d: int) -> float:
