@@ -57,6 +57,13 @@ class ArchConfig:
             moe_topk=min(m.moe_topk, 2) if m.moe_topk else 0,
             sliding_window=min(m.sliding_window, 8) if m.sliding_window else 0,
             n_img_tokens=4 if m.n_img_tokens else 0,
+            kv_lora_rank=min(m.kv_lora_rank, 32),
+            qk_nope_dim=min(m.qk_nope_dim, 16),
+            qk_rope_dim=min(m.qk_rope_dim, 8),
+            v_head_dim=min(m.v_head_dim, 16),
+            n_dense_layers=min(m.n_dense_layers, 1),
+            moe_d_ff=min(m.moe_d_ff, 32),
+            moe_held=min(m.moe_held, 4), moe_held_start=0,
             dtype=jnp.float32)
         return dataclasses.replace(self, model=red)
 
@@ -72,6 +79,7 @@ _ARCH_IDS = [
     "xlstm_350m",
     "internvl2_1b",
     "seamless_m4t_large_v2",
+    "moonlight_16b_a3b",
 ]
 
 

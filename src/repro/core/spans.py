@@ -15,10 +15,13 @@ import jax
 from jax import monitoring
 
 #: device phases; the ``fed.*`` ones do not nest in each other, ``model.*``
-#: ones are sub-phases of ``fed.client.sgd``
+#: ones are sub-phases of ``fed.client.sgd`` (``model.moe.*``: the expert
+#: layer's routing and sort, its held experts' grouped matmuls, its shared
+#: experts)
 PHASES = ("fed.client.sgd", "fed.client.flatten", "fed.client.encode",
           "fed.server.fold", "fed.server.psum", "fed.server.apply",
-          "model.attn")
+          "model.attn", "model.moe.route", "model.moe.experts",
+          "model.moe.shared")
 #: host spans of ``launch/train.main``
 HOST_SPANS = ("fed.round", "fed.feed", "fed.compile", "fed.checkpoint")
 

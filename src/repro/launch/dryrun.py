@@ -133,8 +133,10 @@ def build_prefill_cell(arch, shape, mesh):
     batch = shape.global_batch
     all_batch_axes = tuple(list(plan.client_axes) + list(plan.micro_axes))
 
-    if cfg.family in ("dense", "moe", "vlm"):
-        from repro.models import transformer as T
+    if cfg.family in ("dense", "moe", "vlm", "mla_moe"):
+        from repro.models import mla_moe, transformer
+        T = mla_moe if cfg.family == "mla_moe" else transformer
+
         def prefill(params, tokens):
             x, _ = T.forward_hidden(params, tokens, cfg)
             return (x[:, -1:] @ T.lm_head(params, cfg)).astype(jnp.float32)

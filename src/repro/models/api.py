@@ -17,7 +17,7 @@ from repro.models import layers as L
 @dataclasses.dataclass(frozen=True)
 class ModelCfg:
     name: str
-    family: str                 # dense | moe | hybrid | xlstm | encdec | vlm
+    family: str     # dense | moe | mla_moe | hybrid | xlstm | encdec | vlm
     n_layers: int
     d_model: int
     n_heads: int
@@ -38,6 +38,22 @@ class ModelCfg:
     remat_save_weights: bool = False  # keep FSDP-gathered layer weights across
     #   remat: 1/3 less gather traffic for +L*layer_bytes HBM — only viable
     #   when per-layer weights are small (see EXPERIMENTS.md §Perf)
+    rms_eps: float = 1e-6       # the mla_moe family's layer and final
+    #   RMSNorm eps; the other families run layers.rms_norm's default, 1e-6
+    # -- the mla_moe family (DeepSeek-V3 layout): latent attention, leading
+    #    dense layers, then expert layers; 0 leaves a field unused
+    kv_lora_rank: int = 0       # width of the latent KV (c_kv)
+    qk_nope_dim: int = 0        # per-head QK width without RoPE
+    qk_rope_dim: int = 0        # per-head QK width with RoPE (k_pe shared)
+    v_head_dim: int = 0         # per-head V width
+    n_dense_layers: int = 0     # leading layers with a dense FFN of d_ff
+    moe_d_ff: int = 0           # width of one routed or shared expert
+    moe_shared: int = 0         # shared experts, run as one SwiGLU; the
+    #   router scores by sigmoid
+    moe_scale: float = 1.0      # routed_scaling_factor on the top-k weights
+    moe_aux_alpha: float = 0.0  # weight of the sequence-wise balance loss
+    moe_held: int = 0           # experts this chip holds (0: all of them)
+    moe_held_start: int = 0     # the first of them; they are contiguous
 
     @property
     def d_head(self) -> int:
@@ -49,6 +65,22 @@ class ModelCfg:
                          qkv_bias=self.qkv_bias,
                          sliding_window=self.sliding_window,
                          rope_theta=self.rope_theta, q_chunk=self.q_chunk)
+
+    def mla_cfg(self) -> L.MLACfg:
+        return L.MLACfg(d_model=self.d_model, n_heads=self.n_heads,
+                        kv_lora_rank=self.kv_lora_rank,
+                        qk_nope_dim=self.qk_nope_dim,
+                        qk_rope_dim=self.qk_rope_dim,
+                        v_head_dim=self.v_head_dim,
+                        rope_theta=self.rope_theta, q_chunk=self.q_chunk)
+
+    def expert_cfg(self) -> L.ExpertCfg:
+        return L.ExpertCfg(d_model=self.d_model, d_ff=self.moe_d_ff,
+                           n_experts=self.moe_experts, top_k=self.moe_topk,
+                           n_shared=self.moe_shared,
+                           held=self.moe_held or self.moe_experts,
+                           held_start=self.moe_held_start,
+                           scale=self.moe_scale)
 
     def attn_cfg_bidir(self) -> L.AttnCfg:
         return dataclasses.replace(self.attn_cfg(), causal=False,
@@ -87,6 +119,16 @@ def build_model(cfg: ModelCfg) -> ModelBundle:
             init_cache=lambda b, m: T.init_cache(cfg, b, m),
             train_batch_spec=_lm_specs(cfg),
             subquadratic=cfg.sliding_window > 0)
+
+    if cfg.family == "mla_moe":
+        from repro.models import mla_moe as M
+        return ModelBundle(
+            cfg=cfg,
+            init=lambda key: M.init_params(key, cfg),
+            loss_fn=lambda p, b: M.loss_fn(p, b, cfg),
+            decode_step=lambda p, c, t, pos: M.decode_step(p, c, t, pos, cfg),
+            init_cache=lambda b, m: M.init_cache(cfg, b, m),
+            train_batch_spec=_lm_specs(cfg))
 
     if cfg.family == "hybrid":
         from repro.models import hybrid as Hy

@@ -1,5 +1,7 @@
 """Shared layers: RMSNorm, RoPE, GQA attention (full / sliding-window,
-train + KV-cache decode), SwiGLU MLP, sort-free capacity MoE.
+train + KV-cache decode), latent attention (MLA, train + latent-cache
+decode), SwiGLU MLP, sort-free capacity MoE, and dropless routed experts of
+which a chip holds a share.
 
 All layer parameter trees are built *stacked over depth* (leading dim L) so
 model forwards are a single ``lax.scan`` over layers — compile time and HLO
@@ -132,20 +134,25 @@ def _sdpa_chunk(q_chunk, k, v, q_pos, k_pos, cfg: AttnCfg):
         scores = jnp.where(mask[None, None, None], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1).astype(q_chunk.dtype)
     out = jnp.einsum("bgrcs,bsgd->bcgrd", probs, v)
-    return out.reshape(B, c, H, hd)
+    return out.reshape(B, c, H, v.shape[-1])
 
 
-def _flash_kv_attention(q, k, v, positions, cfg: AttnCfg, kv_chunk: int):
+def _flash_kv_attention(q, k, v, positions, cfg: AttnCfg, kv_chunk: int,
+                        remat: bool = False):
     """Flash-style attention chunked over the KEY/VALUE axis with online
-    softmax.  Why KV-chunked (not Q-chunked): under sequence sharding the
-    Q/seq dim is distributed — reshaping it into chunks forces GSPMD to
+    softmax; q/k: (B, S, H|K, hd), v: (B, S, K, vd), vd the V width (latent
+    attention's differs from the QK width).  Why KV-chunked (not
+    Q-chunked): under sequence sharding the Q/seq dim is distributed —
+    reshaping it into chunks forces GSPMD to
     all-gather full activations per layer (measured, EXPERIMENTS.md §Perf).
     K/V are explicitly replicated (gather_seq in _qkv — small under GQA), so
     chunking THEM is sharding-transparent, and peak scores memory drops from
-    (B,H,S,S) to (B,H,S,kc).
+    (B,H,S,S) to (B,H,S,kc). ``remat`` recomputes each chunk's scores in the
+    backward pass, which then keeps only the carry of each chunk and not its
+    (B,H,S,kc) probabilities: at S = 8192 those come to 4 GB a chunk stack.
     """
     B, S, H, hd = q.shape
-    K = k.shape[2]
+    K, vd = k.shape[2], v.shape[-1]
     rep = H // K
     kc = min(kv_chunk, S)
     if S % kc != 0:
@@ -153,7 +160,7 @@ def _flash_kv_attention(q, k, v, positions, cfg: AttnCfg, kv_chunk: int):
     nc = S // kc
     q5 = q.reshape(B, S, K, rep, hd)
     kt = k.reshape(B, nc, kc, K, hd).swapaxes(0, 1)
-    vt = v.reshape(B, nc, kc, K, hd).swapaxes(0, 1)
+    vt = v.reshape(B, nc, kc, K, vd).swapaxes(0, 1)
     pos_t = positions.reshape(nc, kc)
 
     def body(carry, xs):
@@ -175,13 +182,15 @@ def _flash_kv_attention(q, k, v, positions, cfg: AttnCfg, kv_chunk: int):
             preferred_element_type=jnp.float32)
         return (m_new, l_new, acc), ()
 
+    if remat:
+        body = jax.checkpoint(body, prevent_cse=False)
     m0 = jnp.full((B, K, rep, S), -1e30, jnp.float32)
     l0 = jnp.zeros((B, K, rep, S), jnp.float32)
-    acc0 = jnp.zeros((B, K, rep, S, hd), jnp.float32)
+    acc0 = jnp.zeros((B, K, rep, S, vd), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), (kt, vt, pos_t))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    # (B, K, rep, S, hd) -> (B, S, H, hd)
-    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H * hd).astype(q.dtype)
+    # (B, K, rep, S, vd) -> (B, S, H * vd)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H * vd).astype(q.dtype)
 
 
 @phase("model.attn")
@@ -226,6 +235,112 @@ def attention_decode(x, lp, cfg: AttnCfg, cache_k, cache_v, position):
     probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
     y = jnp.einsum("bgrcs,bsgd->bcgrd", probs, cache_v).reshape(B, 1, -1)
     return y @ lp["wo"], cache_k, cache_v
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2 arXiv:2405.04434 §2.1, no query LoRA)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class MLACfg:
+    d_model: int
+    n_heads: int
+    kv_lora_rank: int         # width of the latent c_kv
+    qk_nope_dim: int
+    qk_rope_dim: int
+    v_head_dim: int
+    rope_theta: float = 1e4
+    q_chunk: int = 512
+    causal: bool = True
+    sliding_window: int = 0
+
+    @property
+    def qk_dim(self) -> int:
+        return self.qk_nope_dim + self.qk_rope_dim
+
+
+def mla_init(key, cfg: MLACfg, n_layers: int, dtype):
+    ks = jax.random.split(key, 4)
+    D, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    return {
+        "wq": _init(ks[0], (n_layers, D, H * cfg.qk_dim), dtype=dtype),
+        "wkv_a": _init(ks[1], (n_layers, D, r + cfg.qk_rope_dim),
+                       dtype=dtype),
+        "ln_kv": jnp.ones((n_layers, r), dtype),
+        "wkv_b": _init(ks[2], (n_layers, r,
+                               H * (cfg.qk_nope_dim + cfg.v_head_dim)),
+                       dtype=dtype),
+        "wo": _init(ks[3], (n_layers, H * cfg.v_head_dim, D), dtype=dtype),
+    }
+
+
+def _mla_q(x, lp, cfg: MLACfg, positions):
+    """(B, S, H, qk_dim): per head [q_nope | RoPE(q_pe)]."""
+    B, S, _ = x.shape
+    q = (x @ lp["wq"]).reshape(B, S, cfg.n_heads, cfg.qk_dim)
+    q_pe = apply_rope(q[..., cfg.qk_nope_dim:], positions, cfg.rope_theta)
+    return jnp.concatenate([q[..., :cfg.qk_nope_dim], q_pe], axis=-1)
+
+
+def _mla_latent(x, lp, cfg: MLACfg, positions):
+    """-> (RMSNorm(c_kv) (B, S, r), RoPE(k_pe) (B, S, qk_rope_dim)): what a
+    position leaves in the latent cache. The latent's RMSNorm runs the
+    default eps, as DeepSeek's ``kv_a_layernorm`` does whatever the model's
+    ``rms_norm_eps``."""
+    kv = x @ lp["wkv_a"]
+    r = cfg.kv_lora_rank
+    c_kv = rms_norm(kv[..., :r], lp["ln_kv"])
+    k_pe = apply_rope(kv[..., None, r:], positions, cfg.rope_theta)
+    return c_kv, k_pe[..., 0, :]
+
+
+def _mla_kv(c_kv, k_pe, lp, cfg: MLACfg):
+    """Latent -> per-head keys [k_nope | k_pe] (k_pe shared by all heads)
+    and values, (B, S, H, qk_dim) and (B, S, H, v_head_dim)."""
+    B, S, _ = c_kv.shape
+    H, nope = cfg.n_heads, cfg.qk_nope_dim
+    kv = (c_kv @ lp["wkv_b"]).reshape(B, S, H, nope + cfg.v_head_dim)
+    k_pe = jnp.broadcast_to(k_pe[:, :, None, :], (B, S, H, cfg.qk_rope_dim))
+    return jnp.concatenate([kv[..., :nope], k_pe], axis=-1), kv[..., nope:]
+
+
+@phase("model.attn")
+def mla_attention(x, lp, cfg: MLACfg, positions):
+    """Training latent attention. x: (B, S, D) -> (B, S, D). Scores are
+    (q_nope.k_nope + q_pe.k_pe) / sqrt(qk_dim), through the same cores as
+    ``attention`` with one K/V head per query head."""
+    B, S, _ = x.shape
+    q = _mla_q(x, lp, cfg, positions)
+    k, v = _mla_kv(*_mla_latent(x, lp, cfg, positions), lp, cfg)
+    if S <= cfg.q_chunk:
+        y = _sdpa_chunk(q, k, v, positions, positions, cfg).reshape(B, S, -1)
+    else:
+        y = _flash_kv_attention(q, k, v, positions, cfg, cfg.q_chunk,
+                                remat=True)
+    return y @ lp["wo"]
+
+
+def mla_decode(x, lp, cfg: MLACfg, cache_c, cache_pe, position):
+    """One-token decode through the latent cache: c_kv (B, S_cache, r) and
+    k_pe (B, S_cache, qk_rope_dim) per position; keys and values are
+    expanded from it. Returns (y, new cache_c, new cache_pe)."""
+    B = x.shape[0]
+    pos = jnp.full((B, 1), position, jnp.int32)
+    q = _mla_q(x, lp, cfg, pos)
+    c_new, pe_new = _mla_latent(x, lp, cfg, pos)
+    cache_c = jax.lax.dynamic_update_slice_in_dim(
+        cache_c, c_new.astype(cache_c.dtype), position, axis=1)
+    cache_pe = jax.lax.dynamic_update_slice_in_dim(
+        cache_pe, pe_new.astype(cache_pe.dtype), position, axis=1)
+    k, v = _mla_kv(cache_c, cache_pe, lp, cfg)
+    valid = jnp.arange(cache_c.shape[1]) <= position
+    scores = jnp.einsum("bqhd,bshd->bhqs", q, k,
+                        preferred_element_type=jnp.float32)
+    scores = scores / (cfg.qk_dim ** 0.5)
+    scores = jnp.where(valid[None, None, None], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1).astype(x.dtype)
+    y = jnp.einsum("bhqs,bshd->bqhd", probs, v).reshape(B, 1, -1)
+    return y @ lp["wo"], cache_c, cache_pe
 
 
 # ---------------------------------------------------------------------------
@@ -442,3 +557,128 @@ def moe_apply(x, lp, n_experts: int, top_k: int, capacity_factor: float = 1.25,
     prob = jnp.mean(gate_all, axis=(0, 1, 2))
     aux = E * jnp.sum(frac * prob)
     return out.reshape(B, S, D), aux
+
+
+# ---------------------------------------------------------------------------
+# dropless routed experts, of which this chip holds a contiguous share
+# (DeepSeek-V3 arXiv:2412.19437 §2.1.2)
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class ExpertCfg:
+    d_model: int
+    d_ff: int                 # width of one routed expert
+    n_experts: int            # the router's outputs: every expert
+    top_k: int
+    n_shared: int             # shared experts, one SwiGLU of n_shared * d_ff
+    held: int                 # experts this chip holds ...
+    held_start: int = 0       # ... from this one on
+    scale: float = 1.0        # routed_scaling_factor
+
+
+#: grouped-matmul tiles (rows, contraction, output columns), cut to the
+#: problem where it is smaller
+GMM_TILES = (512, 1024, 1024)
+
+
+def experts_init(key, cfg: ExpertCfg, n_layers: int, dtype):
+    ks = jax.random.split(key, 5)
+    D, F, Eh = cfg.d_model, cfg.d_ff, cfg.held
+    return {"router": _init(ks[0], (n_layers, D, cfg.n_experts), dtype=dtype),
+            "experts": {
+                "w1": _init(ks[1], (n_layers, Eh, D, F), dtype=dtype),
+                "w3": _init(ks[2], (n_layers, Eh, D, F), dtype=dtype),
+                "w2": _init(ks[3], (n_layers, Eh, F, D), dtype=dtype)},
+            "shared": mlp_init(ks[4], D, cfg.n_shared * F, n_layers, dtype)}
+
+
+@phase("model.moe.route")
+def route(x, router, cfg: ExpertCfg):
+    """x: (B, S, D) -> (weights (B, S, k) f32, experts (B, S, k) int32,
+    sequence-wise balance loss). Sigmoid scores over all ``n_experts`` in
+    f32; the top-k's scores normalised to sum 1, times ``scale``."""
+    scores = jax.nn.sigmoid(jnp.einsum(
+        "bsd,de->bse", x.astype(jnp.float32), router.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    top, idx = jax.lax.top_k(scores, cfg.top_k)
+    w = top / (jnp.sum(top, axis=-1, keepdims=True) + 1e-20) * cfg.scale
+    return w, idx, balance_loss(scores, idx, cfg.n_experts)
+
+
+def balance_loss(scores, idx, n_experts: int):
+    """DeepSeek-V3's sequence-wise balance loss sum_i f_i P_i, averaged over
+    the batch: f_i = E / (k S) * (tokens of the sequence that select i),
+    P_i the mean over the sequence of the scores normalised over all E."""
+    S, k = idx.shape[1], idx.shape[2]
+    share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    f = jnp.sum(jax.nn.one_hot(idx, n_experts, dtype=jnp.float32),
+                axis=(1, 2)) * (n_experts / (k * S))
+    return jnp.mean(jnp.sum(f * jnp.mean(share, axis=1), axis=-1))
+
+
+def grouped_matmul(lhs, rhs, sizes, start):
+    """Rows of ``lhs`` (m, K), sorted by expert, times their expert's matrix:
+    ``rhs`` (held, K, N) holds experts start .. start + held - 1 of the
+    ``sizes`` (n_experts,) groups. Rows of the other experts come out zero;
+    work is done for the held experts' rows alone (megablox ``gmm``; its
+    kernels carry the names of its jitted entry points, ``gmm`` and, for the
+    weight gradient, ``tgmm``, inside those of the transforms that reach
+    them, e.g. ``transpose_jvp_jit_gmm___``)."""
+    # imported here, as the kernels' modules are: importing Pallas takes
+    # over a second, which a model without an expert layer need not wait for
+    from jax.experimental.pallas.ops.tpu.megablox import ops as megablox
+
+    from repro.kernels.common import interpret_mode
+    tiles = tuple(min(t, n) for t, n in
+                  zip(GMM_TILES, (lhs.shape[0],) + rhs.shape[1:]))
+    return megablox.gmm(lhs, rhs, sizes, lhs.dtype, tiles,
+                        jnp.asarray(start, jnp.int32), None, False,
+                        interpret_mode())
+
+
+@phase("model.moe.route")
+def sort_by_expert(idx, n_experts: int):
+    """idx: (T, k) -> (the (token, slot) pairs in the order of their
+    expert, (T k,) int32; rows per expert, (n_experts,) int32)."""
+    flat = idx.reshape(-1)
+    return (jnp.argsort(flat, stable=True).astype(jnp.int32),
+            jnp.bincount(flat, length=n_experts).astype(jnp.int32))
+
+
+@phase("model.moe.experts")
+def held_experts(x, w, order, sizes, lp, cfg: ExpertCfg):
+    """Dropless: every (token, expert) pair routed to a held expert is
+    computed, however the routing is skewed. x: (T, D); w: (T, k); order,
+    sizes: ``sort_by_expert``'s. -> (T, D), the held experts' part of the
+    routed sum."""
+    T, D = x.shape
+    m = T * cfg.top_k
+    tm = min(GMM_TILES[0], -(-m // 8) * 8)
+    xs = jnp.pad(x[order // cfg.top_k], ((0, -m % tm), (0, 0)))
+    e = lp["experts"]
+    h = grouped_matmul(xs, e["w1"], sizes, cfg.held_start)
+    g = grouped_matmul(xs, e["w3"], sizes, cfg.held_start)
+    y = grouped_matmul(jax.nn.silu(h) * g, e["w2"], sizes, cfg.held_start)
+    # back to (token, slot) order; padding rows are never read
+    back = jnp.zeros((m,), jnp.int32).at[order].set(
+        jnp.arange(m, dtype=jnp.int32))
+    y = y[back].reshape(T, cfg.top_k, D)
+    return jnp.einsum("tkd,tk->td", y.astype(jnp.float32), w).astype(x.dtype)
+
+
+@phase("model.moe.shared")
+def shared_experts(x, lp):
+    return swiglu(x, lp["shared"])
+
+
+def routed_experts(x, lp, cfg: ExpertCfg):
+    """shared(x) + sum over the selected experts this chip holds of
+    w_i expert_i(x), with the routing over all experts and its balance
+    loss. No code stands in for the experts held elsewhere or for the
+    exchange with them. x: (B, S, D) -> ((B, S, D), balance loss)."""
+    B, S, D = x.shape
+    w, idx, aux = route(x, lp["router"], cfg)
+    order, sizes = sort_by_expert(idx, cfg.n_experts)
+    y = held_experts(x.reshape(B * S, D), w.reshape(B * S, -1), order,
+                     sizes, lp, cfg).reshape(B, S, D)
+    return y + shared_experts(x, lp), aux

@@ -21,26 +21,29 @@ import pytest
 from repro.core import spans
 
 HERE = Path(__file__).resolve().parent
+MOE = tuple(p for p in spans.PHASES if p.startswith("model.moe."))
 SRC = Path(spans.__file__).resolve().parents[2]
 
 TINY = {"n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
         "d_ff": 128, "vocab": 512}
 
 
-def op_names(cohort: str) -> list:
+def op_names(cohort: str, arch: str = "qwen2_0_5b") -> list:
     """[(instruction name, op_name path)] of the tiny round step's compiled
-    HLO on the plan ``cohort``."""
+    HLO on the plan ``cohort``: qwen2 at the sizes of ``TINY``, or another
+    registry entry reduced."""
     from repro.configs.common import get_arch
     from repro.core import fedavg
     from repro.launch import train
     from repro.models.api import build_model
 
     args = train.parse_args([
-        "--arch", "qwen2_0_5b", "--pipeline", "zsign_packed(z=1,sigma=0.01)",
+        "--arch", arch, "--pipeline", "zsign_packed(z=1,sigma=0.01)",
         "--clients", "4", "--local-steps", "2", "--micro-batch", "1",
         "--seq-len", "16", "--cohort", cohort])
-    bundle = build_model(dataclasses.replace(get_arch("qwen2_0_5b").model,
-                                             **TINY))
+    model = (dataclasses.replace(get_arch(arch).model, **TINY)
+             if arch == "qwen2_0_5b" else get_arch(arch).reduced().model)
+    bundle = build_model(model)
     comp = train.build_compressor(args)
     cfg = train.fed_config(args)
     ctx = fedavg.RoundContext(weights_are_mask=True, cohort=cohort)
@@ -71,8 +74,26 @@ def compiled_ops(request):
 
 def test_every_phase_reaches_the_compiled_round(compiled_ops):
     found = set().union(*(components(n) for _, n in compiled_ops))
-    # the psum runs only across devices: test_psum_carries_its_phase
-    assert set(spans.PHASES) - {"fed.server.psum"} <= found
+    # the psum runs only across devices: test_psum_carries_its_phase; the
+    # expert layer's phases only in a model that has one:
+    # test_expert_phases_reach_the_compiled_round
+    assert set(spans.PHASES) - {"fed.server.psum"} \
+        - set(MOE) <= found
+    assert not set(MOE) & found
+
+
+def test_expert_phases_reach_the_compiled_round():
+    """The reduced Moonlight round: the expert layer's routing, grouped
+    matmuls and shared experts each name their ops, forward, backward and
+    recomputed, inside the client step, beside the latent attention."""
+    ops = op_names("stream(shard=1)", "moonlight_16b_a3b")
+    paths = [n for _, n in ops if n.startswith("jit(")]
+    for p in MOE + ("model.attn",):
+        mine = [n for n in paths if p in components(n)]
+        assert any("transpose(jvp" in n for n in mine), p
+        assert any("rematted_computation" in n for n in mine), p
+        for n in mine:
+            assert "fed.client.sgd" in components(n), n
 
 
 def test_backward_and_recomputed_ops_keep_their_phase(compiled_ops):

@@ -6,10 +6,11 @@ described rather than attached, so these tests need no accelerator. They see
 what interpret mode cannot: block shapes the chip's tiling refuses, lowering
 rules Mosaic lacks, VMEM and HBM limits. Widths: the flat qwen2-0.5B
 pseudo-gradient (one client's full encode and its in-place fold into the
-server's sum), and 16-client stacks for the batched encode and the
-sign-reduce.
+server's sum), 16-client stacks for the batched encode and the
+sign-reduce, and the Moonlight cell's expert layer for the grouped matmul.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -137,3 +138,25 @@ def test_compress_dense_noise(shape, n_tiles):
     view = shape((n_tiles * FLAT_ROWS, LANE), jnp.float32)
     _compile(lambda x, n, s: K.compress_pallas(x, n, s, interpret=False),
              view, view, shape((), jnp.float32))
+
+
+def test_grouped_matmul_of_the_expert_layer(shape, monkeypatch):
+    """The held experts' grouped matmul at the Moonlight cell's widths (8 of
+    64 experts, 8,192 tokens x top-6 rows, 2,048 x 1,408), both gradients:
+    the megablox kernels compile for the chip, under names that hold what
+    ``expert_roofline`` looks for in a trace, ``gmm`` (under a gradient
+    transform, ``transpose_jvp_jit_gmm___``; ``tgmm`` for the weights')."""
+    from repro.kernels import common
+    from repro.models import layers as L
+    monkeypatch.setattr(common, "interpret_mode", lambda: False)
+
+    def loss(x, w, sizes):
+        return jnp.sum(L.grouped_matmul(x, w, sizes, 0).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        shape((49152, 2048), jnp.bfloat16), shape((8, 2048, 1408), jnp.bfloat16),
+        shape((64,), jnp.int32)).compile()
+    names = set(re.findall(r"%(\w+)\.\d+ = [^\n]*tpu_custom_call",
+                           compiled.as_text()))
+    assert names and all("gmm" in n for n in names), names
+    assert any("tgmm" in n for n in names), names
