@@ -1,6 +1,8 @@
 """jit'd public wrappers around the z-sign Pallas kernels.
 
-Handle arbitrary-shaped inputs (flatten + pad to the 8192-element tile).
+Handle arbitrary-shaped inputs: the fused encode pads a flat buffer only to
+the next 128 lanes (and not at all at a width of whole lanes), the other
+kernels to the 8192-element tile.
 The kernels run compiled on the TPU and in interpret mode on the CPU
 (kernels/common.interpret_mode); interpret mode checks the kernels' logic,
 not what the TPU compiler accepts (tests/test_tpu_compile.py does that).
@@ -14,17 +16,19 @@ import jax.numpy as jnp
 
 from repro.core import noise as znoise
 from repro.core import wire
-from repro.kernels.common import (LANE, ROWS_BLK, TILE, flat_view,
-                                  interpret_mode)
+from repro.kernels.common import (FLAT_ROWS, LANE, ROWS_BLK, TILE,
+                                  flat_view, interpret_mode)
 from repro.kernels.zsign import zsign as K
 
 
-def _pad_flat(x: jax.Array):
+def _pad_flat(x: jax.Array, to: int = TILE) -> jax.Array:
+    """x -> the flat (-1, LANE) view of its elements, zero-padded to a
+    multiple of ``to``: a copy only when the size is not one already."""
     flat = x.reshape(-1)
-    pad = (-flat.size) % TILE
+    pad = (-flat.size) % to
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    return flat_view(flat), pad
+    return flat_view(flat)
 
 
 @partial(jax.jit, static_argnames=("interpret",))
@@ -34,8 +38,8 @@ def zsign_compress(x: jax.Array, noise: jax.Array, sigma,
     Returns uint8 of ceil(x.size/8) bytes (padded tail packs sign(+pad zeros)).
     """
     interpret = interpret_mode() if interpret is None else interpret
-    x2d, _ = _pad_flat(x.astype(jnp.float32))
-    n2d, _ = _pad_flat(noise.astype(jnp.float32))
+    x2d = _pad_flat(x.astype(jnp.float32))
+    n2d = _pad_flat(noise.astype(jnp.float32))
     packed = K.compress_pallas(x2d, n2d, jnp.asarray(sigma), interpret=interpret)
     return packed.reshape(-1)
 
@@ -43,15 +47,18 @@ def zsign_compress(x: jax.Array, noise: jax.Array, sigma,
 def _batched_encode_tiles_jnp(x2d, key2, sigma, *, z):
     """Client-batched counter-stream encode, pure jnp, tile-scanned.
 
-    x2d: (n, n_tiles * 64, 128) f32 flat views; key2: (n, 2) u32; sigma:
-    (n,) f32 -> (n, n_tiles * 8, 128) u8, byte-for-byte the stack of per-client
-    ``compress_rng_pallas`` outputs (same global quarter-counters, same
-    tile word layout — noise.tile_u01). The lax.scan walks the TILE axis
+    x2d: (n, rows, 128) f32 flat views; key2: (n, 2) u32; sigma: (n,) f32
+    -> (n, n_tiles * 8, 128) u8 with n_tiles = ceil(rows / 64),
+    byte-for-byte the ``compress_rng_pallas`` output (same global
+    quarter-counters, same tile word layout — noise.tile_u01; the rows
+    past the buffer encoded as zeros). The lax.scan walks the TILE axis
     so the largest computed f32 intermediate is one (n, 8192) uniform
     window, never an (n, d) noise surface (the jaxpr pin of
     tests/test_encode_fused.py)."""
     n = x2d.shape[0]
-    n_tiles = x2d[0].size // TILE
+    n_tiles = -(-x2d.shape[1] // FLAT_ROWS)
+    x2d = jnp.pad(x2d, ((0, 0), (0, n_tiles * FLAT_ROWS - x2d.shape[1]),
+                        (0, 0)))
     rows = n_tiles * ROWS_BLK
     if z is None:
         return wire.pack_bool(x2d >= 0.0).reshape(n, rows, LANE)
@@ -99,8 +106,8 @@ def _rng_encode_vmappable(z, interpret: bool):
 
     @jax.custom_batching.custom_vmap
     def enc(x2d, key2, sigma):
-        return K.compress_rng_pallas(x2d, key2, sigma.reshape(1), z=z,
-                                     interpret=interpret)
+        return K.compress_rng_pallas(x2d[None], key2, sigma.reshape(1), z=z,
+                                     interpret=interpret)[0]
 
     @enc.def_vmap
     def _batched(axis_size, in_batched, x2d, key2, sigma):
@@ -115,9 +122,8 @@ def _rng_encode_vmappable(z, interpret: bool):
         sigma = sigma.reshape(n).astype(jnp.float32)
         if interpret:
             return _batched_encode_tiles_jnp(x2d, key2, sigma, z=z), True
-        packed = K.compress_rng_pallas(
-            x2d.reshape(-1, LANE), key2, sigma, z=z, interpret=interpret)
-        return packed.reshape(n, -1, LANE), True
+        return K.compress_rng_pallas(x2d, key2, sigma, z=z,
+                                     interpret=interpret), True
 
     return enc
 
@@ -129,18 +135,22 @@ def zsign_encode_fused(x: jax.Array, key: jax.Array, sigma,
     """Fused client encode with IN-KERNEL counter-based noise.
 
     x: any-shape float32; key: the client's PRNG key (typed or raw uint32
-    pair). Each 8192-element grid tile derives its randomness from
-    threefry2x32(key, global_counters) and writes Sign(x + sigma*xi_z) as
-    wire bytes directly — no fp32 noise buffer in HBM, unlike
-    ``zsign_compress`` which takes a dense noise input. Returns uint8 of
-    ceil(x.size/8192)*1024 bytes (kernel tile padding, as zsign_compress).
+    pair). The kernel reads x's flat (-1, 128) view, which is free at a
+    width of whole lanes (else x is padded to the next 128 only), in blocks
+    of many 8192-element tiles with a ragged last block. Each tile derives
+    its randomness from threefry2x32(key, global_counters) and writes
+    Sign(x + sigma*xi_z) as wire bytes directly — no fp32 noise buffer and
+    no tile-padded copy of x in HBM, unlike ``zsign_compress`` which takes a
+    dense noise input. Returns uint8 of ceil(x.size/8192)*1024 bytes: the
+    wire's whole tiles, the part past x.size encoded as zeros, as
+    zsign_compress pads them.
     ``z`` must be Z_INF (uniform) or 1 (Gaussian); ``add_noise=False``
     (static sigma == 0, vanilla SignSGD) skips the PRNG entirely. ``sigma``
     may be traced (Plateau dynamic sigma; stosign's per-client norm) — a
     runtime 0 also degrades exactly to noise-free signs.
     """
     interpret = interpret_mode() if interpret is None else interpret
-    x2d, _ = _pad_flat(x.astype(jnp.float32))
+    x2d = _pad_flat(x.astype(jnp.float32), LANE)
     k0, k1 = znoise.key_words(key)
     key2 = jnp.stack([k0, k1]).reshape(1, 2)
     enc = _rng_encode_vmappable(z if add_noise else None, interpret)

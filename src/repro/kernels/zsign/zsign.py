@@ -6,7 +6,7 @@ Three kernels:
                      (fused elementwise + 8:1 bitpack; 1 byte out per 8 in;
                      noise is a kernel INPUT — the dense-noise path, kept for
                      finite z > 1 and as the reference encoder)
-  _compress_rng_kernel: in-kernel counter-based noise — each grid tile
+  _compress_rng_kernel: in-kernel counter-based noise — each tile
                      derives its randomness from threefry2x32(client_key,
                      tile_counters) (core/noise.py, plain VPU uint32 ops; 4
                      u16 uniforms per call) and samples the wire bit
@@ -34,17 +34,21 @@ Three kernels:
                      block: a streamed fold reads and writes the sum once
                      per call, in place.
 
-The encode kernels stream HBM->VMEM one 8192-element f32 tile per grid
-step (a (64, 128) block of the flat view, worked on as (8, 1024)) and write
-(8, 128) uint8 tiles; the reduce takes a run of such tiles per step. The
-layout, the bit-pack and the unpack are the shared forms of
-kernels/common.py. Per-client scalars (key words, sigma, the
-threshold scale, reduce weights) live in SMEM; the tile index is the grid
-position. The counter scheme was chosen over pltpu.prng_random_bits because
-the hardware PRNG's stream cannot be reproduced off-TPU — threefry2x32 is
-~13 VPU integer ops per word and gives the interpret-mode kernel, the
-compiled TPU kernel, and the jnp encode the identical byte stream for the
-same client key.
+The dense-noise encode streams HBM->VMEM one 8192-element f32 tile per grid
+step (a (64, 128) block of the flat view, worked on as (8, 1024)) and writes
+(8, 128) uint8 tiles. The counter encode takes a block of many tiles a step
+(:func:`encode_tiles`) straight from the client's unpadded flat (rows, 128)
+view, its last block ragged, and works in the flat view on full vregs:
+threefry runs on one (16, 128) counter array a tile, and only the bits go
+to the (8, 1024) tile view, for the MXU pack. The reduce takes a run of
+tiles per step. The layout, the bit-pack and the unpack are the shared
+forms of kernels/common.py. Per-client scalars (key words, sigma, the
+threshold scale, reduce weights) live in SMEM; the tile index follows from
+the grid position. The counter scheme was chosen over
+pltpu.prng_random_bits because the hardware PRNG's stream cannot be
+reproduced off-TPU — threefry2x32 is ~13 VPU integer ops per word and gives
+the interpret-mode kernel, the compiled TPU kernel, and the jnp encode the
+identical byte stream for the same client key.
 """
 from __future__ import annotations
 
@@ -63,6 +67,8 @@ from repro.kernels.common import (COLS, FLAT_ROWS, LANE, ROWS_BLK, TILE,
 
 CLIENT_BLK = 8              # clients per sign-reduce block, stacks of 8+
 REDUCE_VMEM = 8 << 20       # VMEM budget of one sign-reduce grid step
+ENCODE_VMEM = 8 << 20       # VMEM budget of one encode grid step's blocks
+ENCODE_GROUP = 16           # tiles an encode step computes at a time
 
 _SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
 
@@ -89,79 +95,131 @@ def compress_pallas(x2d: jax.Array, noise2d: jax.Array, sigma: jax.Array,
     )(sigma.reshape(1).astype(jnp.float32), x2d, noise2d, pack_matrix())
 
 
-def _compress_rng_kernel(k_ref, sig_ref, inv_ref, x_ref, m_ref, o_ref, *, z):
-    """Counter-based in-kernel noise: one tile of one client's encode.
+def encode_tiles(n_tiles: int) -> int:
+    """Tiles per encode grid step for a client buffer of ``n_tiles`` tiles.
 
-    Grid (client c, tile t). Tile t covers the client's elements
-    [t*8192, (t+1)*8192). Quarter-counters are global (c = t*2048 + local);
-    one threefry2x32 call yields 4 u16 uniforms that feed the tile's four
-    row-quarters — the layout of noise.tile_u01, which the jnp encode
-    replays verbatim. ``z`` is static: None disables the noise entirely
-    (vanilla SignSGD, sigma == 0), else z in {Z_INF, 1} selects the sign
-    CDF.
+    The most that fit ENCODE_VMEM, rounded down to a power of two: the
+    double-buffered f32 input block and packed output block per tile. The
+    step's temporaries are those of one ENCODE_GROUP of tiles, whatever
+    the block. All of a shorter buffer is one block."""
+    per_tile = 2 * (4 * TILE + TILE // 8)
+    t = 1 << ((ENCODE_VMEM // per_tile).bit_length() - 1)
+    return n_tiles if n_tiles <= t else t
+
+
+def _compress_rng_kernel(k_ref, sig_ref, inv_ref, x_ref, m_ref, o_ref, *, z,
+                         rows):
+    """Counter-based in-kernel noise: one block of one client's encode.
+
+    Grid (client c, block i). The block is ``tiles`` 8192-element tiles of
+    the client's flat (rows, 128) view, (tiles * 64, 128) f32, walked in
+    groups of up to ENCODE_GROUP tiles. Quarter q of tile t is flat rows
+    16q .. 16q + 15 of the tile's (64, 128) rows, and its element at
+    (16q + r, lane) takes the global quarter-counter t * 2048 + 128 r +
+    lane: so a group of g tiles from tile t0 needs the one (16 g, 128)
+    counter array t0 * 2048 + 128 * row + lane, on full vregs. One
+    threefry2x32 call per counter yields 4 u16 uniforms, one for each
+    quarter: the layout of noise.tile_u01, which the jnp encode replays
+    verbatim. Only the 0/1 bits go to the (8 g, 1024) tile view, for the
+    MXU pack. Rows past ``rows`` (the ragged last block) read as 0, which
+    is what a zero pad of the buffer gave. ``z`` is static: None disables
+    the noise entirely (vanilla SignSGD, sigma == 0), else z in {Z_INF, 1}
+    selects the sign CDF.
     """
-    def plain():
-        o_ref[...] = pack_bits(to_tile(x_ref[...]) >= 0.0, m_ref[...])
+    c = pl.program_id(0)
+    blk_rows = x_ref.shape[0]
+    tiles = blk_rows // FLAT_ROWS
+    row0 = pl.program_id(1) * blk_rows
+    qrows = FLAT_ROWS // 4
+    pack_m = m_ref[...]
+
+    def plain(x, t0, g):
+        return x >= 0.0
+
+    def noisy(x, t0, g):
+        row = jax.lax.broadcasted_iota(jnp.uint32, (g * qrows, LANE), 0)
+        lane = jax.lax.broadcasted_iota(jnp.uint32, (g * qrows, LANE), 1)
+        cnt = (t0.astype(jnp.uint32) * jnp.uint32(TILE // 4)
+               + row * jnp.uint32(LANE) + lane)
+        y0, y1 = znoise.counter_words(k_ref[2 * c], k_ref[2 * c + 1], cnt)
+        us = znoise.halves_to_u01(y0) + znoise.halves_to_u01(y1)
+        xq = x.reshape(g, 4, qrows, LANE)
+        return jnp.stack(
+            [znoise.noisy_sign_bits(xq[:, q], u.reshape(g, qrows, LANE),
+                                    inv_ref[c], z)
+             for q, u in enumerate(us)], axis=1).reshape(g * FLAT_ROWS, LANE)
+
+    def encode(sign_bits):
+        def group(j, g):
+            r = pl.multiple_of(j * FLAT_ROWS, FLAT_ROWS)
+            x = x_ref[pl.ds(r, g * FLAT_ROWS), :]
+            if rows % blk_rows:                       # a ragged last block
+                at = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+                x = jnp.where(at < rows - row0 - r, x, 0.0)
+            ones = jnp.where(sign_bits(x, row0 // FLAT_ROWS + j, g), 1.0, 0.0)
+            o_ref[pl.ds(pl.multiple_of(j * ROWS_BLK, ROWS_BLK),
+                        g * ROWS_BLK), :] = pack_bits(to_tile(ones), pack_m)
+
+        full, tail = divmod(tiles, ENCODE_GROUP)
+        if full:
+            pl.loop(0, full)(lambda k: group(k * ENCODE_GROUP, ENCODE_GROUP))
+        if tail:
+            group(full * ENCODE_GROUP, tail)
 
     if z is None:
-        plain()
+        encode(plain)
         return
-    c = pl.program_id(0)
-    t = pl.program_id(1).astype(jnp.uint32)
 
     @pl.when(sig_ref[c] > 0.0)
     def _noisy():
-        qrows = ROWS_BLK // 4
-        row = jax.lax.broadcasted_iota(jnp.uint32, (qrows, COLS), 0)
-        col = jax.lax.broadcasted_iota(jnp.uint32, (qrows, COLS), 1)
-        cnt = t * jnp.uint32(TILE // 4) + row * jnp.uint32(COLS) + col
-        y0, y1 = znoise.counter_words(k_ref[2 * c], k_ref[2 * c + 1], cnt)
-        u0, u1 = znoise.halves_to_u01(y0)
-        u2, u3 = znoise.halves_to_u01(y1)
-        u = jnp.concatenate([u0, u1, u2, u3], axis=0)  # (R, 1024) in (0,1)
-        bits = znoise.noisy_sign_bits(to_tile(x_ref[...]), u, inv_ref[c], z)
-        o_ref[...] = pack_bits(bits, m_ref[...])
+        encode(noisy)
 
     # a runtime sigma of 0 is the noise-free sign (stochastic_sign_bits)
-    pl.when(sig_ref[c] <= 0.0)(plain)
+    pl.when(sig_ref[c] <= 0.0)(lambda: encode(plain))
 
 
-def compress_rng_pallas(x2d: jax.Array, key2: jax.Array, sigma: jax.Array,
-                        *, z, interpret: bool) -> jax.Array:
+def compress_rng_pallas(x3d: jax.Array, key2: jax.Array, sigma: jax.Array,
+                        *, z, interpret: bool,
+                        tiles: int | None = None) -> jax.Array:
     """Fused encode of a client stack, the client axis folded into the GRID.
 
-    x2d: flat view (n * n_tiles * 64, 128) f32 of n clients' tile-padded
-    buffers stacked contiguously; key2: (n, 2) uint32; sigma: (n,) f32 ->
-    (n * n_tiles * 8, 128) u8.
+    x3d: (n, rows, 128) f32, each client's flat view (rows * 128 >= d
+    coordinates, zero past d); key2: (n, 2) uint32; sigma: (n,) f32 ->
+    (n, n_tiles * 8, 128) u8, n_tiles = ceil(rows / 64): whole 8192-element
+    tiles on the wire, the part past ``rows`` encoded as zeros.
 
-    Every client sees exactly the counter stream of a one-client call (its
-    tile index is the grid position along its own rows), so the bytes are
-    bit-identical for any n. Folding the batch into the grid (instead of
-    letting vmap batch the pallas_call) keeps each grid step's output write
+    Each grid step encodes ``tiles`` tiles of one client (by default
+    :func:`encode_tiles` of the buffer), the last block ragged, so no
+    client buffer is padded to whole tiles or blocks. Every client sees
+    exactly the counter stream of a one-client call (its tile index is its
+    position along its own rows), so the bytes are bit-identical for any n
+    and any ``tiles``. Folding the batch into the grid (instead of letting
+    vmap batch the pallas_call) keeps each grid step's output write
     loop-indexed: JAX's pallas batching rule would instead add the client
     axis to every dynamic-update-slice, which XLA lowers to a per-tile copy
     of the WHOLE (n, rows, 128) buffer.
     """
-    n = key2.shape[0]
-    n_tiles = x2d.shape[0] // n // FLAT_ROWS
+    n, rows, _ = x3d.shape
+    n_tiles = pl.cdiv(rows, FLAT_ROWS)
+    t = encode_tiles(n_tiles) if tiles is None else min(tiles, n_tiles)
     sigma = sigma.reshape(n).astype(jnp.float32)
     inv = (jnp.zeros_like(sigma) if z is None
            else znoise.threshold_scale(sigma, z))
     return pl.pallas_call(
-        functools.partial(_compress_rng_kernel, z=z),
-        grid=(n, n_tiles),
+        functools.partial(_compress_rng_kernel, z=z, rows=rows),
+        grid=(n, pl.cdiv(n_tiles, t)),
         in_specs=[
             _SMEM, _SMEM, _SMEM,
-            flat_spec(lambda c, i: (c * n_tiles + i, 0)),
+            pl.BlockSpec((None, t * FLAT_ROWS, LANE), lambda c, i: (c, i, 0)),
             matrix_spec((COLS, LANE)),
         ],
-        out_specs=pl.BlockSpec((ROWS_BLK, LANE),
-                               lambda c, i: (c * n_tiles + i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n * n_tiles * ROWS_BLK, LANE),
+        out_specs=pl.BlockSpec((None, t * ROWS_BLK, LANE),
+                               lambda c, i: (c, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, n_tiles * ROWS_BLK, LANE),
                                        jnp.uint8),
         name="compress_rng",
         interpret=interpret,
-    )(key2.reshape(-1).astype(jnp.uint32), sigma, inv, x2d, pack_matrix())
+    )(key2.reshape(-1).astype(jnp.uint32), sigma, inv, x3d, pack_matrix())
 
 
 def reduce_tiles(n_tiles: int, blk: int) -> int:

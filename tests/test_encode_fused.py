@@ -30,6 +30,7 @@ from repro.core import fedavg
 from repro.core import noise as Z
 from repro.core import wire
 from repro.kernels.zsign import ops
+from repro.kernels.zsign import zsign as ZK
 
 TILE = C.ENCODE_TILE
 
@@ -69,25 +70,55 @@ def test_threefry_matches_random123_vectors():
 # bit-exactness across fused backends
 # ---------------------------------------------------------------------------
 
+#: widths: one tile's part, whole tiles, off the lane (padded to the next
+#: 128), whole lanes but neither whole tiles nor whole blocks (no pad: the
+#: kernel reads past the buffer and encodes zeros there), and longer than
+#: one kernel block, whose last block is ragged
+WIDE = 65 * 8192 + 128 * 5
+
+
 @pytest.mark.parametrize("z", [1, Z.Z_INF])
-@pytest.mark.parametrize("d", [64, 8192, 3 * 8192 + 17, 100_003])
-@pytest.mark.parametrize("sigma", [0.3, 5.0])
+@pytest.mark.parametrize("d", [64, 8192, 3 * 8192 + 17, 100_003,
+                               5 * 8192 + 128 * 9, WIDE])
+@pytest.mark.parametrize("sigma", [0.3, 5.0, 0.0, None])
 def test_fused_backends_bit_exact(z, d, sigma):
+    """A sigma of 0.0 is a runtime 0 (the noise-free sign); None is
+    ``add_noise=False``, which draws no randomness at all."""
     key = jax.random.PRNGKey(d + z)
     flat = jax.random.normal(jax.random.PRNGKey(0), (d,))
+    add = sigma is not None
+    sig = sigma if add else 0.0
+    assert d < WIDE or ZK.encode_tiles(-(-d // TILE)) < -(-d // TILE)
+
+    def jnp_enc(chunk):
+        return C.fused_sign_encode_jnp(flat, key, sig, z=z, add_noise=add,
+                                       chunk_tiles=chunk)
     got = {
-        "jnp": C.fused_sign_encode_jnp(flat, key, sigma, z=z),
-        "jnp_chunk1": C.fused_sign_encode_jnp(flat, key, sigma, z=z,
-                                              chunk_tiles=1),
-        "jnp_chunk3": C.fused_sign_encode_jnp(flat, key, sigma, z=z,
-                                              chunk_tiles=3),
-        "pallas": ops.zsign_encode_fused(flat, key, sigma, z=z),
+        "jnp": jnp_enc(0),
+        "jnp_chunk1": jnp_enc(1),
+        "jnp_chunk3": jnp_enc(3),
+        "pallas": ops.zsign_encode_fused(flat, key, sig, z=z, add_noise=add),
     }
     n_bytes = -(-d // TILE) * TILE // 8
     for name, p in got.items():
         assert p.shape == (n_bytes,) and p.dtype == jnp.uint8, name
         np.testing.assert_array_equal(np.asarray(got["jnp"]), np.asarray(p),
                                       err_msg=name)
+    if not add or sigma == 0.0:
+        np.testing.assert_array_equal(
+            np.asarray(got["jnp"]),
+            np.asarray(wire.pack_flat(jnp.pad(flat, (0, 8 * n_bytes - d)))))
+
+
+@pytest.mark.parametrize("n_tiles,want", [
+    (1, 1), (13, 13), (64, 64), (65, 64), (60307, 64), (73220, 64)])
+def test_encode_tiles_per_step(n_tiles, want):
+    """Tiles per encode grid step: a power of two whose double-buffered
+    blocks fit the VMEM budget, or the whole buffer when it is shorter."""
+    t = ZK.encode_tiles(n_tiles)
+    assert t == want
+    assert 2 * t * (4 * TILE + TILE // 8) <= ZK.ENCODE_VMEM
+    assert t == n_tiles or t & (t - 1) == 0
 
 
 @pytest.mark.parametrize("name", ["zsign", "zsign_packed", "stosign"])
@@ -122,20 +153,31 @@ def test_vmapped_encode_matches_per_client():
     assert np.any(np.asarray(stacked[0]) != np.asarray(stacked[1]))
 
 
-def test_vmapped_pallas_encode_matches_per_client():
-    """The pallas backend's custom vmap rule (grid-folded on TPU, the
-    tile-scanned jnp twin in interpret mode) reproduces each client's
-    unbatched byte stream bit-exactly — single- and multi-tile widths."""
-    for n, d in [(5, 1024), (3, 2 * TILE + 77)]:
-        keys = jax.random.split(jax.random.PRNGKey(3), n)
-        flats = jax.random.normal(jax.random.PRNGKey(4), (n, d))
-        comp = C.Pipeline("zsign(z=1,sigma=0.5,encode_backend=pallas)")
+@pytest.mark.parametrize("route", ["vmap", "grid"])
+@pytest.mark.parametrize("n,d", [(5, 1024), (3, 2 * TILE + 77),
+                                 (3, 5 * TILE + 128 * 9)])
+def test_vmapped_pallas_encode_matches_per_client(route, n, d):
+    """A client stack reproduces each client's unbatched byte stream
+    bit-exactly: through the pallas backend's custom vmap rule ("vmap": in
+    interpret mode the tile-scanned jnp twin), and through the kernel with
+    the client axis in its grid ("grid", the rule's TPU path), here in
+    blocks of 2 tiles so that each client's last block is ragged."""
+    keys = jax.random.split(jax.random.PRNGKey(3), n)
+    flats = jax.random.normal(jax.random.PRNGKey(4), (n, d))
+    comp = C.Pipeline("zsign(z=1,sigma=0.5,encode_backend=pallas)")
+    if route == "vmap":
         stacked = jax.jit(jax.vmap(
             lambda k, f: comp.encode(k, f, None)[0]))(keys, flats)
-        for i in range(n):
-            single, _ = comp.encode(keys[i], flats[i], None)
-            np.testing.assert_array_equal(np.asarray(stacked[i]),
-                                          np.asarray(single), err_msg=str(d))
+    else:
+        x = jnp.pad(flats, ((0, 0), (0, (-d) % 128))).reshape(n, -1, 128)
+        key2 = jnp.stack(Z.key_words(keys), axis=-1)
+        stacked = ZK.compress_rng_pallas(
+            x, key2, jnp.full((n,), 0.5), z=1, interpret=True,
+            tiles=2).reshape(n, -1)
+    for i in range(n):
+        single, _ = comp.encode(keys[i], flats[i], None)
+        np.testing.assert_array_equal(np.asarray(stacked[i]),
+                                      np.asarray(single), err_msg=str(d))
 
 
 def test_vmapped_pallas_encode_cost_linear_in_clients():

@@ -52,12 +52,19 @@ def one_chip():
 
 
 @pytest.fixture(scope="module")
-def n_tiles():
-    """8192-element tiles of the tile-padded qwen2-0.5B flat buffer."""
+def d():
+    """Coordinates of the qwen2-0.5B flat buffer: whole lanes, not whole
+    tiles."""
     bundle = build_model(get_arch("qwen2_0_5b").model)
     shapes = jax.eval_shape(bundle.init, jax.random.PRNGKey(0))
     d = sum(s.size for s in jax.tree_util.tree_leaves(shapes))
-    assert d > 490_000_000
+    assert d > 490_000_000 and d % LANE == 0 and d % TILE
+    return d
+
+
+@pytest.fixture(scope="module")
+def n_tiles(d):
+    """8192-element tiles of the tile-padded qwen2-0.5B flat buffer."""
     return -(-d // TILE)
 
 
@@ -73,17 +80,18 @@ def _compile(fn, *shapes):
 
 
 @pytest.mark.parametrize("z", [1, 0, None])
-def test_compress_rng_one_client(shape, n_tiles, z):
+def test_compress_rng_one_client(shape, d, z):
+    """One client's unpadded flat view, its last block ragged."""
     _compile(lambda x, k, s: K.compress_rng_pallas(x, k, s, z=z,
                                                    interpret=False),
-             shape((n_tiles * FLAT_ROWS, LANE), jnp.float32),
+             shape((1, d // LANE, LANE), jnp.float32),
              shape((1, 2), jnp.uint32), shape((1,), jnp.float32))
 
 
 def test_compress_rng_client_stack(shape):
     _compile(lambda x, k, s: K.compress_rng_pallas(x, k, s, z=1,
                                                    interpret=False),
-             shape((N_CLIENTS * STACK_TILES * FLAT_ROWS, LANE), jnp.float32),
+             shape((N_CLIENTS, STACK_TILES * FLAT_ROWS, LANE), jnp.float32),
              shape((N_CLIENTS, 2), jnp.uint32),
              shape((N_CLIENTS,), jnp.float32))
 
@@ -98,6 +106,17 @@ def test_encode_needs_only_its_input_and_output(shape, n_tiles):
     mem = compiled.memory_analysis()
     padded_in, packed_out = 4 * n_tiles * TILE, n_tiles * TILE // 8
     assert mem.temp_size_in_bytes <= padded_in + packed_out + (1 << 20), mem
+
+
+def test_encode_of_whole_lanes_copies_nothing(shape, d, n_tiles):
+    """At qwen2-0.5B's own width, a multiple of 128 and not of the tile, the
+    kernel reads the client's buffer in place: no padded copy of it."""
+    compiled = _compile(
+        lambda x, k: ops.zsign_encode_fused(x, k, 0.01, z=1, interpret=False),
+        shape((d,), jnp.float32), shape((2,), jnp.uint32))
+    mem = compiled.memory_analysis()
+    packed_out = n_tiles * TILE // 8
+    assert mem.temp_size_in_bytes <= packed_out + (1 << 20), mem
 
 
 def test_sign_reduce(shape, n_tiles):
