@@ -2,8 +2,8 @@
 
     PYTHONPATH=src python examples/serve_lm.py
 
-Drives the same `decode_step` the dry-run lowers for the decode_32k /
-long_500k cells: batched requests, greedy sampling, per-step cache update.
+Drives each family's `decode_step`: batched requests, greedy sampling,
+per-step cache update.
 """
 import time
 

@@ -1,1 +1,1 @@
-from repro.configs.common import ArchConfig, SHAPES, get_arch, list_archs  # noqa: F401
+from repro.configs.common import ArchConfig, get_arch, list_archs  # noqa: F401

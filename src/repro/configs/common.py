@@ -1,29 +1,11 @@
-"""Config schema, shape grid and the architecture registry."""
+"""Config schema and the architecture registry."""
 from __future__ import annotations
 
 import dataclasses
 import importlib
 
 import jax.numpy as jnp
-from typing import Optional, Tuple
-
 from repro.models.api import ModelCfg
-
-
-@dataclasses.dataclass(frozen=True)
-class ShapeCfg:
-    name: str
-    kind: str          # train | prefill | decode
-    seq_len: int
-    global_batch: int
-
-
-SHAPES = {
-    "train_4k": ShapeCfg("train_4k", "train", 4_096, 256),
-    "prefill_32k": ShapeCfg("prefill_32k", "prefill", 32_768, 32),
-    "decode_32k": ShapeCfg("decode_32k", "decode", 32_768, 128),
-    "long_500k": ShapeCfg("long_500k", "decode", 524_288, 1),
-}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -31,15 +13,6 @@ class ArchConfig:
     arch_id: str
     model: ModelCfg
     source: str                      # public-literature citation tag
-    big: bool = False                # True => sequential clients single-pod,
-    #                                  per-pod clients multi-pod (replica
-    #                                  cannot fit a 16-way model shard)
-    seq_client_groups: int = 4       # sequential clients when big
-    local_steps: int = 1             # E for the dry-run train step
-    client_lr: float = 0.01
-    server_lr: float = 1.0
-    zsign_z: int = 1                 # 1 = Gaussian, 0 = uniform (z=inf)
-    zsign_sigma: float = 0.01
     notes: str = ""
 
     def reduced(self) -> "ArchConfig":

@@ -11,4 +11,4 @@ ARCH = ArchConfig(
                    n_layers=24, d_model=3840, n_heads=32, n_kv_heads=8,
                    d_ff=10240, vocab=32000, sliding_window=4096,
                    dtype=jnp.bfloat16),
-    notes="llama+mistral mix: SWA(4096) => sub-quadratic, runs long_500k")
+    notes="llama+mistral mix: SWA(4096)")

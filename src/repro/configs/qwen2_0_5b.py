@@ -10,6 +10,5 @@ ARCH = ArchConfig(
     model=ModelCfg(name="qwen2-0.5b", family="dense",
                    n_layers=24, d_model=896, n_heads=14, n_kv_heads=2,
                    d_ff=4864, vocab=151936, qkv_bias=True,
-                   dtype=jnp.bfloat16,
-                       remat_save_weights=True),
+                   dtype=jnp.bfloat16),
     notes="GQA kv=2, QKV bias, tied embeddings")

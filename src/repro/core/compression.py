@@ -1384,13 +1384,6 @@ class Pipeline:
         the engine gates the kwarg on this capability flag."""
         return self._needs_spec
 
-    def stacks_group_payloads(self) -> bool:
-        """Whether the engine's sequential-group scan should emit the raw
-        payload stack (aggregated ONCE over all groups x clients at the end)
-        instead of accumulating per-group decoded f32 sums. True exactly
-        when the wire layout is compressed — see core/fedavg.py."""
-        return self.wire_format().layout != "dense"
-
     def state_slots(self, n_coords: int):
         """All :class:`StateSlot` declarations of this pipeline's stateful
         stages, in stage order (both client- and server-scope)."""
@@ -1573,8 +1566,8 @@ class Pipeline:
         fp32 SUM over its client axis — bitpacked sign wires, COO scatters
         and dense einsums alike — the cross-device reduce is one O(d) psum
         of the accumulator (wire.psum_accumulator), NEVER a gather of the
-        per-client payload stack. The multi-device streaming driver
-        (fedavg.stream_cohort) calls this once per round, after each
+        per-client payload stack. The multi-device device walk of
+        ``fedavg.build_round_step`` calls this once per round, after each
         device's shard scan."""
         return wire.psum_accumulator(acc, axis_name)
 

@@ -1,10 +1,7 @@
 """RoundContext: the one typed knob-bundle a federated round runs under.
 
-Before this module existed, every deployment policy knob travelled as its own
-positional/keyword argument through three layers (compressor constructor ->
-``fedavg.build_round_step`` -> train/dryrun CLIs), and each sign-family
-compressor class re-resolved "auto" backends for itself. ``RoundContext``
-makes the policy a single frozen value:
+``RoundContext`` makes every deployment policy knob of a round one frozen
+value, handed to ``fedavg.build_round_step``:
 
   * ``agg_backend`` / ``encode_backend`` — backend policy for the server
     sign-reduce and the client fused encode. ``None`` means "keep whatever
@@ -13,8 +10,6 @@ makes the policy a single frozen value:
   * ``weights_are_mask`` — the caller's STATIC guarantee that aggregation
     weights are exact 0/1 participation masks (unlocks the popcount
     sign-reduce specialization; see wire.unpack_sum_mask).
-  * ``legacy_client_path`` — restore the pre-fused client step (scan over E
-    even at E == 1 + update/subtract round-trip); benchmark baseline only.
   * ``dynamic_sigma`` — thread the server state's traced sigma (Plateau
     controller) into the codec instead of its static config value.
   * ``donate_state`` — whether drivers donate the server state into the
@@ -164,8 +159,8 @@ class CohortPolicy:
     """Parsed form of ``RoundContext.cohort`` — how the round driver walks
     the cohort.
 
-      mode="vmap"    one vmap over all ``client_groups * n_clients`` clients
-                     (plus the legacy sequential-group scan when groups > 1).
+      mode="vmap"    each group of ``n_clients`` clients is one shard, one
+                     vmap; ``client_groups`` groups run as a shard scan.
       mode="stream"  shard the flat cohort into ``shard``-client slices and
                      ``lax.scan`` them through the fused encode, folding each
                      shard's payload stack into ONE running wire accumulator
@@ -382,8 +377,8 @@ class RoundModePolicy:
 class RoundContext:
     """Frozen per-deployment policy for one federated round step.
 
-    Constructed once by whoever owns the deployment decision (the train /
-    dryrun CLIs, run_fed, a test) and handed to
+    Constructed once by whoever owns the deployment decision (the train
+    CLI, the benchmark, a test) and handed to
     ``fedavg.build_round_step(loss_fn, compressor, cfg, ctx)``; the engine
     applies it to the compression pipeline via ``Pipeline.with_context`` and
     to its own client/aggregation paths. ``None`` backends defer to the
@@ -392,7 +387,6 @@ class RoundContext:
     agg_backend: Optional[str] = None
     encode_backend: Optional[str] = None
     weights_are_mask: bool = False
-    legacy_client_path: bool = False
     dynamic_sigma: bool = False
     donate_state: bool = True
     #: cohort execution policy for the round driver — a CohortPolicy spec

@@ -221,9 +221,8 @@ def unpack_sum(packed: jax.Array, weights: jax.Array,
     8-client reduce into one cache-resident gather per coordinate. The dense
     (n_clients, d) fp32 sign matrix (32 bits/coord/client) that the
     pre-fused server decode materialized never exists — the fp32 working set
-    is the output-sized accumulator only (~5-10x faster than the dense path
-    on CPU at n_clients >= 32; see BENCH_kernels.json / BENCH_round.json). Dead clients
-    (weight 0) contribute exactly 0.
+    is the output-sized accumulator only. Dead clients (weight 0) contribute
+    exactly 0.
 
     ``acc`` is the partial-accumulator FOLD hook for the streaming cohort
     driver, in one of two forms:
@@ -396,7 +395,7 @@ def check_mask_membership(mask: jax.Array) -> None:
     values. Called eagerly it raises immediately on violation; under ``jit``
     the caller must functionalize the check, i.e. wrap the jitted step as
     ``err, out = checkify.checkify(jax.jit(step))(...); err.throw()`` — the
-    train/dryrun launchers and the CI attacks job do exactly that. A bare
+    train launcher and the CI attacks job do exactly that. A bare
     ``jax.jit`` around a debug-wire step fails at trace time with checkify's
     "not functionalized" error, which is intentional: debug mode refuses to
     run unchecked.
@@ -423,9 +422,7 @@ def unpack_sum_mask(packed: jax.Array, mask: jax.Array,
     Delight 7-3, vectorized over all bytes) so one byte holds 8 clients'
     bits for a single coordinate, then ``lax.population_count`` + a tiny
     cross-block add yield the per-coordinate count. ~24 u8 passes over the
-    wire bytes total — no per-coordinate expansion to int8/fp32 at all
-    (~9x over the dense path on CPU at n_clients = 32, on par with the
-    weighted LUT gather of ``unpack_sum``; see BENCH_kernels.json). Exact
+    wire bytes total — no per-coordinate expansion to int8/fp32 at all. Exact
     by construction (integer counts), so it is bit-identical to
     ``unpack_sum``, ``unpack_sum_dense`` and the Pallas kernel for any 0/1
     mask.
@@ -439,8 +436,8 @@ def unpack_sum_mask(packed: jax.Array, mask: jax.Array,
     Because the membership contract cannot be
     checked on traced values, dispatch here is gated on a STATIC guarantee
     plumbed from whoever constructs the mask: the round engine's
-    ``build_round_step(weights_are_mask=True)`` (set by the train/dryrun
-    launchers, whose participation sampler emits exact 0/1) flips the
+    ``RoundContext(weights_are_mask=True)`` (set by the train launcher,
+    whose participation sampler emits exact 0/1) flips the
     sign-family compressors' flag and ``compression.sign_reduce`` then
     routes its jnp backend through this popcount path. Weighted calls (EF
     mask * scale, data-size weights) keep the LUT path. ``debug=True`` adds

@@ -63,9 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import wire
-from repro.core import noise as znoise
 from repro.core.context import RoundModePolicy
-from repro.core.spans import phase
 
 #: latency model kinds (RoundContext.latency spec heads)
 LATENCY_KINDS = ("zero", "const", "linear", "lognormal", "pareto")
@@ -223,73 +221,41 @@ def simulate_close_times(policy: RoundModePolicy, model: LatencyModel,
 
 
 def build_async_round_step(*, policy: RoundModePolicy, latency_spec,
-                           compressor, cfg, round_math, finish,
-                           constrain_wire, cohort_policy, adversary,
-                           total: int):
+                           compressor, host_walk, finish,
+                           cohort_policy, adversary, total: int):
     """Assemble the async round driver. Called ONLY from
-    ``fedavg.build_round_step`` (which owns context resolution, the round
-    math, and the ``_finish`` decode closure); every argument after
+    ``fedavg.build_round_step`` (which owns context resolution, the shard
+    executor, and the ``_finish`` decode closure); every argument after
     ``policy``/``latency_spec`` is one of that builder's internals, handed
-    over so the async driver runs the IDENTICAL per-shard computation.
+    over so the async driver runs the sync host walk (``host_walk``) with
+    its own fold weights.
 
     Returns ``async_round_step(state, batch, mask) -> (state, metrics)`` —
     a host Python loop (do not jit)."""
     from repro.core import fedavg  # deferred: breaks the core<->fed cycle
 
     latency = parse_latency(latency_spec)
-    codec = getattr(compressor, "codec", compressor)
-    if policy.staleness == "poly" and getattr(codec, "weights_are_mask",
-                                              False):
+    if policy.staleness == "poly" and getattr(compressor.codec,
+                                              "weights_are_mask", False):
         raise ValueError(
             "staleness=poly(...) folds FRACTIONAL stale weights, which "
             "breaks the static weights_are_mask 0/1 contract (and the "
             "vote/popcount aggregation laws built on it). Use "
             "staleness=cutoff(s) with this pipeline, or drop "
             "weights_are_mask.")
-    shard_fns = {}
     #: host-side event queue: arrival round -> list of
     #: (compute_round, client_id, fold_weight, payload_row); rows are
     #: numpy trees, replayed in (compute_round, client_id) order
     pending = {}
 
-    def _shard_fn(spec, shard):
-        # the sync host driver's jitted per-shard kernel, generalized two
-        # ways: a FOLD weight vector separate from the compute mask (late
-        # clients compute at mask weight but fold in a later round), and
-        # the encoded payload stack as an extra output so late rows can be
-        # sliced into the host-side queue
-        key = (shard, spec.n_coords)
-        if key not in shard_fns:
-            def fn(params, sub, sigma, server, round_idx, s_idx, batch_s,
-                   cstate_s, mask_s, fold_w_s, acc, loss_acc):
-                keys_s = znoise.client_keys(sub, s_idx * jnp.uint32(shard),
-                                            shard)
-                idx_s = (s_idx.astype(jnp.int32) * shard
-                         + jnp.arange(shard, dtype=jnp.int32))
-                enc, new_cstate_s, loss_s = round_math.group_encode(
-                    spec, params, batch_s, keys_s, cstate_s, mask_s, sigma,
-                    idx_s, round_idx, server)
-                with phase("fed.server.fold"):
-                    acc = compressor.aggregate(enc, fold_w_s, spec.n_coords,
-                                               acc=acc)
-                if not isinstance(acc, wire.SignFoldAcc):
-                    acc = constrain_wire(acc)
-                return acc, loss_acc + loss_s, new_cstate_s, enc
-            shard_fns[key] = jax.jit(fn)
-        return shard_fns[key]
-
     def async_round_step(state, batch, mask):
         """Async round driver: shard walk + deadline fold + stale-payload
         queue. Python loop — do NOT wrap in jax.jit."""
         spec = wire.tree_spec(state.params)
-        plan = fedavg.resolve_cohort(cohort_policy, total, spec.n_coords,
-                                     None)
+        plan = fedavg.resolve_cohort(cohort_policy, total, spec.n_coords)
         shard = plan.shard if plan.mode == "stream" else total
-        n_shards = -(-total // shard)
         rng, sub = jax.random.split(state.rng)
-        sigma = state.sigma
         r = int(state.round)
-        stateful = state.comp_state is not None
 
         mask_np = np.asarray(mask, np.float32)
         if adversary is not None:
@@ -310,51 +276,22 @@ def build_async_round_step(*, policy: RoundModePolicy, latency_spec,
         fold_w = (flat_mask * on_time).astype(np.float32)
         late_ids = np.nonzero((stale_w > 0.0) & ~on_time
                               & (flat_mask > 0.0))[0]
-
-        gen = fedavg.iter_shards(batch, compute_mask.reshape(mask_np.shape),
-                                 state.comp_state, shard=shard, total=total)
-        slots = n_shards * shard
-        fold_w_pad = np.zeros(slots, np.float32)
+        fold_w_pad = np.zeros(-(-total // shard) * shard, np.float32)
         fold_w_pad[:total] = fold_w
-        cur = jax.device_put(next(gen))
-        enc_shape = jax.eval_shape(
-            lambda b, k, c, m: round_math.group_encode(
-                spec, state.params, b, k, c, m, sigma,
-                server=state.comp_server)[0],
-            cur[1], znoise.client_keys(sub, 0, shard), cur[2], cur[3])
-        acc = (compressor.fold_init(enc_shape)
-               if hasattr(compressor, "fold_init") else None)
-        if acc is None:
-            agg_shape = jax.eval_shape(
-                lambda e, m: compressor.aggregate(e, m, spec.n_coords),
-                enc_shape, cur[3])
-            acc = jnp.zeros(agg_shape.shape, agg_shape.dtype)
-        loss_sum = jnp.zeros(())
-        fn = _shard_fn(spec, shard)
-        rows_host, prev_rows = [], None
-        for s_i in range(n_shards):
-            # double buffer, exactly as the sync host driver: upload shard
-            # s+1 before launching shard s, drain shard s-1's state rows
-            # while shard s computes
-            nxt = jax.device_put(next(gen)) if s_i + 1 < n_shards else None
-            w_s = jnp.asarray(fold_w_pad[s_i * shard:(s_i + 1) * shard])
-            acc, loss_sum, rows, enc = fn(state.params, sub, sigma,
-                                          state.comp_server, state.round,
-                                          *cur, w_s, acc, loss_sum)
-            if stateful and prev_rows is not None:
-                rows_host.append(jax.tree.map(np.asarray, prev_rows))
-            prev_rows = rows
+
+        def queue_late(lo, enc):
             # queue this shard's late payload rows for their arrival round
             # (each client id < total owns exactly one non-pad slot)
-            lo = s_i * shard
             for cid in late_ids[(late_ids >= lo) & (late_ids < lo + shard)]:
                 row = jax.tree.map(lambda x: np.asarray(x[int(cid) - lo]),
                                    enc)
-                arrival = r + int(stale_s[cid])
-                pending.setdefault(arrival, []).append(
+                pending.setdefault(r + int(stale_s[cid]), []).append(
                     (r, int(cid), float(flat_mask[cid] * stale_w[cid]),
                      row))
-            cur = nxt
+
+        acc, loss_sum, new_cstate = host_walk(
+            state, spec, sub, batch, compute_mask.reshape(mask_np.shape),
+            shard, fold_w=fold_w_pad, on_payload=queue_late)
 
         # fold the stale payloads ARRIVING this round, in deterministic
         # (compute_round, client_id) order, each at its staleness weight
@@ -365,22 +302,7 @@ def build_async_round_step(*, policy: RoundModePolicy, latency_spec,
             acc = compressor.aggregate(stacked,
                                        jnp.asarray([w], jnp.float32),
                                        spec.n_coords, acc=acc)
-            if not isinstance(acc, wire.SignFoldAcc):
-                acc = constrain_wire(acc)
             stale_weight_sum += w
-        if hasattr(compressor, "fold_finalize"):
-            acc = constrain_wire(compressor.fold_finalize(acc)) \
-                if isinstance(acc, wire.SignFoldAcc) else acc
-
-        new_cstate = None
-        if stateful:
-            rows_host.append(jax.tree.map(np.asarray, prev_rows))
-            stacked = jax.tree.map(lambda *rs: np.concatenate(rs, axis=0),
-                                   *rows_host)
-            new_cstate = jax.tree.map(
-                lambda x: x[:total].reshape(
-                    (cfg.client_groups, cfg.n_clients) + x.shape[1:]),
-                stacked)
 
         # the effective participation of the round: on-time mask weights
         # plus the stale weights folded in — _finish divides the decoded
@@ -392,7 +314,8 @@ def build_async_round_step(*, policy: RoundModePolicy, latency_spec,
         eff_w = fold_w.copy()
         eff_w[0] += np.float32(stale_weight_sum)
         eff_mask = jnp.asarray(eff_w.reshape(mask_np.shape))
-        return finish(state, spec, rng, sigma, acc, new_cstate, loss_sum,
+        return finish(state, spec, rng, state.sigma,
+                      compressor.fold_finalize(acc), new_cstate, loss_sum,
                       eff_mask, plan.shard)
 
     return async_round_step

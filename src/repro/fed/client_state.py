@@ -9,8 +9,8 @@ engine-facing semantics the round drivers rely on:
                    ``(client_groups, n_clients) + shape`` tree ONCE
                    (``fedavg.init_server_state``), slices it per shard under
                    every cohort plan (vmap / stream / host feed / async
-                   buffering), and shards it along the cohort axis
-                   (``launch/sharding.wire_state_specs``).
+                   buffering), and shards it along the cohort axis under
+                   ``stream(devices=D)``.
   scope="server"   one shared buffer, replicated across devices; updated in
                    the round tail (``_finish``) from the DECODED aggregate —
                    never from per-client payloads, so no dense

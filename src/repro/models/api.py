@@ -1,7 +1,7 @@
 """Unified model API: ModelCfg + build_model -> ModelBundle.
 
-ModelBundle is what the federated engine, launcher, dry-run and tests
-consume: init / loss_fn / decode_step / init_cache / per-step input specs.
+ModelBundle is what the federated engine, launcher and tests consume:
+init / loss_fn / decode_step / init_cache / per-step input specs.
 """
 from __future__ import annotations
 
@@ -26,7 +26,7 @@ class ModelCfg:
     vocab: int
     moe_experts: int = 0
     moe_topk: int = 0
-    moe_ep: bool = False   # expert-parallel (big experts) vs replicated
+    moe_ep: bool = False   # capacity MoE: one-hot einsum dispatch vs scatter
     qkv_bias: bool = False
     sliding_window: int = 0
     tie_embeddings: bool = True
@@ -35,9 +35,6 @@ class ModelCfg:
     n_img_tokens: int = 0       # vlm stub prefix length
     src_frac: float = 0.5       # encdec: fraction of seq_len used as source
     q_chunk: int = 512
-    remat_save_weights: bool = False  # keep FSDP-gathered layer weights across
-    #   remat: 1/3 less gather traffic for +L*layer_bytes HBM — only viable
-    #   when per-layer weights are small (see EXPERIMENTS.md §Perf)
     rms_eps: float = 1e-6       # the mla_moe family's layer and final
     #   RMSNorm eps; the other families run layers.rms_norm's default, 1e-6
     # -- the mla_moe family (DeepSeek-V3 layout): latent attention, leading
@@ -98,8 +95,6 @@ class ModelBundle:
     decode_step: Callable         # (params, cache, tokens, position) -> (logits, cache)
     init_cache: Callable          # (batch, max_len) -> cache
     train_batch_spec: Callable    # (micro_batch, seq_len) -> pytree of ShapeDtypeStruct
-    decode_supported: bool = True
-    subquadratic: bool = False    # eligible for long_500k
 
 
 def _lm_specs(cfg: ModelCfg):
@@ -117,8 +112,7 @@ def build_model(cfg: ModelCfg) -> ModelBundle:
             loss_fn=lambda p, b: T.loss_fn(p, b, cfg),
             decode_step=lambda p, c, t, pos: T.decode_step(p, c, t, pos, cfg),
             init_cache=lambda b, m: T.init_cache(cfg, b, m),
-            train_batch_spec=_lm_specs(cfg),
-            subquadratic=cfg.sliding_window > 0)
+            train_batch_spec=_lm_specs(cfg))
 
     if cfg.family == "mla_moe":
         from repro.models import mla_moe as M
@@ -138,8 +132,7 @@ def build_model(cfg: ModelCfg) -> ModelBundle:
             loss_fn=lambda p, b: Hy.loss_fn(p, b, cfg),
             decode_step=lambda p, c, t, pos: Hy.decode_step(p, c, t, pos, cfg),
             init_cache=lambda b, m: Hy.init_cache(cfg, b, m),
-            train_batch_spec=_lm_specs(cfg),
-            subquadratic=True)
+            train_batch_spec=_lm_specs(cfg))
 
     if cfg.family == "xlstm":
         from repro.models import xlstm as X
@@ -149,8 +142,7 @@ def build_model(cfg: ModelCfg) -> ModelBundle:
             loss_fn=lambda p, b: X.loss_fn(p, b, cfg),
             decode_step=lambda p, c, t, pos: X.decode_step(p, c, t, pos, cfg),
             init_cache=lambda b, m: X.init_cache(cfg, b, m),
-            train_batch_spec=_lm_specs(cfg),
-            subquadratic=True)
+            train_batch_spec=_lm_specs(cfg))
 
     if cfg.family == "encdec":
         from repro.models import encdec as E
@@ -168,8 +160,7 @@ def build_model(cfg: ModelCfg) -> ModelBundle:
             loss_fn=lambda p, b: E.loss_fn(p, b, cfg),
             decode_step=lambda p, c, t, pos: E.decode_step(p, c, t, pos, cfg),
             init_cache=lambda b, m: E.init_cache(cfg, b, m, src_len=2048),
-            train_batch_spec=spec,
-            subquadratic=False)
+            train_batch_spec=spec)
 
     if cfg.family == "vlm":
         from repro.models import transformer as T
@@ -201,7 +192,6 @@ def build_model(cfg: ModelCfg) -> ModelBundle:
             loss_fn=vlm_loss,
             decode_step=lambda p, c, t, pos: T.decode_step(p, c, t, pos, cfg),
             init_cache=lambda b, m: T.init_cache(cfg, b, m),
-            train_batch_spec=spec,
-            subquadratic=False)
+            train_batch_spec=spec)
 
     raise ValueError(f"unknown family {cfg.family!r}")

@@ -14,13 +14,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
-from repro.launch.hints import seq_shard, fsdp_params
 
 
 def _remat_policy(cfg):
-    names = ["kv_gathered"] + (["fsdp_gathered"] if cfg.remat_save_weights
-                               else [])
-    return jax.checkpoint_policies.save_only_these_names(*names)
+    return jax.checkpoint_policies.save_only_these_names("attn_kv")
 
 
 def init_params(key, cfg):
@@ -73,7 +70,7 @@ def _mem_kv(mem, lp, cfg):
 
 def encode(params, embeds, cfg):
     """embeds: (B, S_src, D) stub frame embeddings -> encoder memory."""
-    x = seq_shard(embeds.astype(cfg.dtype))
+    x = embeds.astype(cfg.dtype)
     S = x.shape[1]
     positions = jnp.arange(S, dtype=jnp.int32)
     bidir = cfg.attn_cfg_bidir()
@@ -82,11 +79,9 @@ def encode(params, embeds, cfg):
     @partial(jax.checkpoint, prevent_cse=False,
              policy=_remat_policy(cfg))
     def body(x, lp):
-        h = seq_shard(x + L.attention(L.rms_norm(x, lp["enc_ln1"]),
-                                      fsdp_params(lp["enc_attn"], skip=()),
-                                      bidir, positions))
-        return seq_shard(h + L.swiglu(L.rms_norm(h, lp["enc_ln2"]),
-                                      fsdp_params(lp["enc_mlp"], skip=()))), ()
+        h = x + L.attention(L.rms_norm(x, lp["enc_ln1"]), lp["enc_attn"],
+                            bidir, positions)
+        return h + L.swiglu(L.rms_norm(h, lp["enc_ln2"]), lp["enc_mlp"]), ()
 
     x, _ = jax.lax.scan(body, x, stack)
     return L.rms_norm(x, params["enc_lnf"])
@@ -94,7 +89,7 @@ def encode(params, embeds, cfg):
 
 def decode_train(params, mem, tokens, cfg):
     """mem: (B, S_src, D); tokens: (B, S_tgt)."""
-    x = seq_shard(params["embed"][tokens])
+    x = params["embed"][tokens]
     S = x.shape[1]
     positions = jnp.arange(S, dtype=jnp.int32)
     stack = {k: params[k] for k in
@@ -103,15 +98,13 @@ def decode_train(params, mem, tokens, cfg):
     @partial(jax.checkpoint, prevent_cse=False,
              policy=_remat_policy(cfg))
     def body(x, lp):
-        x_attn = fsdp_params(lp["x_attn"], skip=())
-        h = seq_shard(x + L.attention(L.rms_norm(x, lp["dec_ln1"]),
-                                      fsdp_params(lp["dec_attn"], skip=()),
-                                      cfg.attn_cfg(), positions))
+        x_attn = lp["x_attn"]
+        h = x + L.attention(L.rms_norm(x, lp["dec_ln1"]), lp["dec_attn"],
+                            cfg.attn_cfg(), positions)
         mk, mv = _mem_kv(mem, x_attn, cfg)
-        h = seq_shard(h + _cross_attention(L.rms_norm(h, lp["dec_ln2"]),
-                                           mk, mv, x_attn, cfg))
-        return seq_shard(h + L.swiglu(L.rms_norm(h, lp["dec_ln3"]),
-                                      fsdp_params(lp["dec_mlp"], skip=()))), ()
+        h = h + _cross_attention(L.rms_norm(h, lp["dec_ln2"]), mk, mv,
+                                 x_attn, cfg)
+        return h + L.swiglu(L.rms_norm(h, lp["dec_ln3"]), lp["dec_mlp"]), ()
 
     x, _ = jax.lax.scan(body, x, stack)
     return L.rms_norm(x, params["dec_lnf"])

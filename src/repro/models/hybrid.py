@@ -15,15 +15,12 @@ import jax.numpy as jnp
 
 from repro.models import layers as L
 from repro.models import mamba as M
-from repro.launch.hints import seq_shard, fsdp_params
 
 SUB = 8  # sublayers per super-block: 1 attn + 7 mamba
 
 
 def _remat_policy(cfg):
-    names = ["kv_gathered"] + (["fsdp_gathered"] if cfg.remat_save_weights
-                               else [])
-    return jax.checkpoint_policies.save_only_these_names(*names)
+    return jax.checkpoint_policies.save_only_these_names("attn_kv")
 
 
 def init_params(key, cfg):
@@ -57,14 +54,11 @@ def _super_block(cfg, x, bp, positions):
     for s in range(SUB):
         xn = L.rms_norm(x, bp["ln_mix"][s])
         if s == 0:
-            mix = L.attention(xn, fsdp_params(bp["attn"], skip=()),
-                              cfg.attn_cfg(), positions)
+            mix = L.attention(xn, bp["attn"], cfg.attn_cfg(), positions)
         else:
             lp = jax.tree.map(lambda w: w[s - 1], bp["mamba"])
-            # mamba weights stay sharded: the block is channel-parallel
-            # (mamba.py docstring), so replicating them would defeat it.
             mix = M.mamba_block(xn, lp, d_model=cfg.d_model)
-        x = seq_shard(x + mix)
+        x = x + mix
         hn = L.rms_norm(x, bp["ln_ffn"][s])
         if s % 2 == 0:
             lp = jax.tree.map(lambda w: w[moe_i], bp["moe"])
@@ -74,14 +68,14 @@ def _super_block(cfg, x, bp, positions):
             moe_i += 1
         else:
             lp = jax.tree.map(lambda w: w[mlp_i], bp["mlp"])
-            y = L.swiglu(hn, fsdp_params(lp, skip=()))
+            y = L.swiglu(hn, lp)
             mlp_i += 1
-        x = seq_shard(x + y)
+        x = x + y
     return x, aux
 
 
 def forward_hidden(params, tokens, cfg):
-    x = seq_shard(params["embed"][tokens])
+    x = params["embed"][tokens]
     S = x.shape[1]
     positions = jnp.arange(S, dtype=jnp.int32)
     stack = {k: params[k] for k in ("attn", "mamba", "moe", "mlp", "ln_mix", "ln_ffn")}

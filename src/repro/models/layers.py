@@ -5,7 +5,7 @@ which a chip holds a share.
 
 All layer parameter trees are built *stacked over depth* (leading dim L) so
 model forwards are a single ``lax.scan`` over layers — compile time and HLO
-size independent of depth (essential for the 40-cell dry-run).
+size independent of depth.
 """
 from __future__ import annotations
 
@@ -18,7 +18,6 @@ import jax.ad_checkpoint
 import jax.numpy as jnp
 
 from repro.core.spans import phase
-from repro.launch import hints
 
 
 def _init(key, shape, scale=None, dtype=jnp.float32):
@@ -97,19 +96,13 @@ def _qkv(x, lp, cfg: AttnCfg, positions):
     q = apply_rope(q, positions, cfg.rope_theta)
     k = apply_rope(k, positions, cfg.rope_theta)
     if S > 1:
-        # sequence-parallel attention: queries stay seq-sharded, the (small,
-        # GQA) keys/values are gathered along seq — scores + AV then local.
-        # The optimization barrier keeps the gather on the bf16 value (XLA
-        # otherwise fuses the fp32 upcast for the scores matmul *before* the
-        # all-gather: 2x wire bytes, measured).
-        q = hints.seq_shard(q, 1)
-        k, v = jax.lax.optimization_barrier(
-            (hints.gather_seq(k), hints.gather_seq(v)))
-        # name the gathered K/V so the layer remat policy can SAVE them:
-        # re-gathering on the remat pass costs a third of the attention
-        # collective traffic for 134 MB/layer of residency (granite-moe).
-        k = jax.ad_checkpoint.checkpoint_name(k, "kv_gathered")
-        v = jax.ad_checkpoint.checkpoint_name(v, "kv_gathered")
+        # the barrier keeps K/V in the stored dtype (XLA otherwise fuses the
+        # fp32 upcast of the scores matmul into them); the name lets the
+        # layer remat policies SAVE K/V rather than recompute the
+        # projections and RoPE in the backward pass
+        k, v = jax.lax.optimization_barrier((k, v))
+        k = jax.ad_checkpoint.checkpoint_name(k, "attn_kv")
+        v = jax.ad_checkpoint.checkpoint_name(v, "attn_kv")
     return q, k, v
 
 
@@ -122,8 +115,7 @@ def _sdpa_chunk(q_chunk, k, v, q_pos, k_pos, cfg: AttnCfg):
     S, K = k.shape[1], k.shape[2]
     rep = H // K
     # grouped-GQA einsum instead of jnp.repeat: keeps the K(=kv) head dim
-    # explicit so backward reduces dK/dV at kv-head width (7x smaller
-    # all-reduce under sequence sharding; EXPERIMENTS.md §Perf iteration 4).
+    # explicit so backward reduces dK/dV at kv-head width.
     q5 = q_chunk.reshape(B, c, K, rep, hd)
     scores = jnp.einsum("bcgrd,bsgd->bgrcs", q5, k,
                         preferred_element_type=jnp.float32) / (hd ** 0.5)
@@ -141,13 +133,8 @@ def _flash_kv_attention(q, k, v, positions, cfg: AttnCfg, kv_chunk: int,
                         remat: bool = False):
     """Flash-style attention chunked over the KEY/VALUE axis with online
     softmax; q/k: (B, S, H|K, hd), v: (B, S, K, vd), vd the V width (latent
-    attention's differs from the QK width).  Why KV-chunked (not
-    Q-chunked): under sequence sharding the Q/seq dim is distributed —
-    reshaping it into chunks forces GSPMD to
-    all-gather full activations per layer (measured, EXPERIMENTS.md §Perf).
-    K/V are explicitly replicated (gather_seq in _qkv — small under GQA), so
-    chunking THEM is sharding-transparent, and peak scores memory drops from
-    (B,H,S,S) to (B,H,S,kc). ``remat`` recomputes each chunk's scores in the
+    attention's differs from the QK width). Chunking the keys drops peak
+    scores memory from (B,H,S,S) to (B,H,S,kc). ``remat`` recomputes each chunk's scores in the
     backward pass, which then keeps only the carry of each chunk and not its
     (B,H,S,kc) probabilities: at S = 8192 those come to 4 GB a chunk stack.
     """
@@ -407,9 +394,7 @@ def moe_init(key, d_model, d_ff, n_experts, n_layers, dtype):
 
 
 def _topk_iterative(scores, k: int):
-    """top-k via k argmax+mask rounds. jax.lax.top_k over a sharded batch
-    lowers through Shardy's replicate-fallback (measured 6.4 GB/dev of
-    all-gather on granite-moe); k reduces stay fully local."""
+    """top-k via k argmax+mask rounds."""
     vals, idxs = [], []
     s = scores
     for _ in range(k):
@@ -423,59 +408,42 @@ def _topk_iterative(scores, k: int):
 
 def moe_apply(x, lp, n_experts: int, top_k: int, capacity_factor: float = 1.25,
               ep: bool = False):
-    """Capacity-based top-k MoE with SHARD-LOCAL dispatch.
+    """Capacity-based top-k MoE over every expert, dispatched per sequence:
+    each expert takes at most C = S * k / E * capacity_factor tokens of a
+    sequence and the overflow is dropped.
 
-    Distribution design (EXPERIMENTS.md §Perf, granite-moe iterations): a
-    flat (B*S) dispatch mixes the sequence-sharded dim into an unsharded one,
-    so every scatter/gather against the expert buffer lowers to an all-reduce
-    of the full fp32 buffer (measured 103 GB/dev per round on granite-moe).
-    Instead the sequence dim is split explicitly into
-    (n_shards, S_local) — a sharding-preserving reshape — and dispatch /
-    combine are vmapped per shard: all index ops stay device-local.
-
-    * ep=False (replicated experts — right call for fine-grained MoE like
-      granite's 32 x d_ff=512): expert weights are FSDP-gathered per layer
-      (~100 MB) and compute is fully local. Capacity is per shard.
-    * ep=True (big experts — llama4/jamba): the dispatch buffer is resharded
-      shard-dim->expert-dim (an all-to-all), expert matmuls run
-      expert-parallel over `model`, and the result is resharded back.
+    * ep=False: dispatch and combine are a scatter into and a gather out of
+      the (E, C, D) expert buffer.
+    * ep=True: both are one-hot einsums; at d_ff >= 8k the extra
+      (E*C)/(3*d_ff) ~ 1% FLOPs is noise.
     """
-    from repro.launch import hints as H
     B, S, D = x.shape
     E, k = n_experts, top_k
-    ns = H.seq_shard_count()
-    if S % ns != 0 or (S // ns) * k < E:
-        ns = 1
-    S_loc = S // ns
-    C = max(1, int(S_loc * k / E * capacity_factor))
+    C = max(1, int(S * k / E * capacity_factor))
 
-    xg = hints.shard_dim(x.reshape(B, ns, S_loc, D), 1)      # dim1: seq-sharded
-    logits = xg.astype(jnp.float32) @ lp["router"]           # (B, ns, S_loc, E)
-    gate_all = hints.shard_dim(jax.nn.softmax(logits, axis=-1), 1)
-    gates, idx = _topk_iterative(gate_all, k)                # (B, ns, S_loc, k)
+    logits = x.astype(jnp.float32) @ lp["router"]            # (B, S, E)
+    gate_all = jax.nn.softmax(logits, axis=-1)
+    gates, idx = _topk_iterative(gate_all, k)                # (B, S, k)
     gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    flat_e = idx.reshape(B, ns, S_loc * k)
-    oh = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)          # (B, ns, S*k, E)
-    pos = jnp.cumsum(oh, axis=2) - oh
-    pos = jnp.sum(pos * oh, axis=-1)                         # (B, ns, S*k)
+    flat_e = idx.reshape(B, S * k)
+    oh = jax.nn.one_hot(flat_e, E, dtype=jnp.int32)          # (B, S*k, E)
+    pos = jnp.cumsum(oh, axis=1) - oh
+    pos = jnp.sum(pos * oh, axis=-1)                         # (B, S*k)
     keep = pos < C
     e_idx = jnp.where(keep, flat_e, E - 1)
     p_idx = jnp.where(keep, pos, C - 1)
     # The slot->cell map is INJECTIVE on kept slots, so scatter and gather
     # are exact transposes of each other. XLA cannot know this: the autodiff
-    # transpose of the batched gather lowers to a replicate-then-scatter
-    # (measured 2x51 GB/dev in backward+remat). custom_vjp encodes the
-    # injectivity — dispatch^T = collect, collect^T = dispatch — so both
-    # directions are shard-local pinned gathers/scatters.
+    # transpose of the batched gather is a general scatter. custom_vjp
+    # encodes the injectivity — dispatch^T = collect, collect^T = dispatch.
 
     def _scatter(vals, el, pl):
         f = lambda v, e, p: jnp.zeros((E, C, D), v.dtype).at[e, p].add(v)
-        return hints.shard_dim(jax.vmap(jax.vmap(f))(vals, el, pl), 1)
+        return jax.vmap(f)(vals, el, pl)
 
     def _collect(buf, el, pl):
-        f = lambda b1, e, p: b1[e, p]
-        return hints.shard_dim(jax.vmap(jax.vmap(f))(buf, el, pl), 1)
+        return jax.vmap(lambda b1, e, p: b1[e, p])(buf, el, pl)
 
     @jax.custom_vjp
     def moe_dispatch(vals, el, pl):
@@ -493,70 +461,34 @@ def moe_apply(x, lp, n_experts: int, top_k: int, capacity_factor: float = 1.25,
         lambda buf, el, pl: (_collect(buf, el, pl), (el, pl)),
         lambda res, d_out: (_scatter(d_out, *res), None, None))
 
-    vals = jnp.broadcast_to(xg[:, :, :, None, :],
-                            (B, ns, S_loc, k, D)).reshape(B, ns, S_loc * k, D)
+    vals = jnp.broadcast_to(x[:, :, None, :], (B, S, k, D)).reshape(
+        B, S * k, D)
     vals = jnp.where(keep[..., None], vals, 0).astype(x.dtype)
 
     if ep:
-        # ep mode (big experts, batch+seq both sharded): Shardy's batched
-        # scatter/gather replicate-fallback costs TB/dev here (measured on
-        # jamba). Dispatch/combine as ONE-HOT EINSUMS instead — partitions
-        # perfectly, and at d_ff >= 8k the extra (E*C)/(3*d_ff) ~ 1% FLOPs
-        # is noise.
         cell = jnp.where(keep, e_idx * C + p_idx, E * C)
-        oh = jax.nn.one_hot(cell, E * C, dtype=x.dtype)  # (B,ns,S*k,EC)
-        buf = jnp.einsum("bnsk,bnsd->bnkd", oh, vals)
-        # Two-step reshard (measured best of three variants on jamba:
-        # 3.08 TB vs 3.51 TB direct-to-expert vs 7.59 TB ns-only): pin the
-        # einsum output seq-sharded first, THEN all-to-all to
-        # expert-parallel — GSPMD lowers the staged transition efficiently.
-        buf = hints.shard_dim(buf.reshape(B, ns, E, C, D), 1)
-        buf = hints.shard_dim(buf, 2, ("model",))
+        oh = jax.nn.one_hot(cell, E * C, dtype=x.dtype)      # (B, S*k, EC)
+        buf = jnp.einsum("bsk,bsd->bkd", oh, vals).reshape(B, E, C, D)
     else:
-        buf = moe_dispatch(vals, e_idx, p_idx)   # (B,ns,E,C,D), ns-sharded
+        buf = moe_dispatch(vals, e_idx, p_idx)               # (B, E, C, D)
+
+    h = jnp.einsum("becd,edf->becf", buf, lp["w1"])
+    g3 = jnp.einsum("becd,edf->becf", buf, lp["w3"])
+    y = jnp.einsum("becf,efd->becd", jax.nn.silu(h) * g3, lp["w2"])
 
     if ep:
-        # JIT-gather the non-expert ('data') shards of the expert weights in
-        # bf16, keeping E expert-parallel: avoids the f32 full-weight gather
-        # GSPMD falls back to when the stored 'data' sharding on d_ff
-        # conflicts with the batch dim of buf (measured 515 GB/dev, llama4).
-        def _egather(w):
-            mesh = hints._CTX["mesh"]
-            if mesh is None:
-                return w
-            from jax.sharding import NamedSharding, PartitionSpec as P
-            return jax.lax.optimization_barrier(
-                jax.lax.with_sharding_constraint(
-                    w, NamedSharding(mesh, P("model", None, None))))
-
-        w1, w2, w3 = _egather(lp["w1"]), _egather(lp["w2"]), _egather(lp["w3"])
-    else:
-        from repro.launch.hints import fsdp_params
-        g = fsdp_params({"g1": lp["w1"], "g2": lp["w2"], "g3": lp["w3"]},
-                        skip=())
-        w1, w2, w3 = g["g1"], g["g2"], g["g3"]
-
-    h = jnp.einsum("bnecd,edf->bnecf", buf, w1)
-    g3 = jnp.einsum("bnecd,edf->bnecf", buf, w3)
-    y = jnp.einsum("bnecf,efd->bnecd", jax.nn.silu(h) * g3, w2)
-
-    if ep:
-        y = H.shard_dim(y, 1)                                # all-to-all out
-        out_slots = jnp.einsum("bnsk,bnkd->bnsd", oh,
-                               y.reshape(B, ns, E * C, D).astype(x.dtype))
-        out_slots = hints.shard_dim(out_slots, 1)
+        out_slots = jnp.einsum("bsk,bkd->bsd", oh,
+                               y.reshape(B, E * C, D).astype(x.dtype))
     else:
         out_slots = moe_collect(y.astype(x.dtype), e_idx, p_idx)
-    gl = gates.reshape(B, ns, S_loc * k)
     out_slots = jnp.where(keep[..., None], out_slots, 0) \
-        * gl[..., None].astype(x.dtype)
-    out = hints.shard_dim(
-        out_slots.reshape(B, ns, S_loc, k, D).sum(axis=3), 1)
+        * gates.reshape(B, S * k)[..., None].astype(x.dtype)
+    out = out_slots.reshape(B, S, k, D).sum(axis=2)
     frac = jnp.mean(jax.nn.one_hot(idx[..., 0], E, dtype=jnp.float32),
-                    axis=(0, 1, 2))
-    prob = jnp.mean(gate_all, axis=(0, 1, 2))
+                    axis=(0, 1))
+    prob = jnp.mean(gate_all, axis=(0, 1))
     aux = E * jnp.sum(frac * prob)
-    return out.reshape(B, S, D), aux
+    return out, aux
 
 
 # ---------------------------------------------------------------------------

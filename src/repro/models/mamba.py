@@ -88,38 +88,20 @@ def _causal_conv(x, w):
 
 
 def mamba_block(x, lp, *, d_model: int):
-    """x: (B, T, D) -> (B, T, D). Training forward.
-
-    Distribution: the time recurrence cannot be sequence-parallel, but it IS
-    embarrassingly channel-parallel. Inside the block the sequence dim is
-    therefore REPLICATED (one ~0.5 GB bf16 gather per layer on jamba) and
-    d_inner is sharded over `model`; the output projection reduce-scatters
-    back to the sequence-sharded residual stream. Naively scanning over a
-    sharded time dim instead costs 17.7 TB/dev of collectives (measured,
-    EXPERIMENTS.md §Perf jamba iteration 1).
-    """
-    from repro.launch import hints as H
+    """x: (B, T, D) -> (B, T, D). Training forward."""
     d_in = lp["in_proj"].shape[-1] // 2
     dt_rank = lp["dt_proj"].shape[0]
-    seq_par = x.shape[1] > 1
-    if seq_par:
-        x = jax.lax.optimization_barrier(H.gather_seq(x))
+    if x.shape[1] > 1:
+        x = jax.lax.optimization_barrier(x)
     xz = x @ lp["in_proj"]
-    if seq_par:
-        xz = H.shard_dim(xz, 2, ("model",))     # channel-parallel from here
     x_in, z = jnp.split(xz, 2, axis=-1)
     x_in = jax.nn.silu(_causal_conv(x_in, lp["conv_w"]))
     dt, B_, C_ = _ssm_params(x_in, lp, dt_rank)
-    if seq_par:
-        dt = H.shard_dim(dt, 2, ("model",))
     h0 = jnp.zeros((x.shape[0], d_in, D_STATE), jnp.float32)
     y, _ = _scan_chunked(dt, B_, C_, x_in, lp["a_log"], h0)
     y = y + x_in.astype(jnp.float32) * lp["d_skip"]
     y = (y.astype(x.dtype)) * jax.nn.silu(z)
-    out = y @ lp["out_proj"]
-    if seq_par:
-        out = H.seq_shard(out, 1)               # reduce-scatter to seq-sharded
-    return out
+    return y @ lp["out_proj"]
 
 
 def mamba_cache_init(batch: int, d_model: int, n_layers: int, expand: int = 2):

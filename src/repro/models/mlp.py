@@ -1,4 +1,4 @@
-"""Small MLP classifier used by the paper-figure benchmarks and examples
+"""Small MLP classifier used by the examples
 (stands in for the paper's 2-layer CNN — same scale, pure JAX)."""
 from __future__ import annotations
 

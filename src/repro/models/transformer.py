@@ -12,13 +12,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
-from repro.launch.hints import seq_shard, fsdp_params
 
 
 def _remat_policy(cfg):
-    names = ["kv_gathered"] + (["fsdp_gathered"] if cfg.remat_save_weights
-                               else [])
-    return jax.checkpoint_policies.save_only_these_names(*names)
+    return jax.checkpoint_policies.save_only_these_names("attn_kv")
 
 
 def init_params(key, cfg) -> Dict[str, Any]:
@@ -43,19 +40,14 @@ def init_params(key, cfg) -> Dict[str, Any]:
 
 def _layer(cfg, x, lp, positions):
     """One transformer block. x: (B, S, D)."""
-    lp = dict(lp)
-    lp["attn"] = fsdp_params(lp["attn"], skip=())
-    if cfg.moe_experts == 0:
-        lp["mlp"] = fsdp_params(lp["mlp"], skip=())
     h = x + L.attention(L.rms_norm(x, lp["ln1"]), lp["attn"], cfg.attn_cfg(), positions)
-    h = seq_shard(h)
     hn = L.rms_norm(h, lp["ln2"])
     if cfg.moe_experts > 0:
         y, aux = L.moe_apply(hn, lp["moe"], cfg.moe_experts, cfg.moe_topk,
                              ep=cfg.moe_ep)
     else:
         y, aux = L.swiglu(hn, lp["mlp"]), 0.0
-    return seq_shard(h + y), aux
+    return h + y, aux
 
 
 def _stacked_layer_params(params, cfg):
@@ -68,7 +60,6 @@ def _stacked_layer_params(params, cfg):
 def forward_hidden(params, tokens, cfg, *, embeds: jnp.ndarray | None = None):
     """Returns final-norm hidden states (B, S, D) and MoE aux loss."""
     x = params["embed"][tokens] if embeds is None else embeds.astype(cfg.dtype)
-    x = seq_shard(x)
     S = x.shape[1]
     positions = jnp.arange(S, dtype=jnp.int32)
     lp_stack = _stacked_layer_params(params, cfg)

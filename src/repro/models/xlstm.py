@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import _init, rms_norm
-from repro.launch.hints import seq_shard, fsdp_params
 
 CHUNK = 256
 
@@ -58,21 +57,17 @@ def mlstm_block(x, lp, *, n_heads: int):
     F = jnp.cumsum(log_f, axis=-1)                    # (B, H, T) log prod f
 
     # D[t,s] = exp(F_t - F_s + i_s), s <= t. Flash-style: chunk over the
-    # KEY axis with an online running max — queries / F stay sequence-
-    # sharded, keys+gates are gathered (sharding-transparent chunking, same
-    # rationale as layers._flash_kv_attention: chunking the SHARDED q dim
-    # forces full-activation gathers).
-    from repro.launch import hints as HN
+    # KEY axis with an online running max, as layers._flash_kv_attention.
     kc = min(CHUNK, T)
     if T % kc != 0:
         kc = T
     nc = T // kc
     qf = q.astype(jnp.float32)                        # (B, H, T, hd)
     k_g, v_g, F_g, i_g = jax.lax.optimization_barrier(
-        (HN.gather_seq(k.swapaxes(1, 2)),             # (B, T, H, hd)
-         HN.gather_seq(v.swapaxes(1, 2)),
-         HN.gather_seq(F.swapaxes(1, 2)),             # (B, T, H)
-         HN.gather_seq(i_pre.swapaxes(1, 2))))
+        (k.swapaxes(1, 2),                            # (B, T, H, hd)
+         v.swapaxes(1, 2),
+         F.swapaxes(1, 2),                            # (B, T, H)
+         i_pre.swapaxes(1, 2)))
     kt = k_g.reshape(B, nc, kc, H, hd).swapaxes(0, 1)
     vt = v_g.reshape(B, nc, kc, H, hd).swapaxes(0, 1)
     Ft = F_g.reshape(B, nc, kc, H).swapaxes(0, 1)
@@ -253,16 +248,14 @@ def _group_fwd(cfg, x, gp):
         xn = rms_norm(x, gp["ln"][s])
         if s < GROUP - 1:
             lp = jax.tree.map(lambda w: w[s], gp["mlstm"])
-            x = seq_shard(x + mlstm_block(xn, fsdp_params(lp, skip=()),
-                                          n_heads=cfg.n_heads))
+            x = x + mlstm_block(xn, lp, n_heads=cfg.n_heads)
         else:
-            x = seq_shard(x + slstm_block(xn, fsdp_params(gp["slstm"], skip=()),
-                                          n_heads=cfg.n_heads))
+            x = x + slstm_block(xn, gp["slstm"], n_heads=cfg.n_heads)
     return x
 
 
 def forward_hidden(params, tokens, cfg):
-    x = seq_shard(params["embed"][tokens])
+    x = params["embed"][tokens]
     stack = {k: params[k] for k in ("mlstm", "slstm", "ln")}
 
     @partial(jax.checkpoint, prevent_cse=False)

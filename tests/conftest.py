@@ -1,5 +1,5 @@
 import os
 
-# Tests run on the single host CPU device (the 512-device override is ONLY
-# for launch/dryrun.py). Keep XLA quiet and single-threaded-friendly.
+# Tests run on the single host CPU device. Keep XLA quiet and
+# single-threaded-friendly.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")

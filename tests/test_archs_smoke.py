@@ -1,6 +1,5 @@
 """Per-architecture smoke tests: REDUCED same-family configs, one forward /
-train step on CPU, asserting output shapes + finiteness (the FULL configs are
-exercised via the dry-run only — ShapeDtypeStructs, no allocation)."""
+train step on CPU, asserting output shapes + finiteness."""
 import jax
 import jax.numpy as jnp
 import pytest

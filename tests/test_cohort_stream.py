@@ -135,21 +135,6 @@ def test_resolve_cohort_gating():
     with pytest.raises(ValueError, match="device"):
         fedavg.resolve_cohort(
             f"stream(shard=4,devices={jax.device_count() + 1})", 8, 100)
-    # a launcher plan that shards the client axis over its own mesh
-    # (spmd_axes, e.g. dryrun's 16x16 production cell) pre-empts streaming:
-    # auto keeps the vmap plan even far above the element threshold (the
-    # shard scan would serialize the mesh-parallel axis and trigger
-    # involuntary remats), and a FORCED stream there is a config conflict
-    assert fedavg.resolve_cohort("auto", 4096, d,
-                                 spmd_axes=("data",)) == fedavg.VMAP_PLAN
-    assert fedavg.resolve_cohort("stream", 4096, d,
-                                 spmd_axes=("data",)) == fedavg.VMAP_PLAN
-    with pytest.raises(ValueError, match="client axis"):
-        fedavg.resolve_cohort("stream(shard=4)", 4096, d,
-                              spmd_axes=("data",))
-    with pytest.raises(ValueError, match="client axis"):
-        fedavg.resolve_cohort("stream(feed=host)", 4096, d,
-                              spmd_axes=("data",))
 
 
 def test_auto_shard_size():
@@ -338,6 +323,44 @@ def test_stream_bit_identical_topk_dyadic(shard):
                                   np.asarray(got.params["x"]))
     np.testing.assert_array_equal(np.asarray(ref.comp_state["ef"]),
                                   np.asarray(got.comp_state["ef"]))
+
+
+@pytest.mark.parametrize("spec", ["zsign", "ef|zsign", "identity", "qsgd",
+                                  "ef|topk(frac=0.25)"])
+def test_groups_run_as_shards_bit_identical(spec):
+    """A (G=3, N=4) cohort under vmap runs as 3 shards of 4 through the
+    shard executor: the same 12 clients as (1, 12) under stream(shard=4)
+    and stream(shard=1) give the same params and residuals to every bit
+    over 3 rounds, dead clients included. Dyadic targets and lrs and a
+    power-of-two live count keep the dense and COO sums exact at every
+    shard size; sign sums are exact through the SignFoldAcc carry."""
+    G, N, d = 3, 4, 32
+    y = jnp.round(jax.random.normal(jax.random.PRNGKey(13),
+                                    (G * N, 1, d)) * 4.0)
+    mask = jnp.ones(G * N).at[jnp.asarray([1, 6, 7, 10])].set(0.0)
+    loss_fn = lambda p, b: 0.5 * jnp.sum((p["x"] - b["y"]) ** 2)
+    outs = []
+    for groups, n, cohort in [(G, N, "vmap"), (1, G * N, "stream(shard=4)"),
+                              (1, G * N, "stream(shard=1)")]:
+        comp = C.Pipeline(spec)
+        cfg = fedavg.FedConfig(n_clients=n, client_groups=groups,
+                               client_lr=0.5, server_lr=0.5)
+        step = jax.jit(fedavg.build_round_step(
+            loss_fn, comp, cfg, RoundContext(cohort=cohort)))
+        st = fedavg.init_server_state({"x": jnp.zeros(d)}, cfg, comp,
+                                      jax.random.PRNGKey(1))
+        for _ in range(3):
+            st, m = step(st, {"y": y.reshape(groups, n, 1, d)},
+                         mask.reshape(groups, n))
+        assert float(m.participation) == 8.0
+        state = (None if st.comp_state is None
+                 else np.asarray(st.comp_state["ef"]).reshape(G * N, d))
+        outs.append((cohort, np.asarray(st.params["x"]), state))
+    (_, ref_x, ref_ef), *rest = outs
+    for cohort, x, ef in rest:
+        assert x.tobytes() == ref_x.tobytes(), cohort
+        if ref_ef is not None:
+            assert ef.tobytes() == ref_ef.tobytes(), cohort
 
 
 # ---------------------------------------------------------------------------

@@ -29,6 +29,7 @@ from repro.core import compression as C
 from repro.core import fedavg
 from repro.core import noise as Z
 from repro.core import wire
+from repro.core.context import RoundContext
 from repro.kernels.zsign import ops
 from repro.kernels.zsign import zsign as ZK
 
@@ -465,7 +466,7 @@ def test_no_dense_noise_buffer_in_compiled_single_pass():
 
 
 # ---------------------------------------------------------------------------
-# compressed-domain group scan
+# client groups run as shards of the round's shard executor
 # ---------------------------------------------------------------------------
 
 def _consensus(comp, groups, n, d, seed=0):
@@ -479,21 +480,12 @@ def _consensus(comp, groups, n, d, seed=0):
     return step, st, y.reshape(groups, n, 1, d)
 
 
-def test_stacks_group_payloads_dispatch():
-    assert C.Pipeline("zsign").stacks_group_payloads()
-    assert C.Pipeline("ef|zsign").stacks_group_payloads()
-    assert C.Pipeline("ef|topk").stacks_group_payloads()
-    assert not C.Pipeline("identity").stacks_group_payloads()
-    assert not C.Pipeline("qsgd").stacks_group_payloads()
-    assert not C.Pipeline("dp(noise=1.0)|dense").stacks_group_payloads()
-
-
 @pytest.mark.parametrize("mask_on", [True, False])
 def test_group_scan_bit_identical_to_vmap_path(mask_on):
-    """8 clients as 2x4 (payload-stacking scan) vs 1x8 (vmap): the fused
-    encode streams and the single 8-client sign-reduce are the same
-    computation, so params must be BIT-identical (0/1 mask -> integer sums),
-    including under partial participation."""
+    """8 clients as 2x4 (two shards of 4) vs 1x8 (one shard of 8): the
+    SignFoldAcc carry folds the two shards in the single 8-client
+    sign-reduce's order, so params must be BIT-identical, including under
+    partial participation."""
     d = 80
     outs = {}
     for groups, n in [(1, 8), (2, 4)]:
@@ -531,9 +523,10 @@ def test_group_stack_aggregate_equals_per_group_sum():
 
 
 def test_group_scan_emits_payload_stack_not_dense_partials():
-    """Jaxpr of the G>1 round for a sign compressor: the scan's carry/ys hold
-    uint8 payloads; no fp32 array of (G*N, d) or per-group dense decode
-    appears before the single final aggregate."""
+    """Jaxpr of the G>1 round for a sign compressor: the G groups run as G
+    shards of N through the shard scan, whose carry is the O(d) wire
+    accumulator; no (G*N, d) f32 array of gradients or decoded partials
+    appears anywhere in the round."""
     d = 2 * TILE
     comp = C.Pipeline("zsign(z=1,sigma=0.5,encode_chunk_tiles=1)")
     G, n = 4, 4
@@ -543,20 +536,24 @@ def test_group_scan_emits_payload_stack_not_dense_partials():
     step = fedavg.build_round_step(loss_fn, comp, cfg)
     st = fedavg.init_server_state({"x": jnp.zeros(d)}, cfg, comp,
                                   jax.random.PRNGKey(1))
-    batch = {"y": jnp.zeros((G, n, 1, d))}
+    # scalar per-client targets: any (G*N)*d-sized array in the jaxpr is a
+    # genuine full-cohort buffer, never input data
+    batch = {"y": jnp.zeros((G, n, 1, 1))}
     jaxpr = jax.make_jaxpr(step)(st, batch, jnp.ones((G, n)))
-    # find the scan over groups and check its outputs are u8 payload stacks
-    scans = [e for e in _walk_eqns(jaxpr.jaxpr) if e.primitive.name == "scan"]
-    assert scans, "group loop must lower to lax.scan"
-    group_scan = max(scans, key=lambda e: len(e.outvars))
-    u8_outs = [v for v in group_scan.outvars
-               if getattr(v.aval, "dtype", None) == jnp.uint8]
-    assert u8_outs, "group scan must emit the stacked uint8 payloads"
-    for v in group_scan.outvars:
-        aval = v.aval
-        if aval.dtype == jnp.float32 and aval.ndim >= 1:
-            n_el = int(np.prod(aval.shape))
-            assert n_el < d, f"dense f32 group partial in scan outputs: {aval}"
+    eqns = list(_walk_eqns(jaxpr.jaxpr))
+    group_scans = [e for e in eqns if e.primitive.name == "scan"
+                   and e.params["length"] == G]
+    assert group_scans, "the G groups must lower to one shard scan"
+    for scan in group_scans:
+        for v in scan.outvars[:scan.params["num_carry"]]:
+            assert int(np.prod(v.aval.shape)) <= d, \
+                f"group scan carries more than the O(d) accumulator: {v.aval}"
+    for eqn in eqns:
+        for v in list(eqn.invars) + list(eqn.outvars):
+            aval = getattr(v, "aval", None)
+            if getattr(aval, "dtype", None) == jnp.float32:
+                assert int(np.prod(aval.shape)) < G * n * d, \
+                    f"full-cohort f32 buffer in the G>1 round: {eqn}"
 
 
 # ---------------------------------------------------------------------------
@@ -589,8 +586,8 @@ def test_weights_are_mask_identical_results():
         comp = C.Pipeline("zsign(z=1,sigma=1.0)")
         loss_fn = lambda p, b: 0.5 * jnp.sum((p["x"] - b["y"]) ** 2)
         cfg = fedavg.FedConfig(n_clients=6, client_lr=0.01, server_lr=0.3)
-        step = jax.jit(fedavg.build_round_step(loss_fn, comp, cfg,
-                                               weights_are_mask=flag))
+        step = jax.jit(fedavg.build_round_step(
+            loss_fn, comp, cfg, RoundContext(weights_are_mask=flag)))
         st = fedavg.init_server_state({"x": jnp.zeros(d)}, cfg, comp,
                                       jax.random.PRNGKey(1))
         y = jax.random.normal(jax.random.PRNGKey(2), (1, 6, 1, d))
@@ -599,28 +596,6 @@ def test_weights_are_mask_identical_results():
             st, _ = step(st, {"y": y}, mask)
         outs[flag] = np.asarray(st.params["x"])
     np.testing.assert_array_equal(outs[False], outs[True])
-
-
-def test_e1_fast_client_path_matches_legacy():
-    """The E == 1 gradient shortcut and the legacy scan+subtract client path
-    (the benchmark's dense-baseline engine) agree to f32 rounding — the
-    only difference is the (gamma*g)/gamma round-trip the fast path skips."""
-    d, n = 96, 6
-    comp = C.Pipeline("identity")
-    loss_fn = lambda p, b: 0.5 * jnp.sum((p["x"] - b["y"]) ** 2)
-    cfg = fedavg.FedConfig(n_clients=n, client_lr=0.01, server_lr=0.5)
-    y = jax.random.normal(jax.random.PRNGKey(2), (1, n, 1, d))
-    mask = jnp.ones((1, n))
-    outs = {}
-    for legacy in [False, True]:
-        step = jax.jit(fedavg.build_round_step(loss_fn, comp, cfg,
-                                               legacy_client_path=legacy))
-        st = fedavg.init_server_state({"x": jnp.zeros(d)}, cfg, comp,
-                                      jax.random.PRNGKey(1))
-        for _ in range(5):
-            st, m = step(st, {"y": y}, mask)
-        outs[legacy] = np.asarray(st.params["x"])
-    np.testing.assert_allclose(outs[False], outs[True], rtol=2e-5, atol=1e-6)
 
 
 def test_efsign_has_no_mask_flag():

@@ -13,8 +13,7 @@ Contract under test (see core/compression.py):
     ``codec_input - local_decode(payload)``;
   * a ``dp`` transform's noise FUSES into a downstream sign codec's sigma,
     so DP ships 1 bit/coord with no dense noise surface (jaxpr-enforced);
-  * ``RoundContext`` is the one policy object: legacy kwargs and an explicit
-    context build bit-identical round steps.
+  * ``RoundContext`` is the one policy object the engine takes.
 """
 import subprocess
 import sys
@@ -149,13 +148,14 @@ def test_dynamic_sigma_refused_over_calibrated_dp_stage():
             p.with_context(RoundContext(dynamic_sigma=True))
         # and through the engine entry point
         with pytest.raises(ValueError, match="Plateau"):
-            fedavg.build_round_step(lambda pr, b: 0.0, p,
-                                    fedavg.FedConfig(), dynamic_sigma=True)
+            fedavg.build_round_step(lambda pr, b: 0.0, p, fedavg.FedConfig(),
+                                    RoundContext(dynamic_sigma=True))
     # legacy dpgauss + Plateau still builds and consumes the dynamic sigma
     legacy = C.DPGaussianCompressor(sigma=0.3)
     step = fedavg.build_round_step(
         lambda pr, b: 0.5 * jnp.sum((pr["x"] - b["y"]) ** 2), legacy,
-        fedavg.FedConfig(n_clients=2, client_lr=0.01), dynamic_sigma=True)
+        fedavg.FedConfig(n_clients=2, client_lr=0.01),
+        RoundContext(dynamic_sigma=True))
     st = fedavg.init_server_state({"x": jnp.zeros(8)}, fedavg.FedConfig(
         n_clients=2, client_lr=0.01), legacy, jax.random.PRNGKey(0),
         sigma0=0.7)
@@ -184,19 +184,6 @@ def test_clip_only_dp_never_consumes_dynamic_sigma():
 def test_fractional_z_rejected():
     with pytest.raises(ValueError, match="integer or 'inf'"):
         C.Pipeline("zsign(z=2.5)")
-
-
-def test_ctx_plus_legacy_kwargs_conflict_raises():
-    comp = C.Pipeline("zsign(sigma=0.5)")
-    with pytest.raises(ValueError, match="not both"):
-        fedavg.build_round_step(lambda p, b: 0.0, comp, fedavg.FedConfig(),
-                                RoundContext(), agg_backend="jnp")
-    import benchmarks  # noqa: F401 -- ensure package importable
-    from benchmarks.common import run_fed
-    with pytest.raises(ValueError, match="not both"):
-        run_fed(lambda p, b: 0.0, {"x": jnp.zeros(4)}, lambda t: {},
-                comp, fedavg.FedConfig(), rounds=1, ctx=RoundContext(),
-                agg_backend="jnp")
 
 
 def test_legacy_factories_reject_unknown_kwargs():
@@ -473,31 +460,6 @@ def test_cli_trains_pipeline_spec_end_to_end(spec):
 # ---------------------------------------------------------------------------
 # RoundContext policy
 # ---------------------------------------------------------------------------
-
-def test_round_context_equals_legacy_kwargs_bit_identical():
-    """build_round_step(ctx=RoundContext(...)) and the legacy kwargs spell
-    the same round: bit-identical params after several rounds."""
-    d, n = 64, 6
-    comp = C.Pipeline("zsign(z=1,sigma=1.0)")
-    loss_fn = lambda p, b: 0.5 * jnp.sum((p["x"] - b["y"]) ** 2)
-    cfg = fedavg.FedConfig(n_clients=n, client_lr=0.01, server_lr=0.3)
-    y = jax.random.normal(jax.random.PRNGKey(2), (1, n, 1, d))
-    mask = jnp.ones((1, n)).at[0, 2].set(0.0)
-    outs = {}
-    for label, kw in [
-            ("ctx", dict(ctx=RoundContext(agg_backend="jnp",
-                                          encode_backend="jnp",
-                                          weights_are_mask=True))),
-            ("legacy", dict(agg_backend="jnp", encode_backend="jnp",
-                            weights_are_mask=True))]:
-        step = jax.jit(fedavg.build_round_step(loss_fn, comp, cfg, **kw))
-        st = fedavg.init_server_state({"x": jnp.zeros(d)}, cfg, comp,
-                                      jax.random.PRNGKey(1))
-        for _ in range(4):
-            st, _ = step(st, {"y": y}, mask)
-        outs[label] = np.asarray(st.params["x"])
-    np.testing.assert_array_equal(outs["ctx"], outs["legacy"])
-
 
 def test_with_context_per_stage_rebinding():
     ctx = RoundContext(agg_backend="dense", encode_backend="reference",

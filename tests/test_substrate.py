@@ -1,4 +1,4 @@
-"""Substrate coverage: data pipeline, optimizers, sharding plans, hints."""
+"""Substrate coverage: data pipeline and optimizers."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -54,60 +54,3 @@ def test_optimizers_descend_quadratic(name, kw):
         grads = {"w": 2 * params["w"]}  # d/dw ||w||^2
         params, state = opt.update(grads, state, params)
     assert float(jnp.linalg.norm(params["w"])) < 1e-2
-
-
-# -- sharding plans ------------------------------------------------------------
-
-def test_plans_cover_global_batch():
-    from repro.configs.common import SHAPES, get_arch, list_archs
-    from repro.launch.sharding import make_plan
-
-    class M:
-        axis_names = ("data", "model")
-        shape = {"data": 16, "model": 16}
-
-    class M2:
-        axis_names = ("pod", "data", "model")
-        shape = {"pod": 2, "data": 16, "model": 16}
-
-    for arch_id in list_archs():
-        arch = get_arch(arch_id)
-        for mesh in (M(), M2()):
-            plan = make_plan(arch, SHAPES["train_4k"], mesh)
-            total = (plan.micro * plan.n_clients * plan.client_groups
-                     * plan.local_steps)
-            assert total == SHAPES["train_4k"].global_batch, (arch_id, plan)
-
-
-def test_param_specs_shard_big_dims():
-    import jax
-    from repro.configs.common import SHAPES, get_arch
-    from repro.launch import sharding as SH
-    from repro.models.api import build_model
-
-    class M:
-        axis_names = ("data", "model")
-        shape = {"data": 16, "model": 16}
-
-    arch = get_arch("qwen2_0_5b")  # vocab divisible by 16 => embed sharded
-    plan = SH.make_plan(arch, SHAPES["train_4k"], M())
-    shapes = jax.eval_shape(build_model(arch.model).init, jax.random.PRNGKey(0))
-    specs = SH.param_specs(shapes, M(), plan)
-    # embed sharded on vocab; attention mats sharded somewhere
-    assert specs["embed"][0] is not None
-    flat = jax.tree_util.tree_leaves(
-        specs, is_leaf=lambda s: hasattr(s, "index"))
-    sharded = sum(1 for s in flat if any(e is not None for e in s))
-    assert sharded >= len(flat) // 2
-
-
-# -- hints off-mesh are no-ops -------------------------------------------------
-
-def test_hints_noop_without_mesh():
-    from repro.launch import hints as H
-    x = jnp.ones((4, 32, 8))
-    assert H.seq_shard(x) is x
-    assert H.gather_seq(x) is x
-    assert H.seq_shard_count() == 1
-    lp = {"w": jnp.ones((8, 8))}
-    assert H.fsdp_params(lp, skip=())["w"] is lp["w"]
